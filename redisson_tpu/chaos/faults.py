@@ -100,17 +100,13 @@ KINDS = tuple(_STREAM)
 
 
 def _xla_runtime_error(text: str) -> RuntimeError:
-    """The exception SHAPE real JAX raises from the device runtime: the
-    concrete ``jaxlib`` class when available (it subclasses RuntimeError
-    and is constructible), else a plain RuntimeError with identical text —
-    catch sites match on the message, never the class, so both shapes
-    exercise the same recovery path."""
-    try:
-        from jaxlib.xla_extension import XlaRuntimeError
+    """The exception real JAX raises from the device runtime
+    (``jax.errors.JaxRuntimeError``, a RuntimeError subclass) — catch sites
+    match on the message, never the class.  Imported here, not at module
+    top: wire-only processes arm this plane without ever touching jax."""
+    from jax.errors import JaxRuntimeError
 
-        return XlaRuntimeError(text)
-    except Exception:  # pragma: no cover - jaxlib is baked into the image
-        return RuntimeError(text)
+    return JaxRuntimeError(text)
 
 
 @dataclass

@@ -71,6 +71,15 @@ class NodeStartupError(RuntimeError):
     the exit code and a log tail so the failure is diagnosable."""
 
 
+def _local_tpu_chips() -> int:
+    """TPU chips visible to THIS host, counted without touching jax (the
+    supervisor must never hold a chip its children need): the device nodes
+    libtpu itself opens."""
+    import glob
+
+    return len(glob.glob("/dev/accel*") + glob.glob("/dev/vfio/[0-9]*"))
+
+
 class NodeProc:
     """One supervised server process: identity, liveness, history.  The
     process itself lives behind a :class:`NodeHandle` — local child or
@@ -214,8 +223,32 @@ class ClusterSupervisor:
         """Every node placed in failure domain ``host``."""
         return [n for n in self.nodes() if n.host_label == host]
 
+    def _check_one_process_per_chip(self) -> None:
+        """A chip belongs to one process.  Unless told ``platform="cpu"``
+        (--platform beats the environment in the child) every child takes
+        what JAX_PLATFORMS or jax's default gives it; on a TPU host that
+        hands N local children the same chip, and all but the first die or
+        hang at backend init — fail here, by name, not after N ready
+        timeouts."""
+        platform = self.platform or self.extra_env.get(
+            "JAX_PLATFORMS", os.environ.get("JAX_PLATFORMS", "")
+        )
+        local = sum(
+            1 for h in self._master_hosts + list(self._replica_hosts.values())
+            if not self.driver.is_remote(h)
+        )
+        if (platform.strip().lower() != "cpu" and local > 1
+                and _local_tpu_chips() > 0):
+            raise NodeStartupError(
+                f"{local} local nodes with platform={platform or None!r} on "
+                "a TPU host: every child would claim the same chip (one "
+                "process per chip).  Pass platform='cpu' for a host-only "
+                "fleet, or run ONE tpu-server with --devices all."
+            )
+
     def start(self) -> "ClusterSupervisor":
         try:
+            self._check_one_process_per_chip()
             self._arm_tls()
             for i in range(self.n_masters):
                 node = self._make_node(

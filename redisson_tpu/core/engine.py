@@ -39,7 +39,7 @@ class Engine:
         # XLA compile cache before the first kernel compiles (lazy — a
         # wire-only client never constructs an Engine and never pays the
         # jax import)
-        redisson_tpu._enable_persistent_compile_cache()
+        redisson_tpu.enable_compile_cache()
         self.config = config if config is not None else Config()
         self.store = DeviceStore()
         self.pubsub = PubSubHub()
@@ -457,10 +457,7 @@ class Engine:
         for key, arr in list(rec.arrays.items()):
             devs = getattr(arr, "devices", None)
             if devs is not None:
-                try:
-                    ds = devs()
-                except TypeError:  # pragma: no cover
-                    continue
+                ds = devs()
                 if len(ds) != 1 or ds == {device}:
                     continue  # sharded plane, or already home
             elif not isinstance(arr, np.ndarray):
@@ -481,10 +478,7 @@ class Engine:
                 if not isinstance(arr, np.ndarray):
                     continue
             else:
-                try:
-                    ds = devs()
-                except TypeError:  # pragma: no cover
-                    continue
+                ds = devs()
                 if len(ds) != 1 or ds == {device}:
                     continue
             rec.arrays[key] = jax.device_put(arr, device)

@@ -62,6 +62,8 @@ import time
 import zlib
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
+from redisson_tpu.core.ioplane import _device_id_of
+
 # interned tier constants: guard sites compare with ``is``
 HOT = "hot"
 WARM = "warm"
@@ -386,8 +388,8 @@ class ResidencyManager:
             pool = self.engine.staging_pool(device)
             try:
                 arrays = ioplane.scatter_host_arrays(stash, device, pool=pool)
-            except Exception:  # noqa: BLE001 — packed path refused (exotic
-                import jax      # dtype): per-array upload, same bytes
+            except TypeError:  # a dtype the byte-stream packing cannot
+                import jax     # round-trip: per-array upload, same bytes
 
                 arrays = {
                     k: (jax.device_put(v, device) if device is not None
@@ -416,17 +418,11 @@ class ResidencyManager:
                     return False  # dirty (e.g. pending vector rows)
             except Exception:  # noqa: BLE001 — a broken probe pins, never
                 return False   # unpins: fail safe
-        for a in rec.arrays.values():
-            devs = getattr(a, "devices", None)
-            if devs is None:
-                return False  # host-side numpy plane: nothing to release
-            try:
-                ds = devs()
-            except TypeError:  # pragma: no cover
-                return False
-            if len(ds) != 1:
-                return False  # mesh-sharded plane: parallel/ owns layout
-        return True
+        # host-side numpy planes have nothing to release; mesh-sharded
+        # planes belong to parallel/ — only single-device arrays demote
+        return all(
+            _device_id_of(a) is not None for a in rec.arrays.values()
+        )
 
     def demote(self, name: str, cold: bool = False,
                force: bool = False) -> bool:
@@ -453,17 +449,10 @@ class ResidencyManager:
                     stash = {
                         k: np.asarray(v) for k, v in rec.arrays.items()
                     }
-                    dev = -1
-                    for a in rec.arrays.values():
-                        devs = getattr(a, "devices", None)
-                        if devs is not None:
-                            try:
-                                ds = devs()
-                                if len(ds) == 1:
-                                    dev = next(iter(ds)).id
-                                    break
-                            except TypeError:  # pragma: no cover
-                                pass
+                    dev = next(
+                        (d for d in map(_device_id_of, rec.arrays.values())
+                         if d is not None), -1,
+                    )
                     rec.arrays.clear()
                     rec.stash = stash
                     rec.stash_dev = dev
@@ -491,15 +480,8 @@ class ResidencyManager:
         with no_promote():
             for _kind, rec in self.engine.store.census_records():
                 for a in rec.arrays.values():
-                    devs = getattr(a, "devices", None)
-                    if devs is None:
-                        continue
-                    try:
-                        ds = devs()
-                    except TypeError:  # pragma: no cover
-                        continue
-                    if len(ds) == 1:
-                        d = next(iter(ds)).id
+                    d = _device_id_of(a)
+                    if d is not None:
                         out[d] = out.get(d, 0) + int(a.nbytes)
         return out
 
@@ -515,14 +497,7 @@ class ResidencyManager:
             nbytes = 0
             on_dev = False
             for a in rec.arrays.values():
-                devs = getattr(a, "devices", None)
-                if devs is None:
-                    continue
-                try:
-                    ds = devs()
-                except TypeError:  # pragma: no cover
-                    continue
-                if len(ds) == 1 and next(iter(ds)).id == dev_id:
+                if _device_id_of(a) == dev_id:
                     on_dev = True
                     nbytes += int(a.nbytes)
             if on_dev and self._demotable(name, rec):
@@ -662,15 +637,8 @@ class ResidencyManager:
                     cold[d] = cold.get(d, 0) + int(rec.cold_bytes)
                 else:
                     for a in rec.arrays.values():
-                        devs = getattr(a, "devices", None)
-                        if devs is None:
-                            continue
-                        try:
-                            ds = devs()
-                        except TypeError:  # pragma: no cover
-                            continue
-                        if len(ds) == 1:
-                            d = next(iter(ds)).id
+                        d = _device_id_of(a)
+                        if d is not None:
                             hot[d] = hot.get(d, 0) + int(a.nbytes)
         rows: Dict[str, float] = {}
         for tier, per in (("hot", hot), ("warm", warm), ("cold", cold)):

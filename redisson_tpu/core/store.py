@@ -94,11 +94,11 @@ class DeviceStore:
         self.residency = None
 
     def _placed(self, name: str, rec: StateRecord) -> StateRecord:
+        # a failed placement FAILS the install: swallowing it would leave
+        # the record on the default device while CLUSTER DEVICES names
+        # another owner — every device's share silently landing on device 0
         if self.placement_hook is not None:
-            try:
-                self.placement_hook(name, rec)
-            except Exception:  # noqa: BLE001 — placement is an optimization:
-                pass           # a failed placement must never fail the write
+            self.placement_hook(name, rec)
         return rec
 
     def _reaped(self, name: str) -> None:

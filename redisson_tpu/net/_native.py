@@ -1,13 +1,18 @@
 """ctypes loader for the native runtime library (native/resp.cpp).
 
-Builds `native/build/librtpu.so` on first use with g++ (the image has no
-pybind11; the C ABI + ctypes is the binding layer — see repo guidelines).
-Every entry point degrades to pure Python if the toolchain or library is
-unavailable, so the framework never hard-requires the native path.
+Builds ``native/build/librtpu-<digest>.so`` on first use with g++ (the image
+has no pybind11; the C ABI + ctypes is the binding layer — see repo
+guidelines).  The artifact is keyed by the CONTENT of resp.cpp: the library a
+process loads is always built from the source next to it, whatever a copied
+tree did to mtimes, and nothing built is checked in.  Every entry point
+degrades to pure Python if the toolchain or library is unavailable, so the
+framework never hard-requires the native path — ``build_status()`` says
+which of the two happened.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -15,11 +20,13 @@ from typing import Optional
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _NATIVE_DIR = os.path.join(_REPO_ROOT, "native")
-_SO_PATH = os.path.join(_NATIVE_DIR, "build", "librtpu.so")
+_SRC_PATH = os.path.join(_NATIVE_DIR, "resp.cpp")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
-_tried = False
+# "untried" | "disabled" (RTPU_NO_NATIVE) | "no_source" | "loaded" (artifact
+# for this source already on disk) | "built" | "build_failed" | "load_failed"
+_status = "untried"
 
 
 class RtpuToken(ctypes.Structure):
@@ -31,31 +38,40 @@ class RtpuToken(ctypes.Structure):
     ]
 
 
-def _build(dst: Optional[str] = None) -> bool:
-    src = os.path.join(_NATIVE_DIR, "resp.cpp")
-    if not os.path.exists(src):
-        return False
-    os.makedirs(os.path.dirname(_SO_PATH), exist_ok=True)
+def so_path() -> Optional[str]:
+    """Artifact path for the resp.cpp on disk, or None without a source."""
+    try:
+        with open(_SRC_PATH, "rb") as fh:
+            digest = hashlib.sha256(fh.read()).hexdigest()[:16]
+    except OSError:
+        return None
+    return os.path.join(_NATIVE_DIR, "build", f"librtpu-{digest}.so")
+
+
+def _build(dst: str) -> bool:
+    """Compile resp.cpp to `dst`, atomically: concurrent first users (N
+    spawned servers) each build to a private name and rename into place."""
+    os.makedirs(os.path.dirname(dst), exist_ok=True)
+    tmp = f"{dst}.{os.getpid()}"
     try:
         subprocess.run(
-            ["g++", "-O3", "-fPIC", "-shared", "-std=c++17", "-o", dst or _SO_PATH, src],
+            ["g++", "-O3", "-fPIC", "-shared", "-std=c++17", "-o", tmp, _SRC_PATH],
             check=True,
             capture_output=True,
             timeout=120,
         )
+        os.replace(tmp, dst)
         return True
-    except Exception:
+    except (OSError, subprocess.SubprocessError):
         return False
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
 
 
-def _stale() -> bool:
-    """True when the checked-in/previously-built .so predates resp.cpp —
-    a stale artifact must never silently serve a diverged source."""
-    src = os.path.join(_NATIVE_DIR, "resp.cpp")
-    try:
-        return os.path.getmtime(_SO_PATH) < os.path.getmtime(src)
-    except OSError:
-        return False
+def build_status() -> str:
+    """How the last ``load()`` resolved the library (see ``_status``)."""
+    return _status
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -109,41 +125,29 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
 
 
 def load() -> Optional[ctypes.CDLL]:
-    """The native library, or None if unavailable (pure-Python fallback)."""
-    global _lib, _tried
-    if _lib is not None or _tried:
+    """The native library, or None if unavailable (pure-Python fallback).
+    Resolved once per process; ``build_status()`` says how."""
+    global _lib, _status
+    if _status != "untried":
         return _lib
     with _lock:
-        if _lib is not None or _tried:
-            return _lib
-        _tried = True
-        if os.environ.get("RTPU_NO_NATIVE"):
-            return None
-        if (not os.path.exists(_SO_PATH) or _stale()) and not _build():
-            if not os.path.exists(_SO_PATH):
-                return None
-        try:
-            lib = _bind(ctypes.CDLL(_SO_PATH))
-        except OSError:
-            return None
-        except AttributeError:
-            # Artifact built from an older resp.cpp (mtimes lied, e.g. a git
-            # checkout stamping both files together): rebuild to a fresh
-            # path — re-dlopen()ing the original path could hand back the
-            # cached stale handle — then promote it to the canonical name.
-            tmp = f"{_SO_PATH}.{os.getpid()}"
-            try:
-                if not _build(tmp):
-                    return None
-                lib = _bind(ctypes.CDLL(tmp))
-                os.replace(tmp, _SO_PATH)
-            except (OSError, AttributeError):
-                return None
-            finally:
-                if os.path.exists(tmp):
-                    try:
-                        os.remove(tmp)
-                    except OSError:
-                        pass
-        _lib = lib
+        if _status == "untried":
+            _lib, _status = _resolve()
         return _lib
+
+
+def _resolve():
+    if os.environ.get("RTPU_NO_NATIVE"):
+        return None, "disabled"
+    path = so_path()
+    if path is None:
+        return None, "no_source"
+    status = "loaded"
+    if not os.path.exists(path):
+        if not _build(path):
+            return None, "build_failed"
+        status = "built"
+    try:
+        return _bind(ctypes.CDLL(path)), status
+    except (OSError, AttributeError):
+        return None, "load_failed"

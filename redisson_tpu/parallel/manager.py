@@ -67,6 +67,11 @@ def _committed_device(arr):
 def _merge_axis0_max(x):
     import jax.numpy as jnp
 
+    # the reduction over the device axis lowers to a max all-reduce, which
+    # XLA:TPU miscomputes for unsigned 8/16-bit integers (see the note in
+    # parallel/sharded.py) — HLL registers are uint8: carry them as int32
+    if x.dtype in (jnp.uint8, jnp.uint16):
+        return jnp.max(x.astype(jnp.int32), axis=0).astype(x.dtype)
     return jnp.max(x, axis=0)
 
 
@@ -123,7 +128,9 @@ def merge_across_devices(arrays, dest_device=None):
             )
             return ioplane.colocate(_merge_axis0_max(stacked), dest_device)
         except Exception:  # noqa: BLE001 — collective path unavailable:
-            pass           # the d2d colocate chain below is always correct
+            # the d2d colocate chain below is always correct; counted so a
+            # collective the device refuses cannot hide behind it
+            ioplane.STATS.count_merge_fallback()
     out = None
     for p in partials:
         p = ioplane.colocate(p, dest_device)

@@ -25,16 +25,25 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-# jax.shard_map graduated from jax.experimental in newer releases; the
-# pinned 0.4.x still ships it experimental-only — resolve once here
-try:
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from redisson_tpu.parallel.mesh import DP_AXIS, SHARD_AXIS
 from redisson_tpu.ops import hll as hll_ops
 from redisson_tpu.utils import hashing as H
+
+
+# XLA:TPU (jax 0.9.0 / libtpu 0.0.34, v5e 2x2) MISCOMPUTES max/min
+# all-reduces over UNSIGNED 8- and 16-bit integers, silently: 2,122 of
+# 16,384 random uint8 lanes came back wrong from a pmax over `dp`; int8,
+# int32 and every psum were exact (chip run, PR 21).  The planes here are
+# uint8 with values below 128 — bits are 0/1, HLL ranks at most 33 — so the
+# collective rides int8, same width on the wire, and converts back.
+
+
+def _pmax_u8(x, axis):
+    return jax.lax.pmax(x.astype(jnp.int8), axis).astype(jnp.uint8)
+
+
+def _pmin_u8(x, axis):
+    return jax.lax.pmin(x.astype(jnp.int8), axis).astype(jnp.uint8)
 
 
 def _local_probe_gather(bits_local, tenant, idx_global, m_local):
@@ -96,11 +105,11 @@ def make_sharded_bloom_kernels(
         bits_local = bits_local.at[trow, safe].set(jnp.uint8(1), mode="drop")
         # dp groups each scattered their own ops into their dp-replica of the
         # plane; max-combine across dp so every replica sees every write
-        bits_local = jax.lax.pmax(bits_local, DP_AXIS)
+        bits_local = _pmax_u8(bits_local, DP_AXIS)
         return bits_local, newly
 
     contains = jax.jit(
-        _shard_map(
+        jax.shard_map(
             contains_local,
             mesh=mesh,
             in_specs=(state_spec, ops_spec, ops_spec, ops_spec, P()),
@@ -108,7 +117,7 @@ def make_sharded_bloom_kernels(
         )
     )
     add = jax.jit(
-        _shard_map(
+        jax.shard_map(
             add_local,
             mesh=mesh,
             in_specs=(state_spec, ops_spec, ops_spec, ops_spec, P()),
@@ -147,14 +156,14 @@ def make_sharded_hll_kernels(mesh: Mesh, p: int, n_rows: int):
         owned = (local_t >= 0) & (local_t < t_local) & valid
         trow = jnp.where(owned, local_t, t_local)
         regs_local = regs_local.at[trow, idx].max(rho, mode="drop")
-        regs_local = jax.lax.pmax(regs_local, DP_AXIS)
+        regs_local = _pmax_u8(regs_local, DP_AXIS)
         return regs_local
 
     def estimate_local(regs_local):
         return hll_ops.estimate(regs_local)
 
     add = jax.jit(
-        _shard_map(
+        jax.shard_map(
             add_local,
             mesh=mesh,
             in_specs=(state_spec, ops_spec, ops_spec, ops_spec, P()),
@@ -163,7 +172,7 @@ def make_sharded_hll_kernels(mesh: Mesh, p: int, n_rows: int):
         donate_argnums=(0,),
     )
     estimate = jax.jit(
-        _shard_map(
+        jax.shard_map(
             estimate_local, mesh=mesh, in_specs=(state_spec,), out_specs=P(SHARD_AXIS)
         )
     )
@@ -223,14 +232,14 @@ def make_sharded_bitset_kernels(mesh: Mesh, m: int, width: int = 0):
                 jnp.uint8(1 if setting else 0), mode="drop"
             )
             combined = (
-                jax.lax.pmax(bits_local, DP_AXIS)
+                _pmax_u8(bits_local, DP_AXIS)
                 if setting
-                else jax.lax.pmin(bits_local, DP_AXIS)
+                else _pmin_u8(bits_local, DP_AXIS)
             )
             return combined, old & valid
 
         return jax.jit(
-            _shard_map(
+            jax.shard_map(
                 set_local, mesh=mesh,
                 in_specs=(state_spec, ops_spec, P()),
                 out_specs=(state_spec, ops_spec),
@@ -245,13 +254,13 @@ def make_sharded_bitset_kernels(mesh: Mesh, m: int, width: int = 0):
         return jax.lax.psum(jnp.sum(bits_local, dtype=jnp.int32), SHARD_AXIS)
 
     get = jax.jit(
-        _shard_map(
+        jax.shard_map(
             get_local, mesh=mesh,
             in_specs=(state_spec, ops_spec, P()),
             out_specs=ops_spec,
         )
     )
     card = jax.jit(
-        _shard_map(card_local, mesh=mesh, in_specs=(state_spec,), out_specs=P())
+        jax.shard_map(card_local, mesh=mesh, in_specs=(state_spec,), out_specs=P())
     )
     return (make_set(True), make_set(False)), get, card

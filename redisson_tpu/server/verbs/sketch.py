@@ -222,8 +222,7 @@ def cmd_getbits(server, ctx, args):
 
 # blob forms: indexes travel as ONE little-endian i32 buffer and previous
 # bit values return as ONE byte blob — RESP integer encode/parse for
-# thousands of per-bit args is pure overhead at batch sizes (bytes on the
-# wire are the cost that matters through the tunnel)
+# thousands of per-bit args is pure overhead at batch sizes
 @register("SETBITSB")
 def cmd_setbitsb(server, ctx, args):
     import numpy as np

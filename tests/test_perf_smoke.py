@@ -417,14 +417,12 @@ def test_perf_gate_passes_self_and_fails_known_regression(tmp_path):
     gate = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(gate)
 
-    r5 = os.path.join(repo, "BENCH_r05.json")
-    if not os.path.exists(r5):
-        pytest.skip("no recorded BENCH artifacts")
+    r5 = os.path.join(repo, "tests", "golden", "bench_gate_base.json")
     assert gate.main(["--fresh", r5, "--baseline", r5]) == 0
-    r3 = os.path.join(repo, "BENCH_r03.json")
-    if os.path.exists(r3):
-        # the recorded r3->r5 decline (the motivating regression) must FAIL
-        assert gate.main(["--fresh", r5, "--baseline", r3]) == 1
+    r3 = os.path.join(repo, "tests", "golden", "bench_gate_prior.json")
+    # a headline decline between two records (the motivating regression)
+    # must FAIL
+    assert gate.main(["--fresh", r5, "--baseline", r3]) == 1
 
     # synthetic: a 6% headline drop fails, 4% passes
     with open(r5) as fh:
@@ -489,9 +487,7 @@ def test_perf_gate_config6_floor_and_relative(tmp_path):
     )
     gate = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(gate)
-    r5 = os.path.join(repo, "BENCH_r05.json")
-    if not os.path.exists(r5):
-        pytest.skip("no recorded BENCH artifacts")
+    r5 = os.path.join(repo, "tests", "golden", "bench_gate_base.json")
     with open(r5) as fh:
         base = gate.load_bench_doc(fh.read())
 
@@ -644,9 +640,7 @@ def test_perf_gate_config5d_first_sight_and_relative(tmp_path):
     )
     gate = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(gate)
-    r5 = os.path.join(repo, "BENCH_r05.json")
-    if not os.path.exists(r5):
-        pytest.skip("no recorded BENCH artifacts")
+    r5 = os.path.join(repo, "tests", "golden", "bench_gate_base.json")
     with open(r5) as fh:
         base = gate.load_bench_doc(fh.read())
 
@@ -693,9 +687,7 @@ def test_perf_gate_config6r_floor_ceiling_and_relative(tmp_path):
     )
     gate = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(gate)
-    r5 = os.path.join(repo, "BENCH_r05.json")
-    if not os.path.exists(r5):
-        pytest.skip("no recorded BENCH artifacts")
+    r5 = os.path.join(repo, "tests", "golden", "bench_gate_base.json")
     with open(r5) as fh:
         base = gate.load_bench_doc(fh.read())
 

@@ -1,29 +1,29 @@
 """Perf regression gate (ISSUE 2 satellite): compare a fresh bench.py run
-against the latest recorded BENCH_rNN.json, per config.
+against a recorded one, per config.
 
-The headline throughput slid three rounds in a row (8.17M -> 8.03M -> 7.71M
-contains/s, BENCH_r03..r05) before anyone was forced to look; this gate makes
-that slide impossible to miss again.  It is the pre-commit perf ritual
-(README "Performance"): run bench.py on the chip, feed the JSON here, commit
-only when the gate is green or the miss is explicitly traded out in ROADMAP.
+A headline that slides a few percent a round goes unnoticed until someone
+is forced to look; this gate makes the slide impossible to miss.  It is the
+pre-commit perf ritual (README "Performance"): run bench.py on the chip,
+feed the JSON here, commit only when the gate is green or the miss is
+explicitly traded out in ROADMAP.
 
 Usage:
-  python tools/perf_gate.py --fresh out.json      # out.json = bench.py stdout
-  python bench.py | tee out.txt; python tools/perf_gate.py --fresh out.txt
-  python tools/perf_gate.py --run                 # runs bench.py itself
-  python tools/perf_gate.py --fresh out.json --baseline BENCH_r03.json
+  python tools/perf_gate.py --fresh out.json --baseline prev.json
+  python bench.py | tee out.txt; python tools/perf_gate.py --fresh out.txt --baseline prev.json
+  python tools/perf_gate.py --run --baseline prev.json   # runs bench.py itself
 
 Inputs accept either the raw bench.py JSON line (possibly embedded in other
-stdout) or a recorded BENCH_rNN.json wrapper ({"parsed": {...}}).  Baseline
-defaults to the highest-numbered BENCH_r*.json in the repo root.
+stdout) or a recorded wrapper ({"parsed": {...}}).  No record of current
+code is checked in, so --baseline is required in practice (without it the
+gate looks for BENCH_r*.json in the repo root and exits when none exists).
 
 Gate rule: exit nonzero on a >5% drop (--threshold) in any GATED metric:
 the HEADLINE (windowed bank contains/s), CONFIG5 (cluster mixed ops/s),
 CONFIG2 flush p99 ms (lower is better — the latency floor the overlap
 plane of ISSUE 3 attacks), and CONFIG4 cold entries/s.  Every other
 tracked metric prints in the regression table and flags WARN on a drop —
-visible, but advisory (tunnel variance on the secondary configs is real;
-the gated numbers are windowed/best-of or percentile-stable).
+visible, but advisory (run-to-run variance on the secondary configs is
+real; the gated numbers are windowed/best-of or percentile-stable).
 """
 from __future__ import annotations
 
