@@ -1230,7 +1230,7 @@ def bench_config2q_qos():
         for i, t in enumerate(("ta", "tb"))
     }
 
-    def leg(qos_on: bool, measure_s: float = MEASURE_S):
+    def leg(qos_on: bool):
         st = ServerThread(port=0, workers=4, qos=qos_on).start()
         conns = []
         stop = threading.Event()  # before the try: the finally sets it
@@ -1308,7 +1308,7 @@ def bench_config2q_qos():
                 th.start()
             time.sleep(WARM_S)
             marks = {t: len(lat[t]) for t in lat}  # warm-up excluded
-            time.sleep(measure_s)
+            time.sleep(MEASURE_S)
             stop.set()
             for th in threads:
                 th.join(timeout=60.0)
@@ -1347,22 +1347,6 @@ def bench_config2q_qos():
 
     armed = leg(qos_on=True)
     disarmed = leg(qos_on=False)
-    # per-stage attribution (ISSUE 12): a THIRD, short leg with the tracing
-    # plane armed — separate from the gated legs so the trace cost can
-    # never skew the p99/fairness numbers rounds are compared on.  The
-    # stage breakdown answers "which stage moved" when a chip run shifts
-    # the gated numbers (the ROADMAP chip-run deliverable).
-    from redisson_tpu.observe import trace as _obs_trace
-
-    prev_tracing = _obs_trace.set_tracing(True)
-    try:
-        _obs_trace.TRACER.reset()
-        leg(qos_on=True, measure_s=2.0)
-        stage_breakdown = _obs_trace.TRACER.stage_summary()
-    finally:
-        _obs_trace.set_tracing(prev_tracing)
-        _obs_trace.TRACER.reset()
-        _obs_trace.TRACER.slowlog_reset()
     assert armed["server_sheds"] > 0, (
         "hostile tenant never shed — the budget knob is not binding; "
         "the armed leg measured nothing"
@@ -1384,7 +1368,6 @@ def bench_config2q_qos():
         "config2q_fairness_p99_ratio": armed["fairness_p99_ratio"],
         "config2q_interactive_speedup_vs_noqos": round(speedup, 3),
         "config2q_noqos_interactive_p99_ms": disarmed["interactive_p99_ms"],
-        "stage_breakdown": stage_breakdown,
         "armed": armed,
         "disarmed": disarmed,
     }
@@ -2581,9 +2564,6 @@ def main():
                     "config2q_preempt_speedup_vs_nopreempt": results["2q"]["qos"]["config2q_preempt_speedup_vs_nopreempt"],
                     "config2q_cluster_fairness_p99_ratio": results["2q"]["qos"]["config2q_cluster_fairness_p99_ratio"],
                     "config2q_cluster_admitted_ratio": results["2q"]["qos"]["config2q_cluster_admitted_ratio"],
-                    # per-stage waterfall of the hostile mix (ISSUE 12):
-                    # which stage a chip run moves, not just the total
-                    "stage_breakdown": results["2q"]["qos"]["stage_breakdown"],
                     "config7_knn_qps": results["7"]["vector"]["config7_knn_qps"],
                     "config7_recall_at_10": results["7"]["vector"]["config7_recall_at_10"],
                     "config7_ivf_knn_qps": results["7"]["vector"]["config7_ivf_knn_qps"],

@@ -8,9 +8,16 @@ never *attributed* to a stage.  This module is the Dapper-style answer
 (PAPERS.md): every parsed frame is stamped with a trace id + monotonic t0,
 and each chokepoint it crosses appends a **stage span**:
 
-  ``parse``     — RESP bytes -> command list (read loop);
+  ``recv``      — the read that brought the frame's first byte -> the read
+                  that completed it (``reads``/``nbytes``/``feed_us``: a
+                  1 MB frame crosses many 64 KB reads).  It lies BEFORE the
+                  frame's t0, so its ``off_us`` is negative;
+  ``parse``     — RESP bytes -> command list (read loop), from offset 0;
   ``qos``       — WindowScheduler classify/charge + bulk-gate wait
                   (tenant/class/items/shed annotated);
+  ``hop``       — ``run_in_executor`` submit -> the worker's first line
+                  (``to`` = ``dispatch`` or ``force``): the queue for a
+                  pool thread plus the thread switch;
   ``dispatch``  — handler execution window for the whole frame;
   ``stage``     — device-lane gate wait (queueing ahead of the chip);
   ``kernel``    — ONE span per coalesced same-verb run, its member commands
@@ -18,7 +25,14 @@ and each chokepoint it crosses appends a **stage span**:
   ``readback``  — D2H force, annotated whether the frame PAID the blocking
                   sync (``blocking``) or rode a grouped fetch (``grouped``);
   ``reply``     — dispatch-done -> bytes written: the tail that makes the
-                  trace total the true client-observable latency.
+                  trace total the true client-observable latency.  Its
+                  children say what the tail was: ``reply.wait`` (for the
+                  overlapped readback future, or in the writer's queue),
+                  ``reply.encode`` and ``reply.write`` (``write`` + ``drain``;
+                  ``nbytes``, ``batch`` = frames in that one write);
+  ``host.gc`` / ``host.stall`` — on SLOW frames only (total at or over the
+                  slowlog threshold): the part of the frame a host pause
+                  overlapped (below).
 
 Finished traces land in a **bounded, lock-light ring** (deque append is a
 single GIL-atomic op), queryable over the wire (``TRACE GET/RESET/CONFIG``,
@@ -27,12 +41,22 @@ per-stage breakdown instead of Redis's flat duration) and ``LATENCY
 HISTORY``; per-stage duration timers feed the server's MetricsRegistry so
 ``prometheus_text`` exports stage histograms.
 
+**Host events** ride the same clock: while armed, a small bounded ring
+records every garbage collection of a millisecond or more (``gc.callbacks``,
+``gen`` annotated) and every time the server's event loop woke 5 ms or more
+late (``stall``: a heartbeat task on the loop — a GC, a worker holding the
+GIL, a long synchronous call).  ``TRACE EVENTS`` lists the ring, four
+monotone ``host_*`` totals ride METRICS, and a slow frame is annotated with
+the events it overlapped — what an operator asks of a slow log.
+
 Arming follows the chaos-hook discipline (net/client.py ``_fault_plane``):
 
   * DISARMED (the default) every instrumentation site costs one module-
     global load plus an ``is None``/``is not None`` branch — no attribute
     chase, no call, no allocation (tests/test_observe.py asserts this at
     the allocator level against the discovered guard lines);
+    the ``gc`` callback is not installed and the heartbeat wakes once a
+    second to look at the guard;
   * ARMED (``RTPU_TRACE=1`` / ``set_tracing(True)`` / ``CONFIG SET
     trace-enabled yes``) replies are bit-identical to disarmed — the
     tracer only *observes* waits and work, it never reorders either.
@@ -43,6 +67,7 @@ the per-server ring; in-process multi-server tests share it knowingly.
 """
 from __future__ import annotations
 
+import gc
 import itertools
 import os
 import threading
@@ -78,8 +103,8 @@ class FrameTrace:
     so the trace carries no lock — the lock-light half of the contract."""
 
     __slots__ = ("trace_id", "ts", "t0", "verbs", "n_cmds", "client_id",
-                 "qos_class", "tenant", "spans", "dispatched_at", "total_us",
-                 "finished", "base_attrs")
+                 "qos_class", "tenant", "spans", "dispatched_at", "hop_at",
+                 "total_us", "finished", "base_attrs")
 
     def __init__(self, trace_id: int, ts: float, t0: float, verbs: str,
                  n_cmds: int, client_id: int):
@@ -93,6 +118,10 @@ class FrameTrace:
         self.tenant: Optional[str] = None
         self.spans: List[Span] = []
         self.dispatched_at: Optional[float] = None
+        # submit time of the executor hop in flight (one slot: a frame's
+        # hops follow one another; a sharded plan's buckets, submitted in
+        # one loop turn, share the stamp)
+        self.hop_at = t0
         self.total_us = 0
         self.finished = False
         # attrs merged into EVERY span of this frame (replica-served frames
@@ -101,7 +130,8 @@ class FrameTrace:
 
     def add_span(self, name: str, start: float, end: float,
                  **attrs) -> None:
-        """Record one stage interval ([start, end] monotonic seconds)."""
+        """Record one stage interval ([start, end] monotonic seconds).  An
+        interval that began before t0 (``recv``) keeps its negative offset."""
         if self.base_attrs:
             attrs = {**self.base_attrs, **attrs}
         self.spans.append(Span(
@@ -116,12 +146,18 @@ class FrameTrace:
         ``reply`` span (recorded by the writer task via finish_reply)."""
         self.dispatched_at = time.monotonic()
 
+    def hopped(self, to: str) -> None:
+        """A worker's first line: close the ``hop`` its submit site opened
+        (``hop_at``) — the wait for a pool thread plus the thread switch."""
+        self.add_span("hop", self.hop_at, time.monotonic(), to=to)
+
     def stage_totals(self) -> Dict[str, int]:
-        """{stage: summed µs} — the SLOWLOG breakdown projection (member
-        child spans excluded: they duplicate their kernel span's time)."""
+        """{stage: summed µs} — the SLOWLOG breakdown projection (child
+        spans excluded: ``kernel.member`` duplicates its kernel span's time,
+        the ``reply.*`` children their ``reply`` span's)."""
         out: Dict[str, int] = {}
         for s in self.spans:
-            if s.name.endswith(".member"):
+            if s.name.endswith(".member") or s.name.startswith("reply."):
                 continue
             out[s.name] = out.get(s.name, 0) + s.dur_us
         return out
@@ -136,6 +172,15 @@ class Tracer:
 
     # LATENCY HISTORY depth (Redis keeps 160 samples per event)
     LATENCY_SAMPLES = 160
+    # host-event ring: a collection enters it at GC_MIN_S (the young
+    # generation collects thousands of times a minute in tens of µs — the
+    # totals count those, the ring keeps what can explain a slow frame), a
+    # late loop wake-up at STALL_MIN_S; LONG_S is the size PERF.md gives
+    # the stalls that make p99 unboundable
+    HOST_EVENTS = 1024
+    GC_MIN_S = 0.001
+    STALL_MIN_S = 0.005
+    LONG_S = 0.040
 
     def __init__(self, ring_capacity: int = 512,
                  slowlog_max_len: int = 128,
@@ -152,6 +197,15 @@ class Tracer:
         # MetricsRegistry receiving stage.<name> timers (server wires its
         # default registry here; None = no histogram feed)
         self.registry = None
+        # host events (armed only): (kind, wall ts, monotonic start,
+        # seconds, attrs) appended at the event's END, so the ring is in
+        # end order; the monotone totals behind the host_* METRICS series
+        self._host: deque = deque(maxlen=self.HOST_EVENTS)
+        self._gc_t0: Optional[float] = None
+        self.gc_pause_s = 0.0
+        self.gc_long_pauses = 0
+        self.loop_stall_s = 0.0
+        self.loop_long_stalls = 0
 
     # -- frame lifecycle ------------------------------------------------------
 
@@ -184,25 +238,33 @@ class Tracer:
         )
         self._ring.append(trace)
         thr = self.slowlog_slower_than_us
-        if thr >= 0 and trace.total_us >= thr:
+        slow = thr >= 0 and trace.total_us >= thr
+        if slow:
+            self._annotate_host_events(trace)
+        totals = trace.stage_totals()
+        if slow:
             self._slowlog.append((
                 next(self._slowlog_ids), int(trace.ts), trace.total_us,
-                trace, trace.stage_totals(),
+                trace, totals,
             ))
         reg = self.registry
         if reg is not None:
             reg.timer("stage.total").record(trace.total_us / 1e6)
-            for stage, us in trace.stage_totals().items():
+            for stage, us in totals.items():
                 reg.timer(f"stage.{stage}").record(us / 1e6)
         self._note_latency("total", trace.ts, trace.total_us / 1e3)
-        for stage, us in trace.stage_totals().items():
+        for stage, us in totals.items():
             self._note_latency(stage, trace.ts, us / 1e3)
 
-    def finish_reply(self, trace: FrameTrace) -> None:
-        """Writer-task completion: close the ``reply`` span (dispatch-done
-        -> bytes written) and finish the trace at the write timestamp —
+    def finish_reply(self, trace: FrameTrace, write_t0: float, nbytes: int,
+                     batch: int) -> None:
+        """Writer-task completion: close ``reply.write`` (the batch's
+        ``write`` -> ``drain`` returned) and the ``reply`` span (dispatch-done
+        -> bytes written), and finish the trace at the write timestamp —
         total therefore equals the client-observable latency."""
         now = time.monotonic()
+        trace.add_span("reply.write", write_t0, now, nbytes=nbytes,
+                       batch=batch)
         start = trace.dispatched_at if trace.dispatched_at is not None else now
         trace.add_span("reply", start, now)
         self.finish(trace, end=now)
@@ -219,6 +281,62 @@ class Tracer:
                 event, deque(maxlen=self.LATENCY_SAMPLES)
             )
         dq.append((int(ts), ms))
+
+    # -- host events ----------------------------------------------------------
+
+    def note_wake(self, due: float, now: float) -> None:
+        """The loop's heartbeat was due at ``due`` and woke at ``now``
+        (server._heartbeat; one loop a server, so no two writers of these
+        totals): STALL_MIN_S or more late is a ``stall``."""
+        seconds = now - due
+        if seconds < self.STALL_MIN_S:
+            return
+        self.loop_stall_s += seconds
+        if seconds >= self.LONG_S:
+            self.loop_long_stalls += 1
+        self._host.append(("stall", time.time() - seconds, due, seconds,
+                           None))
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """``gc.callbacks`` entry (installed by set_tracing(True) only).
+        Collections never nest and run under the GIL, so the start stamp is
+        one slot; the callback takes no lock — it can fire between any two
+        bytecodes of any thread, also under this tracer's own."""
+        if phase == "start":
+            self._gc_t0 = time.monotonic()
+            return
+        t0 = self._gc_t0
+        if t0 is None:  # armed between a collection's start and its stop
+            return
+        self._gc_t0 = None
+        seconds = time.monotonic() - t0
+        self.gc_pause_s += seconds
+        if seconds >= self.GC_MIN_S:
+            if seconds >= self.LONG_S:
+                self.gc_long_pauses += 1
+            self._host.append(("gc", time.time() - seconds, t0, seconds,
+                               {"gen": info.get("generation", -1)}))
+
+    def _annotate_host_events(self, trace: FrameTrace) -> None:
+        """A slow frame gets one ``host.gc`` / ``host.stall`` span for each
+        ring event that overlapped it, clipped to the frame.  Newest first,
+        and done at the first event that ended before the frame began: only
+        slow frames pay, and no registry of frames in flight is needed.
+        (A copy is walked: a collection may append to the ring meanwhile.)"""
+        t0 = trace.t0
+        end = t0 + trace.total_us / 1e6
+        for kind, _ts, start, seconds, attrs in reversed(list(self._host)):
+            if start + seconds <= t0:
+                break
+            if start < end:
+                trace.add_span("host." + kind, max(start, t0),
+                               min(start + seconds, end), **(attrs or {}))
+
+    def host_events(self, n: Optional[int] = None) -> List[tuple]:
+        """Newest-first: [(kind, wall ts, seconds, attrs), ...]."""
+        items = [(k, ts, sec, attrs)
+                 for k, ts, _start, sec, attrs in reversed(list(self._host))]
+        return items if n is None else items[: max(0, n)]
 
     # -- queries --------------------------------------------------------------
 
@@ -238,6 +356,7 @@ class Tracer:
 
     def reset(self) -> None:
         self._ring.clear()
+        self._host.clear()
 
     def set_ring_capacity(self, n: int) -> None:
         n = max(1, int(n))
@@ -288,28 +407,7 @@ class Tracer:
                 n += 1
         return n
 
-    # -- summaries ------------------------------------------------------------
-
-    def stage_summary(self) -> Dict[str, Dict[str, float]]:
-        """{stage: {count, total_ms, p50_ms, p99_ms}} over the current ring
-        — bench's ``details.stage_breakdown`` source."""
-        import numpy as np
-
-        per: Dict[str, List[int]] = {}
-        for tr in list(self._ring):
-            for stage, us in tr.stage_totals().items():
-                per.setdefault(stage, []).append(us)
-            per.setdefault("total", []).append(tr.total_us)
-        out: Dict[str, Dict[str, float]] = {}
-        for stage, vals in per.items():
-            a = np.asarray(vals, np.float64) / 1e3
-            out[stage] = {
-                "count": len(vals),
-                "total_ms": round(float(a.sum()), 3),
-                "p50_ms": round(float(np.percentile(a, 50)), 3),
-                "p99_ms": round(float(np.percentile(a, 99)), 3),
-            }
-        return out
+    # -- census ---------------------------------------------------------------
 
     def census(self) -> Dict[str, float]:
         """Census rows: ring occupancy is BOUNDED by capacity; inflight
@@ -327,10 +425,7 @@ TRACER = Tracer()
 
 # THE guard every instrumentation site loads: None = disarmed (zero-cost),
 # TRACER = armed.  Same shape as net/client.py `_fault_plane`.
-_tracer: Optional[Tracer] = (
-    TRACER if os.environ.get("RTPU_TRACE", "") in ("1", "true", "yes")
-    else None
-)
+_tracer: Optional[Tracer] = None
 
 
 def tracing_enabled() -> bool:
@@ -343,7 +438,18 @@ def set_tracing(on: bool) -> bool:
     global _tracer
     prev = _tracer is not None
     _tracer = TRACER if on else None
+    # the gc callback exists only while armed: disarmed, a collection
+    # calls nothing of ours
+    if on and TRACER._on_gc not in gc.callbacks:
+        gc.callbacks.append(TRACER._on_gc)
+    elif not on and TRACER._on_gc in gc.callbacks:
+        gc.callbacks.remove(TRACER._on_gc)
+        TRACER._gc_t0 = None
     return prev
+
+
+if os.environ.get("RTPU_TRACE", "") in ("1", "true", "yes"):
+    set_tracing(True)
 
 
 def current_trace() -> Optional[FrameTrace]:

@@ -83,12 +83,17 @@ class _TracedEncoded:
     """Pre-encoded frame bytes carrying their FrameTrace (tracing ARMED
     only — disarmed frames enqueue plain bytes, exactly as before): the
     writer task writes `data` and closes the trace's `reply` span, making
-    the trace total the true client-observable latency."""
+    the trace total the true client-observable latency.  Dispatch ends and
+    `reply.encode` is recorded HERE; `put_at` (encode done, about to be
+    queued) opens the frame's `reply.wait` for the writer task to close."""
 
-    __slots__ = ("data", "trace")
+    __slots__ = ("data", "trace", "put_at")
 
-    def __init__(self, data: bytes, trace):
-        self.data = data
+    def __init__(self, results: list, proto: int, trace):
+        trace.mark_dispatched()
+        self.data = _encode_frame(results, proto)
+        self.put_at = time.monotonic()
+        trace.add_span("reply.encode", trace.dispatched_at, self.put_at)
         self.trace = trace
 
 
@@ -113,6 +118,7 @@ def _force_lazies(results: list, server, trace=None) -> None:
     from redisson_tpu.server.registry import gather_lazy_device_results
 
     if trace is not None:
+        trace.hopped("force")
         _obs.set_current(trace)
 
     def fail(i, e):
@@ -290,6 +296,24 @@ class TpuServer:
             "trace_inflight",
             lambda: self.tracer.census()["trace_inflight"],
         )
+        # the host's pauses (armed only; monotone, so a scraper's
+        # after-minus-before is the window's): collections, and wake-ups of
+        # this server's event loop that came late (_heartbeat)
+        self.metrics.gauge(
+            "host_gc_pause_seconds_total", lambda: self.tracer.gc_pause_s
+        )
+        self.metrics.gauge(
+            "host_gc_long_pauses_total", lambda: self.tracer.gc_long_pauses
+        )
+        self.metrics.gauge(
+            "host_loop_stall_seconds_total", lambda: self.tracer.loop_stall_s
+        )
+        self.metrics.gauge(
+            "host_loop_long_stalls_total",
+            lambda: self.tracer.loop_long_stalls,
+        )
+        self._heartbeat_task = None
+        self.heartbeat_wakes = 0
         # orphaned RESP3 pushes (ISSUE 12 satellite bugfix): the process-
         # global drop counter was census-only — a fleet scrape could never
         # see a desync-avoided push drop.  Now a first-class gauge.
@@ -1398,6 +1422,7 @@ class TpuServer:
         lane/readback spans land on the frame; laneless dispatches record
         their own `dispatch` span (the lane gate records it otherwise)."""
         if trace is not None:
+            trace.hopped("dispatch")
             _obs.set_current(trace)
         try:
             gate = self._occupancy_gate((cmd,), qos_class)
@@ -1435,6 +1460,7 @@ class TpuServer:
         replies extend in frame order, so per-connection FIFO and reply
         bytes are identical to the unsplit dispatch."""
         if trace is not None:
+            trace.hopped("dispatch")
             _obs.set_current(trace)
         try:
             lane = self._lane_for(cmds)
@@ -1504,6 +1530,7 @@ class TpuServer:
         ):
             return _Encoded(resp.encode_error("ERR bad request frame"))
         if trace is not None:
+            trace.hopped("dispatch")
             _obs.set_current(trace)
             t0 = time.monotonic()
             try:
@@ -1545,6 +1572,7 @@ class TpuServer:
         bucket still coalesce into one stacked-bank kernel (now guaranteed
         single-device).  Returns [(frame_index, result), ...]."""
         if trace is not None:
+            trace.hopped("dispatch")
             _obs.set_current(trace)
             try:
                 return self._dispatch_device_bucket(
@@ -1661,11 +1689,15 @@ class TpuServer:
                         )
                         else self._pool_for(adm)
                     )
+                    if trace is not None:
+                        trace.hop_at = time.monotonic()
                     results[i] = await loop.run_in_executor(
                         pool, self._dispatch_one_sync, ctx, cmd, trace
                     )
                 continue
             jobs = []
+            if trace is not None:
+                trace.hop_at = time.monotonic()  # the buckets share it
             for dev_index, idxs in seg.items():
                 self.stats["commands"] += len(idxs)
                 jobs.append(loop.run_in_executor(
@@ -1932,6 +1964,7 @@ class TpuServer:
                         return False
                     if trace is not None:
                         trace.mark_dispatched()
+                        trace.hop_at = trace.dispatched_at
                     fut = loop.run_in_executor(
                         self._pool_for(adm), _force_lazies, results, self,
                         trace,
@@ -1940,15 +1973,16 @@ class TpuServer:
                         _PendingFrame(results, fut, ctx.proto, trace)
                     )
                     return True
+                if trace is not None:
+                    trace.hop_at = time.monotonic()
                 await loop.run_in_executor(
                     self._pool_for(adm), _force_lazies, results, self, trace
                 )
             if results:
                 if trace is not None:
-                    trace.mark_dispatched()
-                    write_q.put_nowait(_TracedEncoded(
-                        _encode_frame(results, ctx.proto), trace
-                    ))
+                    write_q.put_nowait(
+                        _TracedEncoded(results, ctx.proto, trace)
+                    )
                 else:
                     write_q.put_nowait(_encode_frame(results, ctx.proto))
             return True
@@ -1984,6 +2018,8 @@ class TpuServer:
             if run_end is not None:
                 run_cmds = commands[ci:run_end]
                 self.stats["commands"] += len(run_cmds)
+                if trace is not None:
+                    trace.hop_at = time.monotonic()
                 results.extend(
                     await loop.run_in_executor(
                         self._pool_for(adm), self._dispatch_bloom_run_laned,
@@ -2005,6 +2041,8 @@ class TpuServer:
                 if bytes(cmd[0]).upper() in _SLOW_COMMANDS
                 else self._pool_for(adm)
             )
+            if trace is not None:
+                trace.hop_at = time.monotonic()
             try:
                 results.append(
                     await loop.run_in_executor(
@@ -2047,12 +2085,15 @@ class TpuServer:
                     return False  # connection is going down; stop dispatching
                 if trace is not None:
                     trace.mark_dispatched()
+                    trace.hop_at = trace.dispatched_at
                 fut = loop.run_in_executor(
                     self._pool_for(adm), _force_lazies, results, self, trace
                 )
                 write_q.put_nowait(_PendingFrame(results, fut, ctx.proto,
                                                  trace))
                 return True
+            if trace is not None:
+                trace.hop_at = time.monotonic()
             await loop.run_in_executor(
                 self._pool_for(adm), _force_lazies, results, self, trace
             )
@@ -2060,10 +2101,7 @@ class TpuServer:
             # one queue item per frame — the whole frame's replies
             # encode in one pass and write in one syscall batch
             if trace is not None:
-                trace.mark_dispatched()
-                write_q.put_nowait(_TracedEncoded(
-                    _encode_frame(results, ctx.proto), trace
-                ))
+                write_q.put_nowait(_TracedEncoded(results, ctx.proto, trace))
             else:
                 write_q.put_nowait(_encode_frame(results, ctx.proto))
         return True
@@ -2115,7 +2153,10 @@ class TpuServer:
             # Tracing (armed only): traced items carry their FrameTrace;
             # once the batch's bytes are written+drained each trace closes
             # its `reply` span HERE — the trace total is therefore the true
-            # client-observable latency.  A trace whose bytes never reach
+            # client-observable latency, and the span's children say what
+            # the tail was: `reply.wait` (the readback future, or the time
+            # queued here), `reply.encode`, `reply.write` (write -> drain
+            # returned, shared by the batch).  A trace whose bytes never reach
             # the wire (pool death, connection error) is abandoned so the
             # inflight census row still drains.
             held = None  # a _PendingFrame popped while coalescing bytes
@@ -2154,13 +2195,24 @@ class TpuServer:
                                 return
                             finally:
                                 readback_slots.release()
-                            parts.append(item.encoded())
                             if item.trace is not None:
+                                tr = item.trace
+                                t_got = time.monotonic()
+                                parts.append(item.encoded())
+                                tr.add_span("reply.wait", tr.dispatched_at,
+                                            t_got)
+                                tr.add_span("reply.encode", t_got,
+                                            time.monotonic())
                                 if done_tr is None:
                                     done_tr = []
-                                done_tr.append(item.trace)
+                                done_tr.append(tr)
+                            else:
+                                parts.append(item.encoded())
                         elif isinstance(item, _TracedEncoded):
                             parts.append(item.data)
+                            item.trace.add_span(
+                                "reply.wait", item.put_at, time.monotonic()
+                            )
                             if done_tr is None:
                                 done_tr = []
                             done_tr.append(item.trace)
@@ -2174,7 +2226,10 @@ class TpuServer:
                             break
                         item = nxt
                     if parts:
-                        writer.write(parts[0] if len(parts) == 1 else b"".join(parts))
+                        payload = parts[0] if len(parts) == 1 else b"".join(parts)
+                        if done_tr is not None:
+                            t_write = time.monotonic()
+                        writer.write(payload)
                         try:
                             await writer.drain()
                         except ConnectionError:
@@ -2184,7 +2239,9 @@ class TpuServer:
                             return
                         if done_tr is not None:
                             for t in done_tr:
-                                _obs.TRACER.finish_reply(t)
+                                _obs.TRACER.finish_reply(
+                                    t, t_write, len(payload), len(parts)
+                                )
                     if final:
                         return
             finally:
@@ -2194,6 +2251,11 @@ class TpuServer:
                     readback_slots.release()
 
         wt = asyncio.create_task(writer_task())
+        # `recv` (tracing armed only): reads, first read's return, bytes and
+        # fruitless-feed seconds of the frame now arriving; rx_n == 0 = the
+        # next read brings a frame's first byte
+        rx_n = rx_bytes = 0
+        rx_t0 = rx_feed = 0.0
         try:
             while True:
                 data = await reader.read(1 << 16)
@@ -2209,6 +2271,16 @@ class TpuServer:
                 t_parse0 = (
                     time.monotonic() if _obs._tracer is not None else None
                 )
+                if t_parse0 is None:
+                    rx_n = 0
+                else:
+                    if rx_n == 0:
+                        # bytes already buffered (armed mid-frame) are the
+                        # frame's too
+                        rx_t0, rx_bytes = t_parse0, parser.pending_bytes
+                        rx_feed = 0.0
+                    rx_n += 1
+                    rx_bytes += len(data)
                 try:
                     commands = parser.feed(data)
                 except ProtocolError as e:
@@ -2219,10 +2291,26 @@ class TpuServer:
                     trace = _obs._tracer.begin_frame(
                         ctx, commands, t0=t_parse0
                     )
+                    if t_parse0 is not None:
+                        # the frame's arrival lies BEFORE its t0 (the read
+                        # that completed it): a negative offset, so `parse`,
+                        # `reply` and the total keep their meaning
+                        left = parser.pending_bytes
+                        trace.add_span(
+                            "recv", rx_t0, t_parse0, reads=rx_n,
+                            nbytes=rx_bytes - left,
+                            feed_us=int(rx_feed * 1e6),
+                        )
+                        # the next frame's first bytes rode this read too
+                        rx_n = 1 if left else 0
+                        rx_t0, rx_bytes, rx_feed = t_parse0, left, 0.0
                     if self.role == "replica":
                         # per-stage replica annotation (ISSUE 17): every
                         # span of a replica-served frame carries replica=1
                         trace.base_attrs = {"replica": 1}
+                elif t_parse0 is not None and not commands:
+                    # a feed that completed nothing is receive-side work
+                    rx_feed += time.monotonic() - t_parse0
                 try:
                     ok = await self._serve_frame(
                         ctx, commands, loop, write_q, readback_slots, alive,
@@ -2313,7 +2401,26 @@ class TpuServer:
         )
         if self.port == 0:
             self.port = self._server.sockets[0].getsockname()[1]
+        self._heartbeat_task = self._loop.create_task(self._heartbeat())
         return self
+
+    async def _heartbeat(self):
+        """The event loop's own pulse.  Tracing armed: sleep 10 ms at a
+        time and record, as a `stall` host event, every wake-up that came
+        5 ms or more late — whatever kept the loop from running (a GC, a
+        worker holding the GIL, a long synchronous call on the loop).
+        Disarmed: one wake a second to look at the guard, nothing recorded
+        — so arming from a worker thread (CONFIG SET trace-enabled yes)
+        needs no cross-thread plumbing and takes effect within a second."""
+        while True:
+            self.heartbeat_wakes += 1
+            if _obs._tracer is None:
+                await asyncio.sleep(1.0)
+                continue
+            due = time.monotonic() + 0.010
+            await asyncio.sleep(0.010)
+            if _obs._tracer is not None:
+                _obs._tracer.note_wake(due, time.monotonic())
 
     async def serve_forever(self):
         await self.start_async()
@@ -2395,6 +2502,8 @@ class TpuServer:
         if loop is not None and server is not None:
             def shutdown():
                 server.close()
+                if self._heartbeat_task is not None:
+                    self._heartbeat_task.cancel()
                 # drop established connections too: clients must see a dead
                 # node, not a half-alive one (failover tests depend on this)
                 for w in list(self._writers):

@@ -1021,14 +1021,20 @@ def cmd_metrics(server, ctx, args):
 # -- tracing plane verbs (ISSUE 12: TRACE / SLOWLOG / LATENCY) ----------------
 
 
+def _attrs_wire(attrs) -> list:
+    """A span's or a host event's flat attrs on the wire: [k, v, ...]."""
+    out = []
+    if attrs:
+        for k, v in attrs.items():
+            out.append(k.encode())
+            out.append(v if isinstance(v, int) else str(v).encode())
+    return out
+
+
 def _span_wire(span) -> list:
     """One stage span on the wire: [name, off_us, dur_us, [k, v, ...]]."""
-    attrs = []
-    if span.attrs:
-        for k, v in span.attrs.items():
-            attrs.append(k.encode())
-            attrs.append(v if isinstance(v, int) else str(v).encode())
-    return [span.name.encode(), span.off_us, span.dur_us, attrs]
+    return [span.name.encode(), span.off_us, span.dur_us,
+            _attrs_wire(span.attrs)]
 
 
 def _trace_wire(tr) -> list:
@@ -1044,10 +1050,18 @@ def _trace_wire(tr) -> list:
 
 @register("TRACE")
 def cmd_trace(server, ctx, args):
-    """TRACE GET [n] [BY total|<stage>] | RESET | CONFIG GET|SET k v —
-    the per-frame span ring over the wire.  GET returns the slowest-n
-    finished traces ordered by total duration (or by one stage's summed
-    duration: BY qos / readback / dispatch / ...), each a full span tree.
+    """TRACE GET [n] [BY total|<stage>] | EVENTS [n] | RESET | CONFIG
+    GET|SET k v — the per-frame span ring over the wire.  GET returns the
+    slowest-n finished traces ordered by total duration (or by one stage's
+    summed duration: BY qos / readback / dispatch / hop / recv / host.gc /
+    ...), each a full span tree: [name, off_us, dur_us, attrs] a span,
+    offsets from the read that completed the frame (`recv`, the frame's
+    arrival, lies before it: a negative offset), `reply.wait` /
+    `reply.encode` / `reply.write` inside `reply`, and on a slow frame one
+    `host.gc` / `host.stall` for each host pause it overlapped.  EVENTS
+    returns the host-event ring, newest first: [kind, unix_ms, dur_us,
+    attrs] with kind `gc` (a collection of 1 ms or more, `gen`) or `stall`
+    (the event loop woke 5 ms or more late).  RESET clears both rings.
     Empty while tracing is disarmed (CONFIG SET trace-enabled yes arms)."""
     sub = bytes(args[0]).upper() if args else b"GET"
     tracer = server.tracer
@@ -1063,6 +1077,13 @@ def cmd_trace(server, ctx, args):
                 raise RespError("ERR TRACE GET ... BY needs a stage name")
             by = _s(rest[1])
         return [_trace_wire(t) for t in tracer.slowest(n, by=by)]
+    if sub == b"EVENTS":
+        n = _int(args[1]) if len(args) > 1 else None
+        return [
+            [kind.encode(), int(ts * 1000), int(seconds * 1e6),
+             _attrs_wire(attrs)]
+            for kind, ts, seconds, attrs in tracer.host_events(n)
+        ]
     if sub == b"RESET":
         tracer.reset()
         return "+OK"
@@ -1084,7 +1105,7 @@ def cmd_trace(server, ctx, args):
                 )
             return "+OK"
         raise RespError("ERR TRACE CONFIG expects GET|SET")
-    raise RespError("ERR TRACE expects GET|RESET|CONFIG")
+    raise RespError("ERR TRACE expects GET|EVENTS|RESET|CONFIG")
 
 
 @register("SLOWLOG")
@@ -1118,7 +1139,8 @@ def cmd_slowlog(server, ctx, args):
 def cmd_latency(server, ctx, args):
     """LATENCY HISTORY <event> | RESET [event ...] | LATEST — Redis parity
     over the per-STAGE samples the tracer collects (events are stage names:
-    total, qos, dispatch, stage, kernel, readback, reply)."""
+    total, recv, parse, qos, hop, dispatch, stage, kernel, readback, reply,
+    and host.gc / host.stall from the slow frames that overlapped a pause)."""
     sub = bytes(args[0]).upper() if args else b""
     tracer = server.tracer
     if sub == b"HISTORY":

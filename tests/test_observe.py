@@ -328,6 +328,18 @@ def test_trace_get_waterfall_attributes_qos_wait_and_readback():
     assert int_qos and max(int_qos) < max(bulk_qos_waits), (
         "interactive frames waited on the bulk gate — attribution is wrong"
     )
+    # the frame's anchors keep their meaning under the closed waterfall:
+    # `parse` starts at offset 0 (t0 = the read that completed the frame),
+    # `recv` lies before it and ends there, and `reply`, `reply.write` and
+    # the total all end at the write
+    for e in entries:
+        sp = spans_of(e)
+        assert sp["parse"][1] == 0, e
+        assert sp["recv"][1] <= 0 and abs(sp["recv"][1] + sp["recv"][2]) <= 2, e
+        assert abs(sp["reply"][1] + sp["reply"][2] - e[2]) <= 2, e
+        assert abs(sp["reply.write"][1] + sp["reply.write"][2] - e[2]) <= 2, e
+    _assert_hops_lead_somewhere(entries)
+    _assert_reply_children(entries)
     # coalesced bulk runs recorded ONE kernel span with member children
     kernel_entries = [
         e for e in entries
@@ -338,6 +350,293 @@ def test_trace_get_waterfall_attributes_qos_wait_and_readback():
         members = [s for s in e[7] if bytes(s[0]) == b"kernel.member"]
         kernels = [s for s in e[7] if bytes(s[0]) == b"kernel"]
         assert len(kernels) >= 1 and len(members) >= 2
+
+
+# -- the closed waterfall: recv, hop, the inside of reply ----------------------
+
+
+def _named(entry, name):
+    return [s for s in entry[7] if bytes(s[0]) == name]
+
+
+def _attrs(span):
+    return {bytes(span[3][i]).decode(): span[3][i + 1]
+            for i in range(0, len(span[3]), 2)}
+
+
+def _assert_hops_lead_somewhere(entries):
+    """Every hop names where it went and ends at or before the start of
+    the `dispatch` (`to=force`: the `readback`) it leads to; a frame with a
+    `dispatch` crossed an executor, so it has a hop."""
+    for e in entries:
+        hops = _named(e, b"hop")
+        if _named(e, b"dispatch"):
+            assert hops, e
+        for h in hops:
+            to = bytes(_attrs(h)["to"])
+            assert to in (b"dispatch", b"force"), e
+            end = h[1] + h[2]
+            if to == b"dispatch":
+                assert any(d[1] >= end for d in _named(e, b"dispatch")), e
+            else:
+                assert all(r[1] >= end for r in _named(e, b"readback")), e
+
+
+def _assert_reply_children(entries):
+    """`reply.wait`, `reply.encode`, `reply.write`: one each, inside
+    `reply`, not overlapping (2 us for the integer offsets)."""
+    for e in entries:
+        (reply,) = _named(e, b"reply")
+        kids = sorted(
+            (s for s in e[7] if bytes(s[0]).startswith(b"reply.")),
+            key=lambda s: (s[1], s[1] + s[2]),
+        )
+        assert sorted(bytes(s[0]) for s in kids) == [
+            b"reply.encode", b"reply.wait", b"reply.write"], e
+        assert kids[0][1] >= reply[1] - 2, e
+        assert kids[-1][1] + kids[-1][2] <= reply[1] + reply[2] + 2, e
+        for x, y in zip(kids, kids[1:]):
+            assert x[1] + x[2] <= y[1] + 2, e
+        w = _attrs(_named(e, b"reply.write")[0])
+        assert w["nbytes"] > 0 and w["batch"] >= 1, e
+
+
+def _traced_mixed_frames(st):
+    """A coalesced run, a lone laned command, readbacks (overlapped) and
+    readback-free frames, traced: the shapes every dispatch wrapper and
+    both writer-queue item kinds see."""
+    blob = np.ascontiguousarray(
+        np.arange(2_000, dtype=np.int64) * 2654435761, "<i8"
+    ).tobytes()
+    conn = _conn(st)
+    try:
+        conn.execute("BF.RESERVE", "cw:bf", 0.01, 50_000)
+        conn.execute_many([
+            ("SET", "cw:k", b"v"),
+            ("BF.MADD64", "cw:bf", blob),
+            ("BF.MADD64", "cw:bf", blob),
+            ("BF.MEXISTS64", "cw:bf", blob),
+            ("PING",),
+        ], timeout=60.0)
+        conn.execute("BF.MEXISTS64", "cw:bf", blob)
+        conn.execute("PING")
+        conn.execute("GET", "cw:k")
+        time.sleep(0.1)
+        return conn.execute("TRACE", "GET", "100", timeout=30.0)
+    finally:
+        conn.close()
+
+
+def test_recv_span_covers_a_frame_that_arrives_in_three_writes():
+    """A frame's arrival is a span of its own, BEFORE t0: the frame's total
+    and `parse` keep their meaning, and the pauses between the sender's
+    writes are in `recv`, not in nothing."""
+    import socket
+
+    from redisson_tpu.net import resp
+
+    obs.set_tracing(True)
+    obs.TRACER.reset()
+    payload = resp.encode_command("SET", "rx:k", b"x" * 300_000)
+    cuts = (0, 100_000, 200_000, len(payload))
+    with ServerThread(port=0) as st:
+        sock = socket.create_connection((st.server.host, st.server.port))
+        try:
+            sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
+            for lo, hi in zip(cuts, cuts[1:]):
+                if lo:
+                    time.sleep(0.15)
+                sock.sendall(payload[lo:hi])
+            got = b""
+            while not got.endswith(b"\r\n"):
+                got += sock.recv(64)
+            assert got == b"+OK\r\n"
+        finally:
+            sock.close()
+        wire = _conn(st)
+        try:
+            time.sleep(0.1)
+            entries = wire.execute("TRACE", "GET", "20")
+        finally:
+            wire.close()
+    (e,) = [e for e in entries if bytes(e[3]) == b"SET"]
+    (recv,) = _named(e, b"recv")
+    a = _attrs(recv)
+    assert a["reads"] >= 3 and a["nbytes"] == len(payload), a
+    assert a["feed_us"] >= 0
+    # two pauses of 150 ms lie inside it (less what a busy host took to
+    # make the first read); it ends where the frame begins
+    assert recv[1] < 0 and recv[2] >= 240_000, recv
+    assert abs(recv[1] + recv[2]) <= 2, recv
+    (parse,) = _named(e, b"parse")
+    assert parse[1] == 0
+    # total_us is still completing-read -> reply written: no pause in it
+    assert e[2] < 140_000, e
+    assert all(s[1] >= 0 for s in e[7] if bytes(s[0]) != b"recv"), e
+
+
+def test_every_executor_crossing_has_a_hop():
+    obs.set_tracing(True)
+    obs.TRACER.reset()
+    with ServerThread(port=0, workers=4) as st:
+        entries = _traced_mixed_frames(st)
+    assert len(entries) >= 5
+    _assert_hops_lead_somewhere(entries)
+    tos = {bytes(_attrs(h)["to"]) for e in entries for h in _named(e, b"hop")}
+    assert tos == {b"dispatch", b"force"}, tos
+    # the pipelined frame crosses once for SET, once for the coalesced run,
+    # once for the probe, once for PING and once more to force — if it
+    # arrived whole; cut in two by the socket, its larger part still hops
+    # once a dispatch
+    big = max(entries, key=lambda e: e[4])
+    assert big[4] >= 3 and len(_named(big, b"hop")) >= 2, big
+
+
+def test_reply_children_lie_inside_reply_and_stage_totals_skip_them():
+    obs.set_tracing(True)
+    obs.TRACER.reset()
+    obs.TRACER.slowlog_slower_than_us = 0  # every frame into SLOWLOG
+    with ServerThread(port=0, workers=4) as st:
+        assert st.server.overlap
+        entries = _traced_mixed_frames(st)
+        conn = _conn(st)
+        try:
+            slow = conn.execute("SLOWLOG", "GET", "50")
+        finally:
+            conn.close()
+    _assert_reply_children(entries)
+    with_readback = [e for e in entries if _named(e, b"readback")]
+    without = [e for e in entries if not _named(e, b"readback")]
+    assert with_readback and without
+    for e in with_readback:
+        # the overlapped readback is what the writer task waited for
+        (wait,) = _named(e, b"reply.wait")
+        (rb,) = _named(e, b"readback")
+        assert wait[1] <= rb[1] and rb[1] + rb[2] <= wait[1] + wait[2] + 2, e
+    # `reply` is counted once: the children are in no per-stage projection
+    for tr in obs.TRACER.entries():
+        totals = tr.stage_totals()
+        assert "reply" in totals and "recv" in totals
+        assert not [k for k in totals if k.startswith("reply.")], totals
+    stages = {bytes(s[0]) for entry in slow for s in entry[4]}
+    assert b"reply" in stages and b"hop" in stages
+    assert not [s for s in stages if s.startswith(b"reply.")], stages
+
+
+# -- the host's pauses: gc and a blocked event loop ----------------------------
+
+
+def _host_series(conn):
+    return {
+        line.split()[0]: float(line.split()[1])
+        for line in bytes(conn.execute("METRICS")).decode().splitlines()
+        if line.startswith("rtpu_host_")
+    }
+
+
+def _big_heap():
+    """Enough containers that a full collection takes well over 40 ms."""
+    return [[i] for i in range(2_500_000)]
+
+
+def test_host_pauses_are_counted_listed_and_put_on_the_slow_frame():
+    import gc
+
+    obs.set_tracing(True)
+    obs.TRACER.reset()
+    assert obs.TRACER._on_gc in gc.callbacks
+    junk = _big_heap()
+    with ServerThread(port=0) as st:
+        conn = _conn(st)
+        waiter = _conn(st)
+        try:
+            before = _host_series(conn)
+            assert set(before) == {
+                "rtpu_host_gc_pause_seconds_total",
+                "rtpu_host_gc_long_pauses_total",
+                "rtpu_host_loop_stall_seconds_total",
+                "rtpu_host_loop_long_stalls_total",
+            }
+            time.sleep(0.05)  # the heartbeat is on its 10 ms pace by now
+            # a frame that is in flight (parked in BLPOP on a worker) while
+            # the host pauses twice: a full collection, then a synchronous
+            # sleep on the server's own loop
+            parked = waiter.execute_many_lazy([("BLPOP", "hp:q", "20")])
+            time.sleep(0.1)
+            # (gc.collect() returns at once while another thread collects —
+            # the big heap makes automatic passes long too: ask until a long
+            # pause has ENDED with the frame parked)
+            longs = obs.TRACER.gc_long_pauses
+            deadline = time.monotonic() + 20.0
+            while (obs.TRACER.gc_long_pauses == longs
+                   and time.monotonic() < deadline):
+                gc.collect()
+            time.sleep(0.05)
+            st.server._loop.call_soon_threadsafe(time.sleep, 0.06)
+            time.sleep(0.15)
+            conn.execute("RPUSH", "hp:q", b"x")
+            assert parked.get(timeout=30.0) == [[b"hp:q", b"x"]]
+            time.sleep(0.1)
+            after = _host_series(conn)
+            events = conn.execute("TRACE", "EVENTS")
+            slowest = conn.execute("TRACE", "GET", "5", "BY", "host.stall")
+        finally:
+            conn.close()
+            waiter.close()
+    del junk
+    for name in before:
+        assert after[name] > before[name], (name, before, after)
+    gcs = [ev for ev in events if bytes(ev[0]) == b"gc"]
+    stalls = [ev for ev in events if bytes(ev[0]) == b"stall"]
+    # [kind, unix_ms, dur_us, attrs], newest first
+    assert any(ev[2] >= 40_000 and ev[3] == [b"gen", 2] for ev in gcs), gcs
+    assert any(ev[2] >= 40_000 for ev in stalls), stalls
+    assert all(ev[1] > 1_600_000_000_000 for ev in events)
+    ends = [ev[1] + ev[2] / 1000 for ev in events]  # newest (by end) first
+    assert all(x >= y - 2 for x, y in zip(ends, ends[1:])), events
+    (frame,) = [e for e in slowest if bytes(e[3]) == b"BLPOP"]
+    hgc = [s for s in _named(frame, b"host.gc") if s[2] >= 40_000]
+    hst = [s for s in _named(frame, b"host.stall") if s[2] >= 40_000]
+    assert hgc and hst, frame
+    assert _attrs(hgc[0])["gen"] == 2
+    for s in hgc + hst:  # clipped to the frame
+        assert s[1] >= 0 and s[1] + s[2] <= frame[2], (s, frame[2])
+    # TRACE RESET clears the event ring too
+    assert obs.TRACER.host_events()
+    obs.TRACER.reset()
+    assert not obs.TRACER.host_events()
+
+
+def test_disarmed_host_plane_installs_and_records_nothing():
+    import gc
+
+    obs.set_tracing(False)
+    obs.TRACER.reset()
+    assert obs.TRACER._on_gc not in gc.callbacks
+    junk = _big_heap()
+    with ServerThread(port=0) as st:
+        conn = _conn(st)
+        try:
+            before = _host_series(conn)
+            wakes = st.server.heartbeat_wakes
+            t0 = time.monotonic()
+            gc.collect()
+            st.server._loop.call_soon_threadsafe(time.sleep, 0.06)
+            conn.execute("PING")
+            time.sleep(max(0.0, 2.0 - (time.monotonic() - t0)))
+            assert st.server.heartbeat_wakes - wakes <= 3
+            assert _host_series(conn) == before
+            assert conn.execute("TRACE", "EVENTS") == []
+        finally:
+            conn.close()
+    del junk
+    assert not obs.TRACER.host_events()
+    # arming installs the one callback, once; disarming takes it away
+    obs.set_tracing(True)
+    obs.set_tracing(True)
+    assert gc.callbacks.count(obs.TRACER._on_gc) == 1
+    obs.set_tracing(False)
+    assert obs.TRACER._on_gc not in gc.callbacks
 
 
 # -- SLOWLOG parity verbs ------------------------------------------------------

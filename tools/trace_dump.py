@@ -52,8 +52,10 @@ def render_trace(entry, width: int = WIDTH) -> str:
         name = _b(name)
         if name.endswith(".member"):
             continue  # members duplicate their kernel span's interval
-        lo = min(width, int(int(off_us) * width / total_us))
-        ln = max(1, int(int(dur_us) * width / total_us))
+        # the bar shows the part inside [0, total]: `recv` lies before 0
+        off_us, end_us = max(0, int(off_us)), int(off_us) + int(dur_us)
+        lo = min(width, int(off_us * width / total_us))
+        ln = max(1, int(max(0, end_us - off_us) * width / total_us))
         bar = "." * lo + "#" * min(ln, width - lo)
         bar += " " * (width - len(bar))
         extra = ""
@@ -64,7 +66,7 @@ def render_trace(entry, width: int = WIDTH) -> str:
             ]
             extra = "  " + ",".join(kv)
         lines.append(
-            f"  {name:<9}{int(dur_us) / 1000:>8.1f}ms |{bar}|{extra}"
+            f"  {name:<12}{int(dur_us) / 1000:>8.1f}ms |{bar}|{extra}"
         )
     return "\n".join(lines)
 
