@@ -133,6 +133,7 @@ class BloomFilterArray(RExpirable):
             bits, newly = K.bloom_bank_add_packed(
                 rec.arrays["bits"], tlh, K.valid_n(n), rec.meta["k"], rec.meta["m"]
             )
+            K.count_rows(n, tlh.shape[1])  # one-shot: the bucket is walked
             rec.arrays["bits"] = bits
             self._touch_version(rec)
         return newly, n
@@ -153,6 +154,7 @@ class BloomFilterArray(RExpirable):
             bits, count = K.bloom_bank_add_packed_count(
                 rec.arrays["bits"], tlh, K.valid_n(n), rec.meta["k"], rec.meta["m"]
             )
+            K.count_rows(n, tlh.shape[1])  # one-shot: the bucket is walked
             rec.arrays["bits"] = bits
             self._touch_version(rec)
         return count
@@ -178,6 +180,7 @@ class BloomFilterArray(RExpirable):
             found = K.bloom_bank_contains_packed_bits(
                 rec.arrays["bits"], tlh, K.valid_n(n), rec.meta["k"], rec.meta["m"]
             )
+            K.count_rows(n, K.rows_issued(n, tlh.shape[1]))
         return found, n
 
     # -- window submission (multi-flush, single transfer) --------------------
@@ -272,6 +275,7 @@ class BloomFilterArray(RExpirable):
             packed = K.bloom_bank_contains_packed_bits(
                 rec.arrays["bits"], tlh, K.valid_n(total), rec.meta["k"], rec.meta["m"]
             )
+            K.count_rows(sum(lengths), total)  # n_valid covers the whole window
         return packed, bb, lengths
 
     def contains_flushes(self, flushes) -> list:
@@ -291,6 +295,7 @@ class BloomFilterArray(RExpirable):
             bits, newly = K.bloom_bank_add_packed_bits(
                 rec.arrays["bits"], tlh, K.valid_n(total), rec.meta["k"], rec.meta["m"]
             )
+            K.count_rows(sum(lengths), total)
             rec.arrays["bits"] = bits
             self._touch_version(rec)
         return newly, bb, lengths

@@ -62,6 +62,7 @@ class HyperLogLogArray(RExpirable):
         with self._engine.locked(self._name):
             rec = self._rec()
             rec.arrays["regs"] = K.hll_bank_add_packed(rec.arrays["regs"], tlh, K.valid_n(n), rec.meta["p"])
+            K.count_rows(n, b)  # one-shot: the bucket is walked
             self._touch_version(rec)
 
     def merge_rows(self, dst_ids, src_ids) -> None:

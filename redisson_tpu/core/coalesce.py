@@ -214,6 +214,7 @@ def fused_bloom_contains_async(engine, names: Sequence[str], keys_list):
         recs, m, k = _validated_records(engine, names)
         planes = jnp.stack([r.arrays["bits"] for r in recs])
         found = K.bloom_bank_contains_packed(planes, tlh, K.valid_n(n), k, m)
+        K.count_rows(n, K.rows_issued(n, tlh.shape[1]))
     return found, lengths
 
 
@@ -235,6 +236,7 @@ def fused_bloom_add_async(engine, names: Sequence[str], keys_list):
         recs, m, k = _validated_records(engine, names)
         planes = jnp.stack([r.arrays["bits"] for r in recs])
         bits2d, newly = K.bloom_bank_add_packed(planes, tlh, K.valid_n(n), k, m)
+        K.count_rows(n, tlh.shape[1])
         for i, rec in enumerate(recs):
             rec.arrays["bits"] = bits2d[i]
             rec.version += 1

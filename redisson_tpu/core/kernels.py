@@ -22,16 +22,24 @@ plane far larger than VMEM.  Pallas on TPU has no vectorized gather (only
 `pl.ds` slice-style dynamic indexing), so a hand-written probe kernel
 degenerates to a scalar loop or a one-hot matmul whose one-hot operand is
 O(batch x plane_rows) — both strictly worse than XLA's native gather unit.
-Microbenchmarks (bank contains, 114k keys x k=7 over a (1000, 96256) plane,
-v5e): XLA flat gather ~21us; blocked row-gather variants 20-30us; the whole
-flush is transfer-bound (~ms), not kernel-bound.  The elementwise hash chain
-fuses into the gather kernel under XLA already.  Pallas remains the right
-tool for the mesh collectives' custom overlap if profiling ever shows XLA's
-psum/pmax lagging (see parallel/sharded.py) — not for these probes.
+What the chip reads (v5e device trace; ledger PR 22/24, micro-benchmark
+PR 25): the bank probe of a 100,000-key flush over a (1000, 96256) plane is
+7.05 ms one-shot, 92 % of it the byte gather at 8.1 ns an index — the same
+per index for one gather of 802,816 or 49 of 14,336.  The served bulk cell is
+kernel-bound on it (chip busy 98.7 %), so rows cost device time, not h2d
+bytes: see _map_valid_chunks.  (An earlier "~21 us, transfer-bound" figure
+here was a host-clock timing of the enqueue through a remote transport.)
+The elementwise hash chain fuses into the gather kernel under XLA already.
+Pallas remains the right tool for the mesh collectives' custom overlap if
+profiling ever shows XLA's psum/pmax lagging (see parallel/sharded.py), and
+for a probe only as a different algorithm (VMEM-resident bit-packed rows, a
+tenant-grouped matmul) — ROADMAP Queue 1, SK.
 """
 from __future__ import annotations
 
 import functools
+import threading as _threading
+from collections import OrderedDict as _OrderedDict
 
 import jax
 import jax.numpy as jnp
@@ -72,12 +80,16 @@ def pow2_bucket(n: int, minimum: int = MIN_BUCKET) -> int:
 
 
 def bucket_size(n: int, minimum: int = MIN_BUCKET) -> int:
-    """Padded batch size for the transfer-bound fast paths.
+    """Padded batch size of a flush: one static operand shape, one h2d and
+    one compiled program a bucket.
 
-    Pow2 bucketing wastes up to 2x of host->device bandwidth on padding.
-    This uses 1/8-octave steps instead: next multiple of
-    (next_pow2(n) / 8) — at most 12.5% padding, at most 8 compiled programs
-    per octave in the jit cache.
+    1/8-octave steps: next multiple of (next_pow2(n) / 8) — at most 12.5%
+    padding, at most 8 compiled programs per octave in the jit cache.  The
+    padding's bytes are cheap (a 1.4 MB h2d); its ROWS are not, where a
+    one-shot body walks them: 8.1 ns a gathered index, 0.85 ms of a 7.05 ms
+    100,000-key probe (v5e trace; ledger PR 22/24).  Bodies that can, stop
+    at n_valid instead (_map_valid_chunks); a finer ladder would buy the same
+    for up to four times the programs an octave.
     """
     if n <= minimum:
         return minimum
@@ -98,8 +110,77 @@ def _valid_mask(n: int, n_valid) -> jax.Array:
     return jnp.arange(n, dtype=jnp.int32) < n_valid
 
 
-import threading as _threading
-from collections import OrderedDict as _OrderedDict
+# Device work follows n_valid, not the bucket.  A bucket fixes the operand's
+# shape (one h2d, one compiled program); what it pads is still gathered by a
+# one-shot body, and at 8.1 ns an index that is device time: a 100,000-key
+# probe in its 114,688 bucket spent 12.8 % of its gathers on rows nobody
+# sent.  Large buckets therefore run as a loop over CHUNK-row slices whose
+# trip count the device computes from n_valid.  Chosen from the device trace
+# (v5e, n = 100,000, one-shot 7.051 ms): 1,024 -> 6.287 ms, 2,048 -> 6.216,
+# 4,096 -> 6.301, 8,192 -> 6.564; a step costs ~1 us of its own.
+CHUNK = 2048            # a multiple of 32, so _pack_bool_u32 words line up
+CHUNKED_MIN_CHUNKS = 8  # below 8 * CHUNK rows the one-shot body runs as ever
+
+
+def chunked(b: int) -> bool:
+    """Whether a bucket of `b` rows runs as the chunk loop.  Decided from the
+    operand's static shape alone: every bucket_size bucket of 16,384 rows or
+    more is a multiple of CHUNK."""
+    return b >= CHUNKED_MIN_CHUNKS * CHUNK and b % CHUNK == 0
+
+
+def rows_issued(n: int, b: int) -> int:
+    """Rows the device walks for `n` valid rows in a bucket of `b`, in a
+    body that runs the chunk loop."""
+    return -(-n // CHUNK) * CHUNK if chunked(b) else b
+
+
+_ROWS_LOCK = _threading.Lock()
+_rows_valid = 0
+_rows_issued = 0
+
+
+def count_rows(n: int, issued: int) -> None:
+    """One bank dispatch: `n` rows a caller sent, `issued` rows the device
+    walks for them (rows_issued(n, b) where the body runs the chunk loop,
+    the bucket where it is one-shot).  Two integer adds, always on; METRICS
+    exports the sums (kernel_rows_valid_total, kernel_rows_issued_total)."""
+    global _rows_valid, _rows_issued
+    with _ROWS_LOCK:  # server worker threads dispatch side by side
+        _rows_valid += n
+        _rows_issued += issued
+
+
+def rows_counted() -> tuple:
+    """(valid, issued) row totals of this process's bank dispatches."""
+    return _rows_valid, _rows_issued
+
+
+def _map_valid_chunks(rows, n_valid, body):
+    """THE one expression of the policy: found[c] = body(*rows[c], n_valid -
+    start of c) for the CHUNK-row slices c of `rows` (parallel 1-D arrays of
+    one bucket) that hold a valid row — ceil(n_valid / CHUNK) of them, a trip
+    count the device reads from n_valid, so a new n in the same bucket
+    compiles nothing.  `body` masks the tail of the last chunk itself; chunks
+    past it are never visited and read back as zeros (not found).
+
+    Only for bodies whose rows are independent of each other: the probes.
+    NOT the bloom adds: their `newly` flags come from a gather taken before
+    the flush's own scatter, and a key repeated across two chunks would see
+    its first add — another reply, not a faster one.  Nor the HLL bank's
+    scatter-max, though max would allow any cut: inside a loop XLA:TPU
+    scatters at 86 ns a row against the one-shot's 1 ms + 9.5 ns (v5e trace:
+    9.8 ms looped, 3.3 ms one-shot, 100,000 rows into the 164 MB bank).
+    Both stay one-shot."""
+
+    def one(i, found):
+        start = i * CHUNK
+        chunk = [jax.lax.dynamic_slice(r, (start,), (CHUNK,)) for r in rows]
+        return jax.lax.dynamic_update_slice(found, body(*chunk, n_valid - start), (start,))
+
+    return jax.lax.fori_loop(0, (n_valid + (CHUNK - 1)) // CHUNK, one,
+                             jnp.zeros(rows[0].shape, jnp.bool_))
+
 
 _N_CACHE: "_OrderedDict" = _OrderedDict()
 _N_CACHE_MAX = 4096
@@ -200,12 +281,22 @@ def _bloom_bank_add_body(bits2d, tenant, lo, hi, n_valid, k: int, m: int):
     return new_flat.reshape(bits2d.shape), newly
 
 
-def _bloom_bank_contains_body(bits2d, tenant, lo, hi, n_valid, k: int, m: int):
+def _bloom_bank_probe(flat, width: int, tenant, lo, hi, n_valid, k: int, m: int):
+    """Probe every row against the flat plane (row stride `width`); rows at or
+    past n_valid gather too and are masked in the result."""
     h1, h2 = H.hash_u64_pair(lo, hi, jnp)
     idx = H.bloom_indexes(h1, h2, k, m, jnp)
-    g = tenant[:, None] * bits2d.shape[1] + idx
-    got = bits2d.reshape(-1).at[g].get(mode="fill", fill_value=1)
+    g = tenant[:, None] * width + idx
+    got = flat.at[g].get(mode="fill", fill_value=1)
     return jnp.all(got != 0, axis=-1) & _valid_mask(lo.shape[0], n_valid)
+
+
+def _bloom_bank_contains_body(bits2d, tenant, lo, hi, n_valid, k: int, m: int):
+    # the flat view is formed once, outside the loop
+    probe = functools.partial(_bloom_bank_probe, bits2d.reshape(-1), bits2d.shape[1], k=k, m=m)
+    if chunked(lo.shape[0]):
+        return _map_valid_chunks((tenant, lo, hi), n_valid, probe)
+    return probe(tenant, lo, hi, n_valid)
 
 
 bloom_bank_add_u64 = jax.jit(_bloom_bank_add_body, static_argnums=(5, 6), donate_argnums=(0,))
@@ -464,6 +555,7 @@ def _hll_bank_add_body(regs2d, tenant, lo, hi, n_valid, p: int):
     size = regs2d.shape[0] * m
     mask = _valid_mask(lo.shape[0], n_valid)
     g = jnp.where(mask, tenant * m + idx, size)  # flat fast path (see bloom bank)
+    # one-shot whatever the bucket: see _map_valid_chunks
     new_flat = regs2d.reshape(-1).at[g].max(rho, mode="drop")
     return new_flat.reshape(regs2d.shape)
 

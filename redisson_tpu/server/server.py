@@ -312,6 +312,16 @@ class TpuServer:
             "host_loop_long_stalls_total",
             lambda: self.tracer.loop_long_stalls,
         )
+        # rows the bank kernels were sent against rows the device walked for
+        # them (core/kernels.py count_rows): what a bucket's padding costs
+        from redisson_tpu.core import kernels as _K
+
+        self.metrics.gauge(
+            "kernel_rows_valid_total", lambda: _K.rows_counted()[0]
+        )
+        self.metrics.gauge(
+            "kernel_rows_issued_total", lambda: _K.rows_counted()[1]
+        )
         self._heartbeat_task = None
         self.heartbeat_wakes = 0
         # orphaned RESP3 pushes (ISSUE 12 satellite bugfix): the process-
