@@ -160,11 +160,15 @@ def group_by_slot_owner(
 COALESCIBLE_BLOB_VERBS = frozenset((b"BF.MADD64", b"BF.MEXISTS64"))
 
 
-def coalescible_frame_runs(cmds: List[Any]) -> List[Tuple[int, int]]:
-    """Maximal [start, end) runs (len >= 2) of CONSECUTIVE same-verb
+def coalescible_frame_runs(cmds: List[Any], min_len: int = 2
+                           ) -> List[Tuple[int, int]]:
+    """Maximal [start, end) runs (len >= `min_len`) of CONSECUTIVE same-verb
     coalescible blob commands in one pipelined frame.  Pure scan: the server
     frame loop replaces each run with a single fused dispatch; everything
-    outside the runs dispatches per command, so frame order is untouched."""
+    outside the runs dispatches per command, so frame order is untouched.
+    A device-sharded server asks for `min_len` 1: which commands of a frame
+    stand alone on a device is the frame's composition, and a lone command
+    dispatched per record would be a program of its own on every lane."""
     def verb_of(cmd) -> Optional[bytes]:
         # malformed frames carry non-bytes elements (nested arrays, ints);
         # they are NOT runs — the per-command path replies their errors
@@ -186,7 +190,7 @@ def coalescible_frame_runs(cmds: List[Any]) -> List[Tuple[int, int]]:
         j = i + 1
         while j < n and verb_of(cmds[j]) == verb:
             j += 1
-        if j - i >= 2:
+        if j - i >= min_len:
             out.append((i, j))
         i = j
     return out

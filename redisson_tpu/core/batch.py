@@ -169,6 +169,8 @@ class Batch:
             # add-then-contains hot pair on one filter (one fused program) —
             # run boundaries never cross a verb change, so the ordering
             # contract is untouched; ineligible runs fall back per group.
+            from redisson_tpu.core.coalesce import stacked_prefix
+
             items = list(groups.items())
             i = 0
             while i < len(items):
@@ -178,6 +180,11 @@ class Batch:
                     j = i + 1
                     while j < len(items) and items[j][0][1] == verb:
                         j += 1
+                    # one stacked dispatch holds so many filters and keys;
+                    # the rest of a longer run is the next iteration's run
+                    j = i + stacked_prefix(
+                        [sum(op.n for op in g_ops) for _g, g_ops in items[i:j]]
+                    )
                     if j - i >= 2 and _try_fused_run(
                         self._engine, verb, items[i:j], pending
                     ):

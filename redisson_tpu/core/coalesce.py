@@ -9,14 +9,28 @@ pays the fixed XLA-dispatch overhead (~10-100us on-chip), so a 64-filter
 wave pays it 64 times for work one kernel could do.
 
 This module fuses such a run into ONE kernel call: filters that share
-geometry (same m, k, hash, physical plane size) are stacked into a (F, S)
-bank on device, every op's keys concatenate into one packed (3, B) transfer
-buffer whose first row is the SEGMENT SLOT (which filter each key probes),
-and the existing bank kernels (core/kernels.py — flat `slot*stride + idx`
-indexing) execute the whole run.  Results scatter back to each issuer by
-segment offset.  The stack itself is an HBM-side copy (F*S bytes), cheap
-next to F dispatch overheads; adds write each filter's row back under the
-same locked_many window that ordered the dispatch.
+geometry (same m, k, hash, physical plane size) are stacked into a small
+bank inside the program, every op's keys concatenate into one packed (3, B)
+transfer buffer whose first row is the SEGMENT SLOT (which filter each key
+probes), and the bank kernels' bodies (core/kernels.py — flat
+`slot*stride + idx` indexing) execute the whole run.  Results scatter back
+to each issuer by segment offset; adds write each filter's new plane back
+under the same locked_many window that ordered the dispatch.
+
+ONE SHAPE A GEOMETRY.  Which filters of a frame share a device, and where a
+socket read cut the frame, change with every frame; a program whose shape
+followed them (F planes, the 1/8-octave row bucket of 100 x F rows) was a
+new XLA compile nearly every frame.  A stacked dispatch therefore always
+stacks STACK_PLANES planes — the run's F, padded by repeating its first
+plane; no row names a padding plane and none is written back — and pads its
+rows to one of the four STACK_ROW_BUCKETS.  The stack is an HBM-side copy of
+STACK_PLANES x S bytes (6 MB for 10,000-key filters), cheap next to F
+dispatch overheads on a device the host cannot keep busy.  Longer runs are
+cut at command boundaries into several dispatches (plan_stacked_chunks); a
+command with more rows than the largest bucket is dispatched alone, as any
+command outside a run is.  The first stacked dispatch of a geometry compiles
+the whole set — two verbs x four row buckets — on every device of the
+placement (_warm_geometry), so no later frame meets a cold program.
 
 Semantics preserved exactly:
   * per-issuer results: segment offsets are computed host-side from the
@@ -30,13 +44,14 @@ Semantics preserved exactly:
     per-group dispatch takes per name.
 
 Ineligible runs (mixed geometry, codec keys, missing records, duplicate add
-names, int32 flat-index overflow) raise CoalesceIneligible — callers fall
-back to the per-group path, so coalescing is a pure fast path, never a
-semantics change.
+names, planes too large to stack, more planes or rows than one stacked
+dispatch holds) raise CoalesceIneligible — callers fall back to the
+per-group path, so coalescing is a pure fast path, never a semantics change.
 """
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple
+import threading
+from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -114,6 +129,69 @@ def plan_subwindows(items: Sequence[int], target: int) -> List[Tuple[int, int]]:
     return out
 
 
+# -- the stacked shape -----------------------------------------------------------
+# Chosen on the v5e (PERF.md section 6, PR 26): the device is idle in every
+# cell that reaches this path, so padding costs microseconds of an idle chip
+# and each shape it saves is a compile a frame would have waited for.
+STACK_PLANES = 16
+STACK_ROW_BUCKETS = (256, 1024, 4096, 16384)
+# planes larger than this are not stacked: STACK_PLANES copies of one would
+# hold more HBM than the dispatch overhead they save is worth
+STACK_MAX_PLANE_CELLS = (64 << 20) // STACK_PLANES
+
+
+def stacked_row_bucket(n: int) -> Optional[int]:
+    """The row bucket a stacked dispatch of `n` rows pads to; None when no
+    bucket holds them."""
+    return next((b for b in STACK_ROW_BUCKETS if n <= b), None)
+
+
+def stacked_prefix(lengths: Sequence[int]) -> int:
+    """How many leading commands of a run (rows a command in `lengths`) one
+    stacked dispatch holds: at most STACK_PLANES planes and the largest row
+    bucket.  At least 1 — a command too long for any bucket stands alone
+    and is dispatched per record."""
+    rows = 0
+    for i, n in enumerate(lengths[:STACK_PLANES]):
+        rows += n
+        if rows > STACK_ROW_BUCKETS[-1]:
+            return max(i, 1)
+    return min(len(lengths), STACK_PLANES)
+
+
+def plan_stacked_chunks(lengths: Sequence[int]) -> List[Tuple[int, int]]:
+    """Cut one coalescible run into [start, end) chunks one stacked dispatch
+    each can hold, at command boundaries and in order (stacked_prefix)."""
+    out: List[Tuple[int, int]] = []
+    start = 0
+    while start < len(lengths):
+        end = start + stacked_prefix(lengths[start:])
+        out.append((start, end))
+        start = end
+    return out
+
+
+_PLANES_LOCK = threading.Lock()
+_planes_asked = 0
+_planes_stacked = 0
+
+
+def planes_counted() -> tuple:
+    """(asked, stacked) plane totals of this process's stacked dispatches:
+    planes the runs named against planes the device stacked for them.
+    METRICS exports both (coalesce_planes_asked_total,
+    coalesce_planes_stacked_total), always on, as kernels.count_rows does
+    for rows."""
+    return _planes_asked, _planes_stacked
+
+
+def _count_planes(asked: int) -> None:
+    global _planes_asked, _planes_stacked
+    with _PLANES_LOCK:  # server worker threads dispatch side by side
+        _planes_asked += asked
+        _planes_stacked += STACK_PLANES
+
+
 def _concat_segments(engine, keys_list) -> Tuple[np.ndarray, np.ndarray, List[int]]:
     """Concatenate per-op int-key arrays into one preallocated buffer plus an
     aligned segment-slot column.  Returns (slot, keys, lengths)."""
@@ -180,9 +258,43 @@ def _validated_records(engine, names: Sequence[str]):
                 )
             device = device if device is not None else d
         recs.append(rec)
-    if len(names) * shape[0] > K.BANK_MAX_CELLS:
-        raise CoalesceIneligible("stacked planes exceed flat int32 index space")
+    if shape[0] > STACK_MAX_PLANE_CELLS:
+        raise CoalesceIneligible("planes too large to stack")
     return recs, m, k
+
+
+_WARM: set = set()
+_WARM_LOCK = threading.Lock()
+
+
+def _warm_geometry(plane, k: int, m: int) -> None:
+    """Compile every stacked program of this geometry before any is needed:
+    both verbs at every row bucket, on every device that can own such a
+    filter (ioplane.warm_targets: the plane's own, and the other lanes of a
+    device-sharded engine) — and the grouped fetch's programs for their
+    results.  Which bucket a run pads to and which device it lands on follow
+    from a frame's composition, so a set compiled as met would have its
+    rarer members met late, by a frame that then waits seconds for the
+    compiler.  Run once a (device, geometry): empty windows over the plane
+    itself (a copy of it on the other lanes), results dropped."""
+    from redisson_tpu.core import ioplane
+
+    kind = (plane.shape[0], k, m)
+    targets = ioplane.warm_targets(plane)
+    if all(ioplane.target_key(t, kind) in _WARM for t in targets):
+        return
+    with _WARM_LOCK:
+        for target in targets:
+            key = ioplane.target_key(target, kind)
+            if key in _WARM:
+                continue
+            planes = (ioplane.stand_in(plane, target[0]),) * STACK_PLANES
+            for b in STACK_ROW_BUCKETS:
+                tlh = K.stage(np.zeros((3, b), np.uint32))
+                found = K.bloom_stack_contains_packed(planes, tlh, K.valid_n(0), k, m)
+                K.bloom_stack_add_packed(planes, tlh, K.valid_n(0), k, m)
+                ioplane.warm_stack_class(found)  # newly-added flags alike
+            _WARM.add(key)
 
 
 def _pack_window(engine, slot: np.ndarray, keys: np.ndarray, device=None):
@@ -192,53 +304,74 @@ def _pack_window(engine, slot: np.ndarray, keys: np.ndarray, device=None):
     placement on, `device` selects that device's LANE pool so two devices'
     waves never contend on one slot pair (ISSUE 8)."""
     n = keys.shape[0]
-    b = K.bucket_size(n)
+    b = stacked_row_bucket(n)
     lo, hi = H.int_keys_to_u32_pair(keys)
     return K.pack_rows(slot, lo, hi, size=b, pool=engine.staging_pool(device)), n
 
 
-def fused_bloom_contains_async(engine, names: Sequence[str], keys_list):
-    """ONE dispatch for a contains run over several same-geometry filters.
-
-    Returns (device bool array over the concatenated window, lengths) —
-    slice issuer i's reply at [sum(lengths[:i]), +lengths[i]).  No host
-    sync: callers force on their own result path (frame-level gather on
-    the server, np.asarray in the batch layer)."""
+def _stacked_window(engine, names: Sequence[str], keys_list):
+    """The packed window of one stacked dispatch, or CoalesceIneligible when
+    the run is more than one holds (callers cut runs with
+    plan_stacked_chunks before they get here)."""
+    if len(names) > STACK_PLANES:
+        raise CoalesceIneligible("more filters than one stacked dispatch holds")
     slot, keys, lengths = _concat_segments(engine, keys_list)
+    if stacked_row_bucket(keys.shape[0]) is None:
+        raise CoalesceIneligible("more rows than one stacked dispatch holds")
     tlh, n = _pack_window(
         engine, slot, keys, device=engine.device_for_name(names[0])
     )
-    import jax.numpy as jnp
+    return tlh, n, lengths
 
+
+def _stacked_planes(recs, k: int, m: int) -> tuple:
+    """The run's planes padded to STACK_PLANES by repeating the first: a
+    padding plane is read by no row (segment slots stop at the run's last
+    filter) and changes no answer.  Counts the padding, and compiles the
+    geometry's programs if this is its first stacked dispatch."""
+    planes = [r.arrays["bits"] for r in recs]
+    _warm_geometry(planes[0], k, m)
+    _count_planes(len(planes))
+    return tuple(planes) + (planes[0],) * (STACK_PLANES - len(planes))
+
+
+def fused_bloom_contains_async(engine, names: Sequence[str], keys_list):
+    """ONE dispatch for a contains run over at most STACK_PLANES
+    same-geometry filters (kernels.bloom_stack_contains_packed).
+
+    Returns (device bool array over the concatenated window, padded to its
+    row bucket; lengths) — slice issuer i's reply at [sum(lengths[:i]),
+    +lengths[i]).  No host sync: callers force on their own result path
+    (frame-level gather on the server, np.asarray in the batch layer) and
+    slice on the host — a device-side slice a reply would be a program an
+    offset."""
+    tlh, n, lengths = _stacked_window(engine, names, keys_list)
     with engine.locked_many(set(names)):
         recs, m, k = _validated_records(engine, names)
-        planes = jnp.stack([r.arrays["bits"] for r in recs])
-        found = K.bloom_bank_contains_packed(planes, tlh, K.valid_n(n), k, m)
+        planes = _stacked_planes(recs, k, m)
+        found = K.bloom_stack_contains_packed(planes, tlh, K.valid_n(n), k, m)
         K.count_rows(n, K.rows_issued(n, tlh.shape[1]))
     return found, lengths
 
 
 def fused_bloom_add_async(engine, names: Sequence[str], keys_list):
-    """ONE dispatch for an add run over several DISTINCT same-geometry
-    filters; writes each filter's new plane row back under the run's locks.
-    Returns (device newly-added bool array, lengths)."""
+    """ONE dispatch for an add run over at most STACK_PLANES DISTINCT
+    same-geometry filters (kernels.bloom_stack_add_packed); writes each
+    filter's new plane back under the run's locks — the run's own planes
+    only, never a padding plane's.  Returns (device newly-added bool array,
+    lengths)."""
     if len(set(names)) != len(names):
         raise CoalesceIneligible(
             "duplicate filter in add run (second group must observe the first)"
         )
-    slot, keys, lengths = _concat_segments(engine, keys_list)
-    tlh, n = _pack_window(
-        engine, slot, keys, device=engine.device_for_name(names[0])
-    )
-    import jax.numpy as jnp
-
+    tlh, n, lengths = _stacked_window(engine, names, keys_list)
     with engine.locked_many(set(names)):
         recs, m, k = _validated_records(engine, names)
-        planes = jnp.stack([r.arrays["bits"] for r in recs])
-        bits2d, newly = K.bloom_bank_add_packed(planes, tlh, K.valid_n(n), k, m)
+        planes = _stacked_planes(recs, k, m)
+        new_planes, newly = K.bloom_stack_add_packed(planes, tlh, K.valid_n(n), k, m)
         K.count_rows(n, tlh.shape[1])
-        for i, rec in enumerate(recs):
-            rec.arrays["bits"] = bits2d[i]
+        for rec, plane in zip(recs, new_planes):  # stops at the run's last
+            rec.arrays["bits"] = plane
             rec.version += 1
     return newly, lengths
 

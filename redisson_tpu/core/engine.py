@@ -447,7 +447,12 @@ class Engine:
     def _place_record(self, name: str, rec) -> None:
         """DeviceStore placement hook: commit the record's single-device
         arrays to the slot's owner.  Multi-device (mesh-sharded) planes are
-        never touched — the parallel/ layer owns their layout."""
+        never touched — the parallel/ layer owns their layout.  An array
+        that merely sits on its owner (uncommitted: every new array starts
+        on the default device) is committed there too, without a copy: a
+        jitted program is compiled for where its operands are committed, so
+        the default device's lane would otherwise run a set of programs of
+        its own, which no warm-up made for the other lanes covers."""
         p = self.placement
         if p is None:
             return
@@ -458,7 +463,7 @@ class Engine:
             devs = getattr(arr, "devices", None)
             if devs is not None:
                 ds = devs()
-                if len(ds) != 1 or ds == {device}:
+                if len(ds) != 1 or (ds == {device} and arr.committed):
                     continue  # sharded plane, or already home
             elif not isinstance(arr, np.ndarray):
                 continue  # host-side state (lists/dicts) never places

@@ -677,80 +677,253 @@ def _readback_guard(dev_id: Optional[int], parts: Sequence[Any]) -> None:
             time.sleep(min(0.002, left))
 
 
-def gather_device_results(groups: Sequence[Sequence[Any]]) -> List[tuple]:
-    """Fetch every device value of `groups` with ONE device->host transfer
-    PER DEVICE: bitcast each value to a uint8 byte stream on device,
-    concatenate per device, pull each device's merged stream (concurrently
-    when results span several devices), split and reinterpret on the host.
-    G groups at one transfer each would pay G sync latencies — this path
-    pays ~one per touched device, and the per-device fetches overlap.
-    Constraint: each device value's dtype must round-trip via
-    ``np.dtype(a.dtype.name)``."""
+# -- the grouped fetch's fixed shapes ---------------------------------------------
+# A frame over many tenants owes each device dozens of small values (previous
+# bits, lengths, found vectors), their number and order the frame's
+# composition.  Merging them with one concatenate was a new XLA program for
+# every composition (and two eager ops a part before it: 25.7 ms for a
+# 16-tenant frame's 50 parts on a v5e host).  Parts of one dtype and shape
+# are stacked instead, by ONE jitted program whose operand count is padded to
+# a rung below, and every stack and every lone part crosses with an
+# asynchronous copy started before the first is waited for: 1.3 ms for the
+# same 50 parts, against 4.2 ms for an asynchronous copy of every part and
+# 1.1 ms for a program a composition (v5e, PERF.md section 6, PR 26).
+GATHER_STACK_RUNGS = (4, 16, 64)
+GATHER_STACK_MAX_BYTES = 1 << 20  # larger parts cross alone: the copy is the cost
+
+_STACK_WARM: set = set()
+_STACK_WARM_LOCK = threading.Lock()
+
+_GATHER_BYTES_LOCK = threading.Lock()
+_gather_bytes_owed = 0
+_gather_bytes_fetched = 0
+
+
+def gather_bytes_counted() -> tuple:
+    """(owed, fetched) byte totals of this process's grouped fetches: bytes
+    the replies are made from against bytes brought to the host for them (a
+    stack's padding, the rest of a value several replies cut their rows
+    from).  METRICS exports both (gather_bytes_owed_total,
+    gather_bytes_fetched_total), always on."""
+    return _gather_bytes_owed, _gather_bytes_fetched
+
+
+@functools.lru_cache(maxsize=None)
+def _stack_program():
     import jax
     import jax.numpy as jnp
 
-    flat = []  # (device uint8 stream, host dtype, orig shape, was_bool)
-    index: List[List[int]] = []  # per group: flat positions
-    for group in groups:
+    return jax.jit(lambda *xs: jnp.stack(xs))
+
+
+def _stack_parts(*parts):
+    return _stack_program()(*parts)
+
+
+def warm_targets(value) -> list:
+    """[(device, committed)] a warm-up for `value`'s kind has to cover:
+    where `value` sits, placed as it is, and — committed, as placement
+    commits a lane's records — every other device that serves a lane beside
+    that one (none on an engine without placement).  A jitted program is
+    compiled for where its operands are committed, so a warm-up call runs on
+    stand-ins placed as the values they stand in for."""
+    own = device_of(value)
+    own_id = getattr(own, "id", None)
+    beside = [
+        lane.device for ls in list(_LANE_SETS) if own_id in ls._lanes
+        for lane in ls.lanes() if lane.dev_id != own_id
+    ]
+    return [(own, bool(getattr(value, "committed", False)))] + [
+        (dev, True) for dev in dict.fromkeys(beside)
+    ]
+
+
+def stand_in(value, device):
+    """`value` itself where it sits, else a copy committed to `device`."""
+    import jax
+
+    return value if device == device_of(value) else jax.device_put(value, device)
+
+
+def target_key(target, kind) -> tuple:
+    device, committed = target
+    return (getattr(device, "id", None), committed, kind)
+
+
+def warm_stack_class(part) -> None:
+    """Compile the stack program of `part`'s dtype and shape at every rung
+    before any is needed, on every warm target (device-sharded serving:
+    which lane first owes two values of a kind, and how many, is a frame's
+    composition).  Called for every kind of value a several-part fetch
+    meets, stacked or not, and by producers that know their results' shapes
+    ahead (core/coalesce.py): a kind first met in warm-up traffic has its
+    programs before the traffic that counts."""
+    kind = (part.dtype.name, part.shape)
+    targets = warm_targets(part)
+    if all(target_key(t, kind) in _STACK_WARM for t in targets):
+        return
+    with _STACK_WARM_LOCK:
+        for target in targets:
+            key = target_key(target, kind)
+            if key in _STACK_WARM:
+                continue
+            operand = stand_in(part, target[0])
+            for rung in GATHER_STACK_RUNGS:
+                _stack_parts(*([operand] * rung))
+            _STACK_WARM.add(key)
+
+
+def _fetch_one_part(part):
+    """The one-part fetch, as it has always been: the value as a uint8
+    stream (bool via uint8, other dtypes bitcast), one transfer, viewed back
+    on the host."""
+    import jax
+    import jax.numpy as jnp
+
+    was_bool = part.dtype == jnp.bool_
+    if was_bool:
+        b = part.astype(jnp.uint8)  # exact: values are 0/1
+    elif part.dtype == jnp.uint8:
+        b = part
+    else:
+        b = jax.lax.bitcast_convert_type(part, jnp.uint8)
+    merged = np.asarray(jnp.ravel(b))
+    if was_bool:
+        return merged.reshape(part.shape).astype(bool)
+    return merged.view(np.dtype(part.dtype.name)).reshape(part.shape)
+
+
+def _fetch_parts(parts: Sequence[Any]) -> Tuple[list, int, int, int]:
+    """Several values of one device -> (host arrays, bytes brought,
+    transfers made, largest stack rung used)."""
+    classes: "dict[tuple, List[int]]" = {}
+    for i, part in enumerate(parts):
+        classes.setdefault((part.dtype.name, part.shape), []).append(i)
+    crossing = []  # (device value, the positions it carries, stacked?)
+    top = GATHER_STACK_RUNGS[-1]
+    widest = 0
+    for members in classes.values():
+        first = parts[members[0]]
+        if first.nbytes > GATHER_STACK_MAX_BYTES:
+            crossing += [(parts[i], [i], False) for i in members]
+            continue
+        warm_stack_class(first)
+        if len(members) == 1:
+            crossing.append((first, members, False))
+            continue
+        for at in range(0, len(members), top):
+            some = members[at : at + top]
+            rung = next(r for r in GATHER_STACK_RUNGS if len(some) <= r)
+            stack = _stack_parts(
+                *[parts[i] for i in some], *([first] * (rung - len(some)))
+            )
+            crossing.append((stack, some, True))
+            widest = max(widest, rung)
+    for value, _at, _stacked in crossing:
+        value.copy_to_host_async()
+    host: list = [None] * len(parts)
+    fetched = 0
+    for value, positions, stacked in crossing:
+        got = np.asarray(value)
+        fetched += got.nbytes
+        for row, i in enumerate(positions):
+            host[i] = got[row, ...] if stacked else got  # an array also where 0-d
+    return host, fetched, len(crossing), widest
+
+
+def gather_device_results(groups: Sequence[Sequence[Any]],
+                          owed: Optional[Sequence[Optional[int]]] = None,
+                          note: Optional[dict] = None) -> List[tuple]:
+    """Fetch every device value of `groups`, each device's share with a
+    number of transfers that does not grow with the number of values, and
+    with a bounded set of XLA programs whatever their number, order and
+    shapes.  A value named by several groups crosses once.  Per device:
+
+      * one value, no lanes: bitcast to a uint8 stream, one transfer, viewed
+        back on the host — the historical single-result fetch, unchanged
+        (its two eager programs are a pair a kind of value AND a device:
+        where lanes serve, a lane that owes one value is a frame's
+        composition, and the value crosses as the lone ones below do);
+      * several: values of one dtype and shape are stacked by one jitted
+        program at a padded operand count (GATHER_STACK_RUNGS; the class's
+        rungs are compiled together, on every lane, when it is first met),
+        lone and large values cross as they are, and every crossing is an
+        asynchronous copy started before the first is waited for.
+
+    Devices fetch concurrently.  G groups at one blocking transfer each
+    would pay G sync latencies (~0.4 ms each on a v5e host).  numpy values
+    pass through.  `owed[i]` is the number of bytes group i's reply is made
+    from where that is less than its values hold (rows of a shared result);
+    it feeds gather_bytes_counted.  `note` (tracing armed) receives `parts`,
+    `fetches` and the largest stack `bucket`."""
+    global _gather_bytes_owed, _gather_bytes_fetched
+
+    uniq: List[Any] = []     # device values, each once
+    seen: "dict[int, int]" = {}
+    index: List[List[Any]] = []  # per group: position in uniq, or the value
+    owed_total = 0
+    for gi, group in enumerate(groups):
         pos = []
+        own = 0
         for arr in group:
-            a = jnp.asarray(arr)
-            was_bool = a.dtype == jnp.bool_
-            if was_bool:
-                b = a.astype(jnp.uint8)  # exact: values are 0/1
-            elif a.dtype == jnp.uint8:
-                b = a
-            else:
-                b = jax.lax.bitcast_convert_type(a, jnp.uint8)
-            pos.append(len(flat))
-            flat.append((
-                jnp.ravel(b),
-                np.dtype(a.dtype.name if not was_bool else "uint8"),
-                a.shape,
-                was_bool,
-            ))
+            if not hasattr(arr, "copy_to_host_async"):  # already on the host
+                pos.append(np.asarray(arr))
+                continue
+            at = seen.get(id(arr))
+            if at is None:
+                at = seen[id(arr)] = len(uniq)
+                uniq.append(arr)
+            pos.append(at)
+            own += arr.nbytes
         index.append(pos)
-    if not flat:
-        return [() for _ in groups]
-    # bucket flat positions by committed device: cross-device streams can
-    # neither concatenate nor ride one transfer — each device gets its own
-    # merged stream (device-sharded serving, ISSUE 8).  The common single-
-    # device case degenerates to exactly the historical one-transfer shape.
+        less = owed[gi] if owed is not None else None
+        owed_total += own if less is None else min(less, own)
+    if not uniq:
+        return [tuple(pos) for pos in index]
+    # bucket by committed device: cross-device values can neither stack nor
+    # ride one transfer — each device fetches its own share (device-sharded
+    # serving, ISSUE 8)
     buckets: "dict[Optional[int], List[int]]" = {}
-    for fi, (part, _d, _s, _b) in enumerate(flat):
-        buckets.setdefault(_device_id_of(part), []).append(fi)
+    for ui, part in enumerate(uniq):
+        buckets.setdefault(_device_id_of(part), []).append(ui)
 
-    host: List[Any] = [None] * len(flat)
+    host: List[Any] = [None] * len(uniq)
+    tally = [(0, 0, 0)] * len(buckets)  # (bytes, transfers, rung) a device
 
-    def fetch_bucket(dev_id, fis) -> None:
-        parts = [flat[fi][0] for fi in fis]
+    def fetch_bucket(bi: int, dev_id, uis) -> None:
+        parts = [uniq[ui] for ui in uis]
         _readback_guard(dev_id, parts)
-        sizes = [int(p.shape[0]) for p in parts]
         STATS.count_sync()
         if dev_id is not None:
             device_stats(dev_id).count_sync()
-        if len(parts) == 1:
-            merged = np.asarray(parts[0])
-            chunks = [merged]
+        if len(parts) == 1 and not any(dev_id in ls._lanes for ls in list(_LANE_SETS)):
+            got = [_fetch_one_part(parts[0])]
+            tally[bi] = (got[0].nbytes, 1, 0)
         else:
-            merged = np.asarray(jnp.concatenate(parts))  # one transfer/device
-            chunks = np.split(merged, np.cumsum(sizes)[:-1])
-        for fi, chunk in zip(fis, chunks):
-            _p, dtype, shape, was_bool = flat[fi]
-            v = np.ascontiguousarray(chunk).view(dtype).reshape(shape)
-            host[fi] = v.astype(bool) if was_bool else v
+            got, *tally[bi] = _fetch_parts(parts)
+        for ui, value in zip(uis, got):
+            host[ui] = value
 
     items = list(buckets.items())
     if len(items) == 1:
-        fetch_bucket(*items[0])
+        fetch_bucket(0, *items[0])
     else:
         futs = [
-            _gather_pool().submit(fetch_bucket, dev_id, fis)
-            for dev_id, fis in items
+            _gather_pool().submit(fetch_bucket, bi, dev_id, uis)
+            for bi, (dev_id, uis) in enumerate(items)
         ]
         for f in futs:
             f.result()  # surface the first failure (caller falls back)
-    return [tuple(host[i] for i in pos) for pos in index]
+    with _GATHER_BYTES_LOCK:
+        _gather_bytes_owed += owed_total
+        _gather_bytes_fetched += sum(t[0] for t in tally)
+    if note is not None:
+        note.update(parts=len(uniq), fetches=sum(t[1] for t in tally),
+                    bucket=max(t[2] for t in tally))
+    return [
+        tuple(host[p] if isinstance(p, int) else p for p in pos)
+        for pos in index
+    ]
 
 
 @functools.lru_cache(maxsize=256)

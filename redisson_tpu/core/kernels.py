@@ -90,6 +90,12 @@ def bucket_size(n: int, minimum: int = MIN_BUCKET) -> int:
     100,000-key probe (v5e trace; ledger PR 22/24).  Bodies that can, stop
     at n_valid instead (_map_valid_chunks); a finer ladder would buy the same
     for up to four times the programs an octave.
+
+    NOT the ladder of the coalescer's stacked runs (core/coalesce.py): their
+    row count is 100 x however many of a frame's tenants share a device, a
+    different rung nearly every frame, on a device the host cannot keep busy
+    — they pad to one of four buckets (STACK_ROW_BUCKETS), all compiled
+    together.
     """
     if n <= minimum:
         return minimum
@@ -452,6 +458,28 @@ bloom_bank_contains_packed = jax.jit(_bloom_bank_contains_impl, static_argnums=(
 @functools.partial(jax.jit, static_argnums=(3, 4))
 def bloom_bank_contains_packed_bits(bits2d, tlh, n_valid, k: int, m: int):
     return _pack_bool_u32(_bloom_bank_contains_impl(bits2d, tlh, n_valid, k, m))
+
+
+# --- stacked-run variants (core/coalesce.py) ----------------------------------
+# A fused run over several single-filter planes is a small bank.  The planes
+# arrive as a TUPLE of equal 1-D planes and are stacked inside the program,
+# so one dispatch does what jnp.stack + the bank kernel + one slice a plane
+# did, and the program's shape is the tuple's length and the row bucket —
+# both fixed by the coalescer (STACK_PLANES, STACK_ROW_BUCKETS), whatever
+# the run's composition.  Nothing is donated: a padding plane repeats a real
+# one.
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def bloom_stack_contains_packed(planes, tlh, n_valid, k: int, m: int):
+    return _bloom_bank_contains_impl(jnp.stack(planes), tlh, n_valid, k, m)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def bloom_stack_add_packed(planes, tlh, n_valid, k: int, m: int):
+    """(one new plane an input plane, newly-added flags)."""
+    bits2d, newly = _bloom_bank_add_packed(jnp.stack(planes), tlh, n_valid, k, m)
+    return tuple(bits2d[i] for i in range(len(planes))), newly
 
 
 @jax.jit

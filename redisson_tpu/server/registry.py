@@ -31,28 +31,35 @@ class LazyReply:
     """Deferred reply: the handler DISPATCHED device work but did not force
     the device->host sync.  The connection loop materializes every lazy
     reply of a pipelined frame together — and, for the (device, finish)
-    form, BITCASTS every device result to one uint8 stream, concatenates,
-    and pulls it in a SINGLE device->host transfer (regardless of dtype
-    mix), so a 32-command frame pays ~1 device->host sync instead of 32
-    (the reference's analog is CommandBatchService's single-flush
-    discipline).  Constraint: each device value's dtype must
-    round-trip via ``np.dtype(a.dtype.name)`` — a dtype numpy can't name
-    (e.g. bfloat16) cannot ride this path.
+    form, fetches every device result of the frame in one grouped fetch a
+    device (core/ioplane.gather_device_results: same-shaped values stacked,
+    every crossing started before the first is waited for), so a
+    32-command frame pays ~1 device->host sync instead of 32 (the
+    reference's analog is CommandBatchService's single-flush discipline).
+    A device value several replies name (the rows of one fused run) crosses
+    once.  Constraint: a reply that is its device's ONLY value crosses as a
+    uint8 stream, so its dtype must round-trip via
+    ``np.dtype(a.dtype.name)`` — a dtype numpy can't name (e.g. bfloat16)
+    cannot ride this path.
 
     Two forms:
       LazyReply(force=fn)              — fn() -> reply, forced individually;
       LazyReply(device=(arrs...), finish=fn) — fn(host_arrays) -> reply,
-        host_arrays delivered by the frame-level grouped transfer.
+        host_arrays delivered by the frame-level grouped transfer.  `owed`:
+        the bytes of them the reply is made from, where that is less than
+        all (its rows of a shared result) — counted, never needed.
     """
 
-    __slots__ = ("device", "finish", "_force")
+    __slots__ = ("device", "finish", "owed", "_force")
 
     def __init__(self, force: Optional[Callable[[], Any]] = None,
                  device: Optional[tuple] = None,
-                 finish: Optional[Callable[[tuple], Any]] = None):
+                 finish: Optional[Callable[[tuple], Any]] = None,
+                 owed: Optional[int] = None):
         self._force = force
         self.device = device
         self.finish = finish
+        self.owed = owed
 
     def force(self) -> Any:
         if self._force is not None:
@@ -63,11 +70,11 @@ class LazyReply:
 
 
 def gather_lazy_device_results(lazies: List["LazyReply"]) -> List[tuple]:
-    """Fetch every device value of `lazies` with ONE device->host transfer —
-    the frame-level grouped gather, now THE shared primitive of the overlap
-    plane (core/ioplane.gather_device_results): the server's reply path, the
+    """Fetch every device value of `lazies` with one grouped fetch a device
+    — the frame-level gather, THE shared primitive of the overlap plane
+    (core/ioplane.gather_device_results): the server's reply path, the
     embedded Batch drain, and bench's A/B harness all force through it, so
-    the bitcast/concat/split discipline cannot diverge between layers."""
+    the fetch discipline cannot diverge between layers."""
     from redisson_tpu.core.ioplane import _is_ready, gather_device_results
 
     if _obs._tracer is not None:
@@ -82,13 +89,20 @@ def gather_lazy_device_results(lazies: List["LazyReply"]) -> List[tuple]:
                 _is_ready(v) for lz in lazies for v in lz.device
             )
             t0 = _time.monotonic()
-            out = gather_device_results([lz.device for lz in lazies])
+            note: dict = {}
+            out = gather_device_results(
+                [lz.device for lz in lazies], [lz.owed for lz in lazies], note
+            )
+            # parts: distinct device values the frame owed; fetches: the
+            # transfers that brought them; bucket: the widest stack ridden
             cur.add_span(
                 "readback", t0, _time.monotonic(),
-                grouped=len(lazies), blocking=int(not was_ready),
+                grouped=len(lazies), blocking=int(not was_ready), **note,
             )
             return out
-    return gather_device_results([lz.device for lz in lazies])
+    return gather_device_results(
+        [lz.device for lz in lazies], [lz.owed for lz in lazies]
+    )
 
 
 class CommandContext:

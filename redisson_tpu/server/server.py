@@ -39,7 +39,11 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from redisson_tpu.client import routing as _routing
 from redisson_tpu.core import ioplane
-from redisson_tpu.core.coalesce import plan_subwindows, runs_within_admission
+from redisson_tpu.core import coalesce as _coalesce
+from redisson_tpu.core.coalesce import (
+    STACK_PLANES, plan_stacked_chunks, plan_subwindows, runs_within_admission,
+    stacked_row_bucket,
+)
 from redisson_tpu.core.engine import Engine
 from redisson_tpu.net import resp
 from redisson_tpu.net.resp import ProtocolError, RespError
@@ -105,14 +109,19 @@ _STOP_DRAIN_S = 5.0
 _DEVICE_FAULT_TRYAGAIN = "TRYAGAIN device fault during dispatch; retry"
 
 
+def _blob_keys(cmd) -> int:
+    """Keys a BF.MADD64 / BF.MEXISTS64 command carries (8 bytes each)."""
+    return len(cmd[2]) // 8 if len(cmd) > 2 else 0
+
+
 def _quarantined_tryagain(dev_id: int) -> str:
     return f"TRYAGAIN device {dev_id} quarantined; retry after evacuation"
 
 
 def _force_lazies(results: list, server, trace=None) -> None:
     """Materialize every LazyReply of a frame in place.  Device-form lazies
-    are fetched with one concatenated transfer per dtype (the whole frame
-    pays ~1 device->host sync); callable-form lazies force individually.
+    are fetched with one grouped fetch a device (the whole frame pays ~1
+    device->host sync a lane); callable-form lazies force individually.
     `trace` (tracing armed only) is activated on this worker thread so the
     readback spans recorded inside the gather land on the right frame."""
     from redisson_tpu.server.registry import gather_lazy_device_results
@@ -321,6 +330,24 @@ class TpuServer:
         )
         self.metrics.gauge(
             "kernel_rows_issued_total", lambda: _K.rows_counted()[1]
+        )
+        # what the fixed shapes of a multi-tenant frame pad (always on, as
+        # the rows above): planes a fused run named against planes its
+        # dispatch stacked (core/coalesce.py), and bytes the frame's replies
+        # are made from against bytes the grouped fetch brought
+        # (core/ioplane.py gather_device_results)
+        self.metrics.gauge(
+            "coalesce_planes_asked_total", lambda: _coalesce.planes_counted()[0]
+        )
+        self.metrics.gauge(
+            "coalesce_planes_stacked_total", lambda: _coalesce.planes_counted()[1]
+        )
+        self.metrics.gauge(
+            "gather_bytes_owed_total", lambda: ioplane.gather_bytes_counted()[0]
+        )
+        self.metrics.gauge(
+            "gather_bytes_fetched_total",
+            lambda: ioplane.gather_bytes_counted()[1],
         )
         self._heartbeat_task = None
         self.heartbeat_wakes = 0
@@ -1177,17 +1204,34 @@ class TpuServer:
 
     def _dispatch_bloom_run(self, ctx, cmds):
         """Coalesced execution of a same-verb BF blob run inside one frame
-        (the adaptive coalescing plane): ONE stacked-bank kernel dispatch for
-        the whole run instead of one per command, per-command LazyReplies
-        riding the frame's single d2h gather.  Ineligible runs fall back to
-        sequential per-command dispatch with identical semantics; an
-        unexpected failure of the fused path falls back only for CONTAINS
-        runs (read-only) — add runs reply per-command errors instead, so a
-        possibly-applied mutation is never re-dispatched (at-most-once)."""
-        from redisson_tpu.server.verbs.sketch import coalesce_bloom_run
-
+        (the adaptive coalescing plane): the run is cut, at command
+        boundaries, into as many stacked dispatches as its planes and rows
+        need (coalesce.plan_stacked_chunks: one for a run of up to 64
+        commands and 16,384 keys), each a self-contained fused run with the
+        add-run at-most-once discipline of the preemptible sub-windows — a
+        failed chunk errors per command and is never re-dispatched, earlier
+        chunks already applied.  Replies extend in frame order."""
         if not self._pause_gate.is_set():
             self._pause_gate.wait(timeout=60.0)
+        chunks = plan_stacked_chunks([_blob_keys(c) for c in cmds])
+        if len(chunks) == 1:
+            return self._dispatch_bloom_chunk(ctx, cmds)
+        out = []
+        for s, e in chunks:
+            out.extend(self._dispatch_bloom_chunk(ctx, cmds[s:e]))
+        return out
+
+    def _dispatch_bloom_chunk(self, ctx, cmds):
+        """ONE stacked-bank kernel dispatch for `cmds` instead of one per
+        command, per-command LazyReplies riding the frame's grouped d2h
+        gather.  Ineligible chunks (and a chunk of one: a command too long
+        to stack) fall back to sequential per-command dispatch with
+        identical semantics; an unexpected failure of the fused path falls
+        back only for CONTAINS runs (read-only) — add runs reply per-command
+        errors instead, so a possibly-applied mutation is never
+        re-dispatched (at-most-once)."""
+        from redisson_tpu.server.verbs.sketch import coalesce_bloom_run
+
         cur = _obs.current_trace() if _obs._tracer is not None else None
         k0 = time.monotonic() if cur is not None else 0.0
         is_add = bytes(cmds[0][0]).upper() == b"BF.MADD64"
@@ -1206,7 +1250,9 @@ class TpuServer:
             if not is_add:
                 track.note_read(ctx, run_names)
         try:
-            fused = coalesce_bloom_run(self, ctx, cmds)
+            fused = None
+            if len(cmds) > 1 or stacked_row_bucket(_blob_keys(cmds[0])) is not None:
+                fused = coalesce_bloom_run(self, ctx, cmds)
         except RuntimeError as e:
             if "shutdown" in str(e):
                 # same contract as the per-command path: a stopping worker
@@ -1242,7 +1288,7 @@ class TpuServer:
                 cur.add_span(
                     "kernel", k0, k1,
                     verb=bytes(cmds[0][0]).upper().decode(),
-                    members=len(cmds),
+                    members=len(cmds), stacked=STACK_PLANES,
                 )
                 for c in cmds[:32]:
                     cur.add_span(
@@ -1607,9 +1653,8 @@ class TpuServer:
             return [(i, enc) for i, _c in items]
         cmds = [c for _i, c in items]
         out = []
-        run_at: Dict[int, int] = (
-            dict(_routing.coalescible_frame_runs(cmds)) if len(cmds) > 1 else {}
-        )
+        # lone commands too (min_len 1): see coalescible_frame_runs
+        run_at: Dict[int, int] = dict(_routing.coalescible_frame_runs(cmds, 1))
         from contextlib import nullcontext
 
         def dispatch_span(lo: int, hi: int) -> None:
@@ -1952,14 +1997,17 @@ class TpuServer:
             and len(commands) > 1
         ):
             try:
-                # with the CPU-replica occupancy model armed (bench
-                # config5d A/B), even a 1-device frame runs the lane
-                # dispatch path so both legs execute identical code
+                # a frame that lands on ONE lane is planned too: its bucket
+                # is one job for one worker, where the sequential loop below
+                # is a hop a command — nothing on an idle pool, and a second
+                # of queueing on a busy one for the few commands a socket
+                # read leaves at the end of a long frame (fanout-4: one
+                # request in five took 2.2 s against 1.1; PERF.md section 6,
+                # PR 26).  Not where bulk sub-windows are armed: the
+                # sequential loop cuts a run finer than a bucket does.
                 plan = self.engine.placement.plan_frame(
                     commands,
-                    single_device_ok=(
-                        ioplane.replica_occupancy() is not None
-                    ),
+                    single_device_ok=self._subwindow_target(qos_class) == 0,
                 )
             except Exception:  # noqa: BLE001 — planning must never
                 plan = None    # break a frame; fall back to serial
@@ -2000,7 +2048,9 @@ class TpuServer:
         if len(commands) > 1:
             runs = [
                 (s, e)
-                for s, e in _routing.coalescible_frame_runs(commands)
+                for s, e in _routing.coalescible_frame_runs(
+                    commands, 1 if self.engine.placement is not None else 2
+                )
                 if all(
                     isinstance(a, (bytes, bytearray))
                     for c in commands[s:e]

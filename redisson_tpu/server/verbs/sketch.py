@@ -343,8 +343,9 @@ def cmd_bf_mexists64(server, ctx, args):
 # commands against different filters (the config-5 fan-out: one command per
 # tenant filter) used to cost one device dispatch per command.  The server
 # frame loop (server/server.py) hands such runs here: same-geometry filters
-# stack into one (F, S) bank, the whole run executes as ONE kernel, and each
-# command's reply is a device slice riding the frame's single d2h gather.
+# stack into one small bank, the whole run executes as ONE kernel, and each
+# command's reply is its rows of the run's result, which rides the frame's
+# grouped d2h gather once.
 # Under the overlap plane (core/ioplane) that gather runs on the writer
 # task's completion queue, so a 64-filter wave's readback overlaps the NEXT
 # wave's staging (engine staging pool) and upload — back-to-back waves
@@ -406,16 +407,19 @@ def coalesce_bloom_run(server, ctx, cmds: List[List[bytes]]):
         raise
     run_hooks_end(tokens, name, None)
 
-    def reply(seg):
+    def reply(off, n):
+        # every reply of the run names the run's ONE device value; the
+        # frame's grouped fetch brings it once and each reply cuts its own
+        # rows on the host (a device-side slice is a program an offset)
         return LazyReply(
-            device=(seg,),
-            finish=lambda v: np.asarray(v[0], np.uint8).tobytes(),
+            device=(flags,), owed=n,
+            finish=lambda v: np.asarray(v[0][off : off + n], np.uint8).tobytes(),
         )
 
     out = []
     off = 0
     for n in lengths:
-        out.append(reply(flags[off : off + n]))
+        out.append(reply(off, n))
         off += n
     return out
 
