@@ -32,6 +32,15 @@ command outside a run is.  The first stacked dispatch of a geometry compiles
 the whole set — two verbs x four row buckets — on every device of the
 placement (_warm_geometry), so no later frame meets a cold program.
 
+WAVES.  A client that flushes one batch over its tenants writes each
+tenant's commands together (set, or, xor, count, then the next tenant), so
+the same-verb runs of a frame have length 1.  But a device-sharded segment
+promises per-key order only, so inside one device's bucket the commands on
+DIFFERENT keys are regrouped into same-verb waves (plan_waves), per-key order
+kept, and each wave is one stacked dispatch: the bloom runs above, and the
+bitset forms SETBITSB, BITOP OR / XOR and BITCOUNT over STACK_PLANES planes
+of one shape (the "bitset waves" section below).
+
 Semantics preserved exactly:
   * per-issuer results: segment offsets are computed host-side from the
     submitted lengths, so every reply slices back to its op in order;
@@ -51,7 +60,8 @@ per-group path, so coalescing is a pure fast path, never a semantics change.
 from __future__ import annotations
 
 import threading
-from typing import List, Optional, Sequence, Tuple
+from collections import OrderedDict
+from typing import Any, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -171,9 +181,64 @@ def plan_stacked_chunks(lengths: Sequence[int]) -> List[Tuple[int, int]]:
     return out
 
 
+def plan_waves(entries) -> List[Tuple[Any, List[int]]]:
+    """Regroup one device bucket's commands into WAVES: [(form, positions)],
+    waves to be run in list order, each wave of a form one stacked dispatch.
+
+    `entries[i]` = (form, writes, reads, rows) of command i in frame order:
+    `form` is what a stacked program needs its members to share (None: the
+    command goes per record, a wave of its own), `writes` / `reads` its keys,
+    `rows` what it adds to a wave's row window (0 where the form fixes it).
+
+    The only order a sharded segment promises is per key
+    (placement.PARALLEL_VERBS), so a command may run beside any command
+    whose keys it does not share.  It joins the first wave of its form, with
+    room, AFTER every wave it depends on — the last that touched a key it
+    writes, the last that wrote a key it reads — else it opens a new wave at
+    the end.  Two commands on one key therefore never swap, and never share
+    a wave unless both only read it; a command with no key keeps its place
+    against every other.  Room is the stacked shape's: STACK_PLANES members
+    and the largest row bucket (a command too long for any bucket stands
+    alone), so consecutive same-verb commands on different keys form the
+    chunks plan_stacked_chunks cuts."""
+    waves: List[list] = []  # [form, positions, rows]
+    last_write: dict = {}
+    last_touch: dict = {}
+    floor = -1  # the last keyless command's wave: nothing later runs before it
+    top = STACK_ROW_BUCKETS[-1]
+    for i, (form, writes, reads, rows) in enumerate(entries):
+        keyless = not writes and not reads
+        after = len(waves) - 1 if keyless else floor
+        for k in writes:
+            after = max(after, last_touch.get(k, -1))
+        for k in reads:
+            after = max(after, last_write.get(k, -1))
+        at = None
+        if form is not None:
+            at = next(
+                (w for w in range(after + 1, len(waves))
+                 if waves[w][0] == form and len(waves[w][1]) < STACK_PLANES
+                 and waves[w][2] + rows <= top),
+                None,
+            )
+        if at is None:
+            at = len(waves)
+            waves.append([form, [], 0])
+        waves[at][1].append(i)
+        waves[at][2] += rows
+        if keyless:
+            floor = at
+        for k in writes:
+            last_write[k] = last_touch[k] = at
+        for k in reads:
+            last_touch[k] = max(last_touch.get(k, -1), at)
+    return [(form, members) for form, members, _rows in waves]
+
+
 _PLANES_LOCK = threading.Lock()
 _planes_asked = 0
 _planes_stacked = 0
+_cmds_offered = 0
 
 
 def planes_counted() -> tuple:
@@ -190,6 +255,24 @@ def _count_planes(asked: int) -> None:
     with _PLANES_LOCK:  # server worker threads dispatch side by side
         _planes_asked += asked
         _planes_stacked += STACK_PLANES
+
+
+def count_offered(n: int) -> None:
+    """`n` commands reached a place where the server decides between a
+    stacked dispatch and per-record dispatch: a device bucket, or a run of
+    the sequential path."""
+    global _cmds_offered
+    with _PLANES_LOCK:
+        _cmds_offered += n
+
+
+def cmds_counted() -> tuple:
+    """(offered, fused) command totals: commands the server offered to the
+    coalescer (count_offered) against commands that rode a stacked dispatch
+    — a member of a stacked dispatch is one plane it was asked for.  METRICS
+    exports both (coalesce_cmds_offered_total, coalesce_cmds_fused_total),
+    always on."""
+    return _cmds_offered, _planes_asked
 
 
 def _concat_segments(engine, keys_list) -> Tuple[np.ndarray, np.ndarray, List[int]]:
@@ -402,3 +485,245 @@ def fused_bloom_pair_async(engine, name: str, add_keys, probe_keys):
         rec.arrays["bits"] = bits
         rec.version += 1
     return newly, n_a, found, n_p
+
+
+# -- bitset waves ------------------------------------------------------------------
+# A wave (plan_waves) of SETBITSB, of BITOP OR / XOR or of BITCOUNT commands
+# on different bitsets of one plane shape is ONE program over STACK_PLANES
+# planes (kernels.bitset_stack_*), as a bloom run is.  A member the forms do
+# not cover — a missing or wrong-typed record, a plane of another shape or
+# device, an index past the plane (the per-record path grows it) — is left
+# out and told to the caller, which dispatches it per record: the members of
+# a wave share no key, so which of them runs first changes nothing.
+
+
+def _bitset_rec(engine, name: str, like=None):
+    """`name`'s record where it holds a bitset plane a stacked dispatch can
+    take — of `like`'s shape and device, where given — else None.  Caller
+    holds the lock."""
+    rec = engine.store.get(name)
+    if rec is None or rec.kind != "bitset":
+        return None
+    plane = rec.arrays["bits"]
+    if plane.shape[0] > STACK_MAX_PLANE_CELLS or rec.meta["nbits"] != plane.shape[0]:
+        return None  # a logical size short of the plane: per record masks it
+    if like is not None and (
+        plane.shape != like.shape or _plane_device(plane) != _plane_device(like)
+    ):
+        return None
+    return rec
+
+
+def _wave_guard(engine) -> None:
+    """Conditions under which the per-record handlers do more than the
+    stacked forms know of: inside a migration window a missing key redirects
+    (store.absent_guard), under a name mapper a key is not its record's
+    name."""
+    if engine.store.absent_guard is not None:
+        raise CoalesceIneligible("migration window open")
+    if getattr(engine.config, "name_mapper", None) is not None:
+        raise CoalesceIneligible("name mapper configured")
+
+
+class _StandIns:
+    """The resident padding of ONE (device, plane shape): STACK_PLANES planes
+    a writing wave donates in the places its members leave free, replaced by
+    what the program returns for them — what they hold is never read.  A
+    donated tuple may not name one buffer twice, so padding cannot repeat a
+    member's plane as a bloom run's does."""
+
+    def __init__(self, like, device):
+        import jax
+
+        self.lock = threading.Lock()
+        host = np.zeros(like.shape, like.dtype)
+        self.planes = [jax.device_put(host, device) for _ in range(STACK_PLANES)]
+
+
+# (device id, plane shape) -> _StandIns, least recently used first; bounded,
+# since a store may hold bitsets of any number of sizes
+_STAND_INS: "OrderedDict[tuple, _StandIns]" = OrderedDict()
+_STAND_INS_MAX = 16
+_STAND_INS_LOCK = threading.Lock()
+
+
+def _donating(like, device, recs: Sequence[Any], call):
+    """One writing wave: `call(stack)` -> (new planes, value) over the planes
+    of `recs`, padded to STACK_PLANES with the stand-ins of `like`'s shape on
+    `device`; each record gets its new plane and its version moves, the
+    stand-ins are replaced by what came back in their places.  Returns
+    `value`.  Caller holds the records' locks.  One wave at a time borrows a
+    set; a call that fails may have consumed the buffers it was given, so
+    the set is dropped and made anew by the next wave."""
+    key = (getattr(device, "id", None), like.shape)
+    with _STAND_INS_LOCK:
+        pads = _STAND_INS.get(key)
+        if pads is None:
+            pads = _STAND_INS[key] = _StandIns(like, device)
+            if len(_STAND_INS) > _STAND_INS_MAX:
+                _STAND_INS.popitem(last=False)  # a wave that holds it keeps it alive
+        _STAND_INS.move_to_end(key)
+    free = STACK_PLANES - len(recs)
+    with pads.lock:
+        try:
+            new, value = call(
+                tuple(r.arrays["bits"] for r in recs) + tuple(pads.planes[:free])
+            )
+        except BaseException:
+            with _STAND_INS_LOCK:
+                _STAND_INS.pop(key, None)
+            raise
+        pads.planes[:free] = new[len(recs):]
+    for rec, plane in zip(recs, new):  # stops at the wave's last member
+        rec.arrays["bits"] = plane
+        rec.version += 1
+    return value
+
+
+def _set_window(idx_list: Sequence[np.ndarray], rows: int, device):
+    """The (STACK_PLANES, rows) index window of one SETBITSB wave, staged
+    once, on the wave's device."""
+    import jax
+
+    window = np.full((STACK_PLANES, rows), K.NO_INDEX, np.int32)
+    for i, idx in enumerate(idx_list):
+        window[i, : idx.shape[0]] = idx
+    return jax.device_put(window, device)
+
+
+def _warm_bitset_shape(plane) -> None:
+    """_warm_geometry's discipline for the bitset waves: the first wave of a
+    plane shape compiles set (at every row bucket), or, xor and count on
+    every device that can own such a bitset — on stand-ins, results dropped
+    — and the grouped fetch's programs for their results."""
+    import jax
+
+    from redisson_tpu.core import ioplane
+
+    kind = ("bitset", plane.shape[0])
+    targets = ioplane.warm_targets(plane)
+    if all(ioplane.target_key(t, kind) in _WARM for t in targets):
+        return
+    with _WARM_LOCK:
+        for target in targets:
+            key = ioplane.target_key(target, kind)
+            if key in _WARM:
+                continue
+            device = target[0]
+            for b in STACK_ROW_BUCKETS:
+                window = _set_window((), b, device)
+                ioplane.warm_stack_class(_donating(
+                    plane, device, (), lambda stack: K.bitset_stack_set(stack, window)
+                ))
+            src = (jax.device_put(np.zeros(plane.shape, plane.dtype), device),)
+            for op in K._BIT_OPS:
+                lengths = _donating(
+                    plane, device, (),
+                    lambda stack: K.bitset_stack_op(stack, src * STACK_PLANES, op),
+                )
+            ioplane.warm_stack_class(lengths)  # the counts alike
+            K.bitset_stack_popcount(src * STACK_PLANES)
+            _WARM.add(key)
+
+
+def fused_bitset_set_async(engine, names: Sequence[str], idx_list):
+    """ONE dispatch for a SETBITSB wave over at most STACK_PLANES DISTINCT
+    bitsets (kernels.bitset_stack_set): writes each member's new plane back
+    under the wave's locks.  Returns (device previous bits, (STACK_PLANES,
+    R) uint8; rows) — rows[i] is member i's row of it, None where the member
+    is left to the per-record path (no such bitset, another shape or
+    device, an index outside the plane)."""
+    if len(names) > STACK_PLANES or len(set(names)) != len(names):
+        raise CoalesceIneligible("more or repeated bitsets in a set wave")
+    _wave_guard(engine)
+    bucket = stacked_row_bucket(max(idx.shape[0] for idx in idx_list))
+    if bucket is None:
+        raise CoalesceIneligible("more rows than one stacked dispatch holds")
+    rows: List[Optional[int]] = [None] * len(names)
+    with engine.locked_many(set(names)):
+        recs: list = []
+        took: list = []
+        for i, (name, idx) in enumerate(zip(names, idx_list)):
+            like = recs[0].arrays["bits"] if recs else None
+            rec = _bitset_rec(engine, name, like) if idx.shape[0] else None
+            if rec is None or int(idx.min()) < 0 or int(idx.max()) >= rec.meta["nbits"]:
+                continue
+            rows[i] = len(recs)
+            recs.append(rec)
+            took.append(idx)
+        if not recs:
+            raise CoalesceIneligible("no member a stacked set can take")
+        first = recs[0].arrays["bits"]
+        device = _plane_device(first)
+        _warm_bitset_shape(first)
+        _count_planes(len(recs))
+        window = _set_window(took, bucket, device)
+        old = _donating(
+            first, device, recs, lambda stack: K.bitset_stack_set(stack, window)
+        )
+    return old, rows
+
+
+def fused_bitop_async(engine, op: str, pairs: Sequence[Tuple[str, str]]):
+    """ONE dispatch for a wave of BITOP `op` (OR / XOR) over at most
+    STACK_PLANES (dest, source) pairs, dest = dest `op` source
+    (kernels.bitset_stack_op); no dest is named twice or as a source.
+    Returns (device length hints, (STACK_PLANES,) int32; rows) as
+    fused_bitset_set_async does; a member whose two bitsets are not both
+    there, of one shape and on one device is left to the per-record path."""
+    dests = [d for d, _s in pairs]
+    if (
+        len(pairs) > STACK_PLANES or len(set(dests)) != len(dests)
+        or set(dests) & {s for _d, s in pairs}
+    ):
+        raise CoalesceIneligible("more or dependent pairs in a bitop wave")
+    _wave_guard(engine)
+    rows: List[Optional[int]] = [None] * len(pairs)
+    with engine.locked_many({n for pair in pairs for n in pair}):
+        recs: list = []
+        srcs: list = []
+        for i, (dest, src) in enumerate(pairs):
+            like = recs[0].arrays["bits"] if recs else None
+            rec = _bitset_rec(engine, dest, like)
+            other = _bitset_rec(engine, src, rec.arrays["bits"]) if rec is not None else None
+            if other is None:
+                continue
+            rows[i] = len(recs)
+            recs.append(rec)
+            srcs.append(other.arrays["bits"])
+        if not recs:
+            raise CoalesceIneligible("no member a stacked bitop can take")
+        first = recs[0].arrays["bits"]
+        _warm_bitset_shape(first)
+        _count_planes(len(recs))
+        srcs += srcs[:1] * (STACK_PLANES - len(srcs))  # read only: may repeat
+        lengths = _donating(
+            first, _plane_device(first), recs,
+            lambda stack: K.bitset_stack_op(stack, tuple(srcs), op),
+        )
+    return lengths, rows
+
+
+def fused_bitcount_async(engine, names: Sequence[str]):
+    """ONE dispatch for a BITCOUNT wave over at most STACK_PLANES bitsets
+    (kernels.bitset_stack_popcount): (device counts, (STACK_PLANES,) int32;
+    rows) as fused_bitset_set_async does.  No host sync: the counts ride
+    the frame's grouped fetch."""
+    if len(names) > STACK_PLANES:
+        raise CoalesceIneligible("more bitsets than one stacked dispatch holds")
+    _wave_guard(engine)
+    rows: List[Optional[int]] = [None] * len(names)
+    with engine.locked_many(set(names)):
+        planes: list = []
+        for i, name in enumerate(names):
+            rec = _bitset_rec(engine, name, planes[0] if planes else None)
+            if rec is not None:
+                rows[i] = len(planes)
+                planes.append(rec.arrays["bits"])
+        if not planes:
+            raise CoalesceIneligible("no member a stacked count can take")
+        _warm_bitset_shape(planes[0])
+        _count_planes(len(planes))
+        planes += planes[:1] * (STACK_PLANES - len(planes))
+        counts = K.bitset_stack_popcount(tuple(planes))
+    return counts, rows

@@ -670,6 +670,51 @@ bitset_bitpos = jax.jit(bt.bitpos, static_argnums=(1, 2))
 bitset_length = jax.jit(bt.length_hint)
 
 
+# --- stacked-wave variants (core/coalesce.py) ---------------------------------
+# One program for a wave of same-verb bitset commands on DIFFERENT planes of
+# one shape.  The planes arrive as a tuple of the coalescer's fixed length and
+# each is worked on in place: one scatter (or one elementwise pass) a plane
+# inside the program, the written planes DONATED.  Chosen on the v5e against a
+# flat bank (jnp.stack, one scatter at slot * stride + index, one slice a
+# plane) and against the same body without donation, 16 planes of 2^20 cells,
+# time to return: set 0.79-0.96 ms donated, 1.6-1.7 not, 1.6-1.7 flat; or/xor
+# 0.31 ms donated, 1.1-1.2 not, 1.1-1.2 flat (PERF.md section 6, PR 27).  A
+# donated tuple must not name one buffer twice, so the coalescer pads a
+# writing wave with resident stand-ins, never with a repeated plane.
+
+# pads a wave's index window: beyond every plane (BitSet.MAX_BIT is below it),
+# so the scatter drops it and the gather reads 0 for it
+NO_INDEX = np.int32(2**31 - 1)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def bitset_stack_set(planes, window):
+    """SETBITSB over a tuple of planes: row i of the (P, R) int32 `window`
+    holds plane i's indexes, padded with NO_INDEX.  Returns (one new plane a
+    plane, the previous bits as one (P, R) uint8 value).  The ones are a
+    constant of the program."""
+    old = jnp.stack([bt.get_bits(p, window[i]) for i, p in enumerate(planes)])
+    return tuple(bt.set_bits(p, window[i], 1) for i, p in enumerate(planes)), old
+
+
+_BIT_OPS = {"OR": bt.bit_or, "XOR": bt.bit_xor}
+
+
+@functools.partial(jax.jit, static_argnums=(2,), donate_argnums=(0,))
+def bitset_stack_op(dests, srcs, op: str):
+    """BITOP OR / XOR over a tuple of (dest, src) plane pairs: (one new dest
+    plane a pair, their length hints as one (P,) int32 value) — the reply's
+    length comes out of the program that made the plane."""
+    new = tuple(_BIT_OPS[op](d, s) for d, s in zip(dests, srcs))
+    return new, jnp.stack([bt.length_hint(z) for z in new])
+
+
+@jax.jit
+def bitset_stack_popcount(planes):
+    """BITCOUNT over a tuple of whole planes: one (P,) int32 value."""
+    return jnp.stack([bt.popcount(p, p.shape[0]) for p in planes])
+
+
 # --------------------------------------------------------------------------
 # Text word-count kernels (MapReduce device path, SURVEY.md §3.5 / §7.3-6).
 #

@@ -256,10 +256,10 @@ class SlotPlacement:
         on the plan — ineligible commands simply serialize).
 
         ``single_device_ok`` returns a plan even when everything lands on
-        ONE device — the bench A/B's 1-device leg (the server sets it while
-        the CPU-replica occupancy model is armed), so both legs run the
-        SAME dispatch code and differ only in lane count."""
-        if (self.n_devices <= 1 and not single_device_ok) or len(commands) < 2:
+        ONE device, a frame of one command too: the server asks for it so
+        that every keyed command of a placed engine is dispatched by the
+        SAME code, in a device bucket, whatever the frame's composition."""
+        if not single_device_ok and (self.n_devices <= 1 or len(commands) < 2):
             return None
         # ONE owner-table snapshot for the whole frame: a rebalance racing
         # the planner must not split same-key commands into different

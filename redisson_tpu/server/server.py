@@ -35,14 +35,15 @@ import threading
 import time
 import uuid
 from concurrent.futures import ThreadPoolExecutor
+from contextlib import ExitStack, nullcontext
 from typing import Any, Dict, List, Optional, Tuple
 
 from redisson_tpu.client import routing as _routing
 from redisson_tpu.core import ioplane
 from redisson_tpu.core import coalesce as _coalesce
 from redisson_tpu.core.coalesce import (
-    STACK_PLANES, plan_stacked_chunks, plan_subwindows, runs_within_admission,
-    stacked_row_bucket,
+    STACK_PLANES, plan_stacked_chunks, plan_subwindows, plan_waves,
+    runs_within_admission, stacked_row_bucket,
 )
 from redisson_tpu.core.engine import Engine
 from redisson_tpu.net import resp
@@ -112,6 +113,42 @@ _DEVICE_FAULT_TRYAGAIN = "TRYAGAIN device fault during dispatch; retry"
 def _blob_keys(cmd) -> int:
     """Keys a BF.MADD64 / BF.MEXISTS64 command carries (8 bytes each)."""
     return len(cmd[2]) // 8 if len(cmd) > 2 else 0
+
+
+_STACKED_BITOPS = (b"OR", b"XOR")
+
+
+def _wave_entry(cmd):
+    """(form, writes, reads, rows) of one device-bucket command for
+    coalesce.plan_waves: which stacked program the command can ride (None:
+    per record) and the keys that order it against the bucket's others.
+    `cmd` is a list of bytes with a whitelisted verb
+    (placement.device_index_for_command).  Forms: a BF blob verb (its rows
+    add up in the wave's window); SETBITSB at the row bucket of its own
+    indexes; BITOP OR / XOR; BITCOUNT.  What a record holds is looked at
+    when the wave is dispatched (verbs/sketch.py coalesce_bitset_wave)."""
+    verb = bytes(cmd[0]).upper()
+    n = len(cmd)
+    if verb in _routing.COALESCIBLE_BLOB_VERBS and n >= 2:
+        key = (bytes(cmd[1]),)
+        if verb == b"BF.MADD64":
+            return (verb,), key, (), _blob_keys(cmd)
+        return (verb,), (), key, _blob_keys(cmd)
+    if verb == b"SETBITSB" and n == 3:
+        bucket = stacked_row_bucket(len(cmd[2]) // 4)
+        form = (verb, bucket) if bucket is not None and len(cmd[2]) >= 4 else None
+        return form, (bytes(cmd[1]),), (), 0
+    if verb == b"BITCOUNT" and n == 2:
+        return (verb,), (), (bytes(cmd[1]),), 0
+    if verb == b"BITOP" and n >= 4:
+        op = bytes(cmd[1]).upper()
+        form = (verb, op) if op in _STACKED_BITOPS else None
+        return form, (bytes(cmd[2]),), tuple(bytes(a) for a in cmd[3:]), 0
+    # any other verb, per record: every key counts as written
+    from redisson_tpu.net import commands as C
+
+    keys = C.command_keys(verb.decode(), cmd[1:])
+    return None, tuple(bytes(k) for k in keys), (), 0
 
 
 def _quarantined_tryagain(dev_id: int) -> str:
@@ -341,6 +378,15 @@ class TpuServer:
         )
         self.metrics.gauge(
             "coalesce_planes_stacked_total", lambda: _coalesce.planes_counted()[1]
+        )
+        # how often the coalescer engages: commands offered to it (device
+        # buckets, runs of the sequential path) against commands that rode
+        # a stacked dispatch
+        self.metrics.gauge(
+            "coalesce_cmds_offered_total", lambda: _coalesce.cmds_counted()[0]
+        )
+        self.metrics.gauge(
+            "coalesce_cmds_fused_total", lambda: _coalesce.cmds_counted()[1]
         )
         self.metrics.gauge(
             "gather_bytes_owed_total", lambda: ioplane.gather_bytes_counted()[0]
@@ -1280,44 +1326,111 @@ class TpuServer:
             fused = None
         if fused is not None:
             if cur is not None:
-                # coalescer fan-in: ONE kernel span for the fused run, its
-                # member commands recorded as child spans sharing the
-                # kernel's interval (bounded so a 1000-command blob run
-                # cannot bloat the trace)
-                k1 = time.monotonic()
-                cur.add_span(
-                    "kernel", k0, k1,
-                    verb=bytes(cmds[0][0]).upper().decode(),
-                    members=len(cmds), stacked=STACK_PLANES,
+                self._stacked_kernel_span(
+                    cur, k0, bytes(cmds[0][0]).upper().decode(), cmds
                 )
-                for c in cmds[:32]:
-                    cur.add_span(
-                        "kernel.member", k0, k1,
-                        key=bytes(c[1]).decode(errors="replace"),
-                    )
             if track is not None and is_add:
                 track.note_write(run_names, ctx)
             return fused
-        out = []
-        for cmd in cmds:
-            try:
-                out.append(REGISTRY.dispatch(self, ctx, cmd))
-            except RespError as e:
-                self.stats["errors"] += 1
-                out.append(_Encoded(resp.encode_error(str(e.args[0]))))
-            except RuntimeError as e:
-                if "shutdown" in str(e):
-                    raise ConnectionResetError(str(e)) from e
-                self.stats["errors"] += 1
-                out.append(_Encoded(
-                    resp.encode_error(f"ERR internal: {type(e).__name__}: {e}")
-                ))
-            except Exception as e:  # noqa: BLE001 — sandbox per-command
-                self.stats["errors"] += 1
-                out.append(_Encoded(
-                    resp.encode_error(f"ERR internal: {type(e).__name__}: {e}")
-                ))
-        return out
+        return [self._dispatch_per_record(ctx, cmd) for cmd in cmds]
+
+    @staticmethod
+    def _stacked_kernel_span(cur, k0: float, verb: str, cmds, key_at: int = 1) -> None:
+        """Coalescer fan-in: ONE kernel span for a stacked dispatch, its
+        member commands recorded as child spans sharing the kernel's
+        interval (bounded so a 1000-command blob run cannot bloat the
+        trace)."""
+        k1 = time.monotonic()
+        cur.add_span(
+            "kernel", k0, k1, verb=verb, members=len(cmds), stacked=STACK_PLANES,
+        )
+        for c in cmds[:32]:
+            cur.add_span(
+                "kernel.member", k0, k1,
+                key=bytes(c[key_at]).decode(errors="replace"),
+            )
+
+    def _dispatch_per_record(self, ctx, cmd):
+        """One command of a run or wave the stacked path did not take: the
+        per-record handler, its errors translated per command."""
+        try:
+            return REGISTRY.dispatch(self, ctx, cmd)
+        except RespError as e:
+            self.stats["errors"] += 1
+            return _Encoded(resp.encode_error(str(e.args[0])))
+        except RuntimeError as e:
+            if "shutdown" in str(e):
+                raise ConnectionResetError(str(e)) from e
+            self.stats["errors"] += 1
+            return _Encoded(
+                resp.encode_error(f"ERR internal: {type(e).__name__}: {e}")
+            )
+        except Exception as e:  # noqa: BLE001 — sandbox per-command
+            self.stats["errors"] += 1
+            return _Encoded(
+                resp.encode_error(f"ERR internal: {type(e).__name__}: {e}")
+            )
+
+    def _dispatch_bitset_wave(self, ctx, cmds):
+        """ONE stacked dispatch for a wave of same-form SETBITSB, BITOP OR /
+        XOR or BITCOUNT commands on different keys (coalesce.plan_waves),
+        per-command LazyReplies riding the frame's grouped fetch.  A member
+        the stacked form does not cover takes the per-record handler — the
+        members of a wave share no key, so it may run after the others.  An
+        unexpected failure falls back to per-record dispatch only for
+        BITCOUNT (read-only); a writing wave replies per-command errors and
+        is never re-dispatched (at-most-once, as add runs)."""
+        from redisson_tpu.server.verbs.sketch import coalesce_bitset_wave
+
+        cur = _obs.current_trace() if _obs._tracer is not None else None
+        k0 = time.monotonic() if cur is not None else 0.0
+        verb = bytes(cmds[0][0]).upper()
+        # the tracking hooks Registry.dispatch runs a command: reads register
+        # BEFORE the dispatch, writes invalidate after it (also where it
+        # failed: possibly applied).  A member that ends per record runs
+        # them again there: a spurious push costs one refetch
+        track = self.tracking if self.tracking.active else None
+        if track is not None:
+            for c in cmds:
+                track.pre_dispatch(ctx, verb, c[1:])
+        try:
+            fused = coalesce_bitset_wave(self, ctx, cmds)
+        except Exception as e:  # noqa: BLE001 — per-wave isolation
+            if isinstance(e, RuntimeError) and "shutdown" in str(e):
+                raise ConnectionResetError(str(e)) from e
+            if verb == b"BITCOUNT":
+                fused = None
+            else:
+                if track is not None:
+                    for c in cmds:
+                        try:
+                            track.post_dispatch(ctx, verb, c[1:])
+                        except Exception:  # noqa: BLE001 — never mask the primary error
+                            pass
+                self.stats["errors"] += len(cmds)
+                enc = resp.encode_error(
+                    _DEVICE_FAULT_TRYAGAIN
+                    if ioplane.is_retryable_device_fault(e)
+                    else f"ERR internal: {type(e).__name__}: {e}"
+                )
+                return [_Encoded(enc) for _ in cmds]
+        if fused is None:
+            return [self._dispatch_per_record(ctx, cmd) for cmd in cmds]
+        rode = [c for c, r in zip(cmds, fused) if r is not None]
+        if cur is not None:
+            if verb == b"BITOP":  # the operator is part of the form
+                self._stacked_kernel_span(
+                    cur, k0, "BITOP " + bytes(cmds[0][1]).upper().decode(), rode, 2
+                )
+            else:
+                self._stacked_kernel_span(cur, k0, verb.decode(), rode)
+        if track is not None:
+            for c in rode:
+                track.post_dispatch(ctx, verb, c[1:])
+        return [
+            r if r is not None else self._dispatch_per_record(ctx, cmd)
+            for cmd, r in zip(cmds, fused)
+        ]
 
     # -- device-sharded frame dispatch (ISSUE 8) ------------------------------
 
@@ -1518,6 +1631,7 @@ class TpuServer:
         if trace is not None:
             trace.hopped("dispatch")
             _obs.set_current(trace)
+        _coalesce.count_offered(len(cmds))
         try:
             lane = self._lane_for(cmds)
             if lane is not None and lane.quarantined:
@@ -1624,9 +1738,9 @@ class TpuServer:
         """One device's ordered slice of a pipelined frame (placement
         plan_frame 'sharded' segment): runs on a worker thread WHILE the
         other devices' buckets run on theirs — the per-chip dispatch lanes
-        of device-sharded serving.  Same-verb BF blob runs inside the
-        bucket still coalesce into one stacked-bank kernel (now guaranteed
-        single-device).  Returns [(frame_index, result), ...]."""
+        of device-sharded serving.  Inside the bucket, commands on
+        different keys regroup into same-verb waves, one stacked dispatch
+        each (all on this device).  Returns [(frame_index, result), ...]."""
         if trace is not None:
             trace.hopped("dispatch")
             _obs.set_current(trace)
@@ -1653,71 +1767,74 @@ class TpuServer:
             return [(i, enc) for i, _c in items]
         cmds = [c for _i, c in items]
         out = []
-        # lone commands too (min_len 1): see coalescible_frame_runs
-        run_at: Dict[int, int] = dict(_routing.coalescible_frame_runs(cmds, 1))
-        from contextlib import nullcontext
+        # The bucket's commands regrouped into same-form waves on disjoint
+        # keys, per-key order kept (coalesce.plan_waves): each wave ONE
+        # stacked dispatch, whatever order the client wrote the tenants'
+        # commands in.  Lone commands too: which commands of a frame stand
+        # alone on a device is the frame's composition, and a lone command
+        # dispatched per record would be a program of its own on every lane.
+        waves = [
+            (form, members, [cmds[i] for i in members])
+            for form, members in plan_waves([_wave_entry(c) for c in cmds])
+        ]
+        _coalesce.count_offered(len(cmds))
+        def dispatch_waves(lo: int, hi: int) -> None:
+            for form, members, wave in waves[lo:hi]:
+                if form is None:
+                    replies = [self._dispatch_one_sync(ctx, wave[0])]
+                elif form[0] in _routing.COALESCIBLE_BLOB_VERBS:
+                    replies = self._dispatch_bloom_run(ctx, wave)
+                else:
+                    replies = self._dispatch_bitset_wave(ctx, wave)
+                out.extend((items[i][0], r) for i, r in zip(members, replies))
 
-        def dispatch_span(lo: int, hi: int) -> None:
-            ci = lo
-            while ci < hi:
-                run_end = run_at.get(ci)
-                if run_end is not None:
-                    replies = self._dispatch_bloom_run(ctx, cmds[ci:run_end])
-                    for off, r in enumerate(replies):
-                        out.append((items[ci + off][0], r))
-                    ci = run_end
-                    continue
-                out.append((items[ci][0], self._dispatch_one_sync(ctx, cmds[ci])))
-                ci += 1
-
-        # preemptible sub-windows (ISSUE 18): an oversized bucket splits its
-        # ONE bucket-wide occupancy into per-segment gates with a lane
-        # preemption point between segments.  Segments cut at dispatch-unit
-        # boundaries — one coalesced run or one single command — so a fused
-        # add run is never split mid-apply (at-most-once).
-        target = self._subwindow_target(qos_class) if lane is not None else 0
-        segs = None
-        if target > 0:
-            units: List[Tuple[int, int]] = []
-            ci = 0
-            while ci < len(cmds):
-                run_end = run_at.get(ci)
-                units.append((ci, run_end) if run_end is not None
-                             else (ci, ci + 1))
-                ci = units[-1][1]
-            unit_items = [
-                self._estimate_device_items(cmds[s:e]) for s, e in units
-            ]
-            plan = plan_subwindows(unit_items, target)
-            if len(plan) > 1:
-                segs = [(units[lo][0], units[hi - 1][1]) for lo, hi in plan]
-        if segs is not None:
-            for k, (s, e) in enumerate(segs):
-                if k:
-                    lane.preempt_point()
-                seg_cmds = cmds[s:e]
-                with lane.occupy(
-                    self._estimate_device_items(seg_cmds),
-                    qos_class=qos_class,
+        def occupied(lo: int, hi: int) -> None:
+            """waves[lo:hi] under ONE occupancy of the lane.  A lane that
+            refuses the dispatch at its gate (a kernel-launch fault, ISSUE
+            19) has run none of them: they reply the retryable fault the
+            sequential path replies a refused command."""
+            seg_cmds = [c for _f, _m, wave in waves[lo:hi] for c in wave]
+            gate = (
+                lane.occupy(
+                    self._estimate_device_items(seg_cmds), qos_class=qos_class,
                     nbytes=(
                         _sched._frame_nbytes(seg_cmds)
                         if qos_class is not None else 0
                     ),
-                ):
-                    dispatch_span(s, e)
-            return out
-
-        gate = (
-            lane.occupy(
-                self._estimate_device_items(cmds), qos_class=qos_class,
-                nbytes=(
-                    _sched._frame_nbytes(cmds) if qos_class is not None else 0
-                ),
+                )
+                if lane is not None else nullcontext()
             )
-            if lane is not None else nullcontext()
-        )
-        with gate:
-            dispatch_span(0, len(cmds))
+            with ExitStack() as held:
+                try:
+                    held.enter_context(gate)
+                except RuntimeError as e:
+                    if not ioplane.is_retryable_device_fault(e):
+                        raise
+                    self.stats["errors"] += len(seg_cmds)
+                    enc = _Encoded(resp.encode_error(_DEVICE_FAULT_TRYAGAIN))
+                    out.extend(
+                        (items[i][0], enc) for _f, members, _w in waves[lo:hi]
+                        for i in members
+                    )
+                    return
+                dispatch_waves(lo, hi)
+
+        # preemptible sub-windows (ISSUE 18): an oversized bucket splits its
+        # ONE bucket-wide occupancy into per-segment gates with a lane
+        # preemption point between segments.  Segments cut at wave
+        # boundaries, so a stacked writing dispatch is never split mid-apply
+        # (at-most-once).
+        target = self._subwindow_target(qos_class) if lane is not None else 0
+        segs = [(0, len(waves))]
+        if target > 0:
+            segs = plan_subwindows(
+                [self._estimate_device_items(wave) for _f, _m, wave in waves],
+                target,
+            )
+        for k, (lo, hi) in enumerate(segs):
+            if k:
+                lane.preempt_point()
+            occupied(lo, hi)
         return out
 
     async def _run_frame_sharded(self, ctx, commands, plan, loop, adm=None,
@@ -1994,7 +2111,6 @@ class TpuServer:
             and ctx.authenticated
             and not ctx.asking
             and shed_mask is None  # a partially-shed frame stays sequential
-            and len(commands) > 1
         ):
             try:
                 # a frame that lands on ONE lane is planned too: its bucket
@@ -2003,8 +2119,12 @@ class TpuServer:
                 # of queueing on a busy one for the few commands a socket
                 # read leaves at the end of a long frame (fanout-4: one
                 # request in five took 2.2 s against 1.1; PERF.md section 6,
-                # PR 26).  Not where bulk sub-windows are armed: the
-                # sequential loop cuts a run finer than a bucket does.
+                # PR 26).  A frame of ONE command too: what a read leaves is
+                # the frame's composition, and in a bucket the command rides
+                # the stacked program every lane has compiled, where the
+                # loop below would run a per-record program of its own.  Not
+                # where bulk sub-windows are armed: the sequential loop cuts
+                # a run finer than a bucket does.
                 plan = self.engine.placement.plan_frame(
                     commands,
                     single_device_ok=self._subwindow_target(qos_class) == 0,

@@ -424,6 +424,110 @@ def coalesce_bloom_run(server, ctx, cmds: List[List[bytes]]):
     return out
 
 
+def coalesce_bitset_wave(server, ctx, cmds: List[List[bytes]]):
+    """Stacked dispatch for one wave of same-form SETBITSB, BITOP OR / XOR
+    or BITCOUNT commands on different keys (core/coalesce.py plan_waves).
+    Returns one entry per command: a LazyReply naming the wave's ONE device
+    value, or None for a command the stacked form does not cover (malformed,
+    no such bitset, a destination that would grow, ...), which the caller
+    dispatches per record for its exact reply and error text.  Returns None
+    when nothing of the wave can ride.  The prechecks are
+    coalesce_bloom_run's, and the chaos plane's device-dispatch chokepoint
+    sits in Registry.dispatch: armed, every command goes there."""
+    import numpy as np
+
+    from redisson_tpu.net import client as _net
+    from redisson_tpu.core import coalesce as CO
+    from redisson_tpu.utils.metrics import run_hooks_end, run_hooks_start
+
+    if (
+        ctx.multi_queue is not None or not ctx.authenticated or ctx.asking
+        or _net._fault_plane is not None
+    ):
+        return None
+    verb = bytes(cmds[0][0]).upper()
+    engine = server.engine
+    # a form: parse(cmd) -> what the stack needs of it (None: not covered),
+    # fuse(args) -> (the wave's device value, rows), reply(arg, row)
+    if verb == b"SETBITSB":
+        def parse(cmd):
+            if len(cmd) == 3:
+                return _s(cmd[1]), np.frombuffer(bytes(cmd[2]), dtype="<i4")
+
+        def fuse(args):
+            return CO.fused_bitset_set_async(
+                engine, [a[0] for a in args], [a[1] for a in args]
+            )
+
+        def reply(arg, row):
+            n = arg[1].shape[0]
+            return LazyReply(
+                device=(value,), owed=n,
+                finish=lambda v: np.asarray(v[0][row, :n], np.uint8).tobytes(),
+            )
+    elif verb == b"BITCOUNT":
+        def parse(cmd):
+            if len(cmd) == 2:
+                return _s(cmd[1])
+
+        def fuse(args):
+            return CO.fused_bitcount_async(engine, args)
+
+        def reply(arg, row):
+            return LazyReply(device=(value,), owed=4, finish=lambda v: int(v[0][row]))
+    else:  # BITOP OR|XOR dest src...: dest = dest op the sources that are not dest
+        op = bytes(cmds[0][1]).upper().decode()
+
+        def parse(cmd):
+            dest = _s(cmd[2])
+            others = [n for n in (_s(a) for a in cmd[3:]) if n != dest]
+            if len(others) == 1:
+                return dest, others[0]
+
+        def fuse(args):
+            return CO.fused_bitop_async(engine, op, args)
+
+        def reply(arg, row):
+            return LazyReply(
+                device=(value,), owed=4,
+                finish=lambda v: (n := int(v[0][row])) // 8 + (1 if n % 8 else 0),
+            )
+
+    routed = server.cluster_view or server.role == "replica"
+    at: List[int] = []   # positions of the commands offered to the stack
+    args: list = []
+    for i, cmd in enumerate(cmds):
+        try:
+            if routed:
+                server.check_routing(verb.decode(), cmd[1:], asking=False,
+                                     readonly=ctx.readonly)
+            arg = parse(cmd)
+        except (RespError, ValueError, UnicodeDecodeError):
+            continue  # redirect, bad blob or name: the per-record path replies
+        if arg is not None:
+            at.append(i)
+            args.append(arg)
+    if not at:
+        return None
+    hooks = getattr(server, "hooks", None) or ()
+    name = verb.decode() + ".COALESCED"
+    tokens = run_hooks_start(hooks, name, (len(at),))
+    try:
+        value, rows = fuse(args)
+    except CO.CoalesceIneligible:
+        run_hooks_end(tokens, name, None)
+        return None
+    except BaseException as e:
+        run_hooks_end(tokens, name, e)
+        raise
+    run_hooks_end(tokens, name, None)
+    out: list = [None] * len(cmds)
+    for i, arg, row in zip(at, args, rows):
+        if row is not None:
+            out[i] = reply(arg, row)
+    return out
+
+
 @register("BFA.RESERVE")
 def cmd_bfa_reserve(server, ctx, args):
     from redisson_tpu.client.objects.bloom_array import BloomFilterArray

@@ -69,6 +69,150 @@ def test_stacked_row_buckets_are_a_short_ladder():
     assert CO.stacked_row_bucket(CO.STACK_ROW_BUCKETS[-1] + 1) is None
 
 
+# -- the wave planner ------------------------------------------------------------------
+
+
+def entry(cmd):
+    from redisson_tpu.server.server import _wave_entry
+
+    return _wave_entry([a if isinstance(a, bytes) else str(a).encode() for a in cmd])
+
+
+def tenant_cmds(t, n_bits=50):
+    _f, a, b = names(t)
+    return [("SETBITSB", a, blob4(np.arange(n_bits))), ("BITOP", "OR", a, a, b),
+            ("BITOP", "XOR", b, b, a), ("BITCOUNT", a)]
+
+
+def check_waves(cmds, waves):
+    """What every plan must hold: each command in exactly one wave; a wave of
+    one form, within the stacked shape; two commands on one key, one of them
+    writing it, keep their frame order in wave order."""
+    entries = [entry(c) for c in cmds]
+    where = {}
+    for w, (form, members) in enumerate(waves):
+        assert members == sorted(members) and members
+        assert form is not None or len(members) == 1
+        assert len(members) <= F0
+        assert len(members) == 1 or sum(entries[i][3] for i in members) <= CO.STACK_ROW_BUCKETS[-1]
+        for i in members:
+            assert i not in where and entries[i][0] == form
+            where[i] = w
+    assert sorted(where) == list(range(len(cmds)))
+    for j, (_fj, wj, rj, _nj) in enumerate(entries):
+        for i in range(j):
+            _fi, wi, ri, _ni = entries[i]
+            if set(wi) & (set(wj) | set(rj)) or set(ri) & set(wj):
+                assert where[i] < where[j], (cmds[i][:2], cmds[j][:2])
+    return where
+
+
+def forms_of(waves):
+    return [form[0].decode() + (" " + form[1].decode() if form[0] == b"BITOP" else "")
+            if form else None for form, _m in waves]
+
+
+def test_a_by_verb_frame_over_a_lane_is_six_waves():
+    """The cell's frame, one lane's share: adds, probes, then per tenant set,
+    or, xor, count, interleaved as a client flushing one batch writes them."""
+    ts = list(range(12))
+    cmds = [("BF.MADD64", names(t)[0], blob8(np.arange(40))) for t in ts[:3]]
+    cmds += [("BF.MEXISTS64", names(t)[0], blob8(np.arange(40))) for t in ts]
+    for t in ts:
+        cmds += tenant_cmds(t)
+    waves = CO.plan_waves([entry(c) for c in cmds])
+    check_waves(cmds, waves)
+    assert forms_of(waves) == ["BF.MADD64", "BF.MEXISTS64", "SETBITSB", "BITOP OR",
+                               "BITOP XOR", "BITCOUNT"]
+    assert [len(m) for _f, m in waves] == [3, 12, 12, 12, 12, 12]
+
+
+@pytest.mark.parametrize("case", ["tenant twice", "shared keys", "bitop across tenants",
+                                  "cut at 16", "ineligible between", "readers share"])
+def test_wave_plans_keep_per_key_order(case):
+    _f0, a0, b0 = names(0)
+    _f1, a1, b1 = names(1)
+    if case == "tenant twice":
+        cmds = tenant_cmds(0) + tenant_cmds(1) + tenant_cmds(0)
+        waves = CO.plan_waves([entry(c) for c in cmds])
+        where = check_waves(cmds, waves)
+        # the second visit's commands each run after the first visit's last
+        assert where[8] > where[3] and len(waves) == 8
+        assert where[4] == where[0]  # tenant 1 rides tenant 0's first waves
+    elif case == "shared keys":
+        cmds = [("SETBITSB", a0, blob4([1])), ("SETBITSB", a0, blob4([2])),
+                ("BITCOUNT", a0), ("SETBITSB", a0, blob4([3])), ("BITCOUNT", a0)]
+        waves = CO.plan_waves([entry(c) for c in cmds])
+        check_waves(cmds, waves)
+        assert [m for _f, m in waves] == [[0], [1], [2], [3], [4]]
+    elif case == "bitop across tenants":
+        cmds = [("SETBITSB", a0, blob4([1])), ("SETBITSB", a1, blob4([1])),
+                ("BITOP", "OR", a0, a0, a1),    # reads a1, writes a0
+                ("BITOP", "OR", a1, a1, b1),    # writes a1: after the wave that read it
+                ("SETBITSB", b0, blob4([1])),   # touches nothing above: first set wave
+                ("BITCOUNT", a1)]
+        waves = CO.plan_waves([entry(c) for c in cmds])
+        where = check_waves(cmds, waves)
+        assert where[0] == where[1] == where[4] == 0
+        assert where[2] < where[3] < where[5]
+    elif case == "cut at 16":
+        cmds = [("BITCOUNT", names(t)[1]) for t in range(2 * F0 + 3)]
+        waves = CO.plan_waves([entry(c) for c in cmds])
+        check_waves(cmds, waves)
+        assert [len(m) for _f, m in waves] == [F0, F0, 3]
+    elif case == "ineligible between":
+        cmds = [("SETBITSB", a0, blob4([1])), ("BITOP", "AND", a0, a0, b0),
+                ("SETBIT", a1, 5, 1), ("SETBITSB", a1, blob4([2])),
+                ("BITOP", "NOT", b1, a1), ("GETBITSB", a0, blob4([1])),
+                ("SETBITSB", b0, blob4([3]))]
+        waves = CO.plan_waves([entry(c) for c in cmds])
+        where = check_waves(cmds, waves)
+        assert forms_of(waves).count(None) == 4
+        assert where[6] == where[3] > where[1]  # the AND read b0: its set waits, then rides a1's
+    else:  # two probes of one filter still share a wave, as a run held them
+        cmds = [("BF.MEXISTS64", names(0)[0], blob8([1, 2])),
+                ("BF.MEXISTS64", names(0)[0], blob8([3])),
+                ("BF.MADD64", names(0)[0], blob8([4])),
+                ("BF.MEXISTS64", names(0)[0], blob8([4]))]
+        waves = CO.plan_waves([entry(c) for c in cmds])
+        check_waves(cmds, waves)
+        assert [m for _f, m in waves] == [[0, 1], [2], [3]]
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_random_buckets_plan_to_ordered_waves_and_bf_runs_no_shorter(seed):
+    """Random buckets over few tenants (many shared keys): the invariants
+    hold, and every run of consecutive BF commands on different filters that
+    coalescible_frame_runs + plan_stacked_chunks dispatch together today
+    still shares one wave."""
+    from redisson_tpu.client import routing
+
+    rng = np.random.default_rng(seed)
+    cmds = []
+    for _ in range(int(rng.integers(5, 120))):
+        t = int(rng.integers(0, 40))
+        bf, a, b = names(t)
+        kind = int(rng.integers(0, 9))
+        n = int(rng.choice([3, 40, 300, 2000, 9000]))
+        cmds.append([
+            ("BF.MADD64", bf, blob8(np.arange(n))), ("BF.MEXISTS64", bf, blob8(np.arange(n))),
+            ("SETBITSB", a, blob4(np.arange(n))), ("BITOP", "OR", a, a, b),
+            ("BITOP", "XOR", b, b, a), ("BITCOUNT", a), ("BITOP", "AND", a, a, b),
+            ("SETBIT", a, 3, 1), ("BITOP", "OR", a, b, names((t + 1) % 40)[1]),
+        ][kind])
+    enc = [[x if isinstance(x, bytes) else str(x).encode() for x in c] for c in cmds]
+    waves = CO.plan_waves([entry(c) for c in cmds])
+    where = check_waves(cmds, waves)
+    for s, e in routing.coalescible_frame_runs(enc, 1):
+        for cs, ce in CO.plan_stacked_chunks([len(c[2]) // 8 for c in enc[s:e]]):
+            chunk = range(s + cs, s + ce)
+            keys = [enc[i][1] for i in chunk]
+            if len(set(keys)) == len(keys) and not any(
+                    k in {c[1] for c in enc[:s]} for k in keys):
+                # different filters, untouched before the run: one wave
+                assert len({where[i] for i in chunk}) == 1, (s, e, cs, ce)
+
+
 # -- fused runs against per-record dispatch --------------------------------------
 
 
@@ -285,7 +429,7 @@ def test_one_part_fetch_runs_the_parents_programs(devices):
 
 # -- the served path, four lanes ----------------------------------------------------
 
-TENANTS = 48
+TENANTS = 72   # 16+ a lane: a lane's waves are cut at STACK_PLANES
 
 
 class Tenant:
@@ -403,13 +547,16 @@ def test_by_verb_frames_equal_the_plain_reference(served, seed):
 
 def test_frames_of_any_composition_compile_nothing_after_one(served):
     """One warm-up frame over every lane, then twenty frames whose tenant
-    count a device, piece cuts and add/probe mix are random: the process
+    count a device, piece cuts and add/probe mix are random — and pieces of
+    ONE command, what a socket read can leave of any frame: the process
     builds no XLA program."""
     st, conn, tenants = served
     rng = np.random.default_rng(26)
     placement = st.server.engine.placement
     assert {placement.device_id_for_name(names(t)[0]) for t in range(TENANTS)} == \
         {d.id for d in placement.devices}
+    a_lane = np.bincount([placement.device_id_for_name(names(t)[0]) for t in range(TENANTS)])
+    assert a_lane.max() > F0  # a lane's waves of the whole-population frame are cut
     warm, checks = by_verb_frame(rng, tenants, list(range(TENANTS)), 6)
     for reply, check in zip(send_in_pieces(conn, warm, []), checks):
         check(reply)
@@ -417,7 +564,9 @@ def test_frames_of_any_composition_compile_nothing_after_one(served):
     for i in range(20):
         picked = [int(t) for t in rng.permutation(TENANTS)[: rng.integers(1, TENANTS + 1)]]
         cmds, checks = by_verb_frame(rng, tenants, picked, int(rng.integers(0, 9)))
-        cuts = rng.integers(1, len(cmds), rng.integers(0, 4))
+        cuts = list(rng.integers(1, len(cmds), rng.integers(0, 4)))
+        lone = int(rng.integers(0, len(cmds)))  # one command alone, of any verb
+        cuts += [lone, lone + 1]
         for reply, check in zip(send_in_pieces(conn, cmds, cuts), checks):
             check(reply)
         assert programs() == before, f"frame {i} ({len(picked)} tenants, cuts {sorted(cuts)})"
@@ -473,6 +622,12 @@ def test_metrics_count_what_the_fixed_shapes_pad(served):
     assert delta("rtpu_gather_bytes_fetched_total") == CO.stacked_row_bucket(200)
 
 
+def _span_attrs(frames):
+    return [(bytes(s[0]).decode(), {bytes(s[3][i]).decode(): s[3][i + 1]
+                                    for i in range(0, len(s[3]) - 1, 2)})
+            for f in frames if int(f[4]) > 1 for s in f[7]]
+
+
 def test_kernel_and_readback_spans_say_what_they_rode(served):
     st, conn, tenants = served
     rng = np.random.default_rng(3)
@@ -485,18 +640,62 @@ def test_kernel_and_readback_spans_say_what_they_rode(served):
         frames = conn.execute("TRACE", "GET", 50)
     finally:
         conn.execute("CONFIG", "SET", "trace-enabled", "no")
-    spans = [(bytes(s[0]).decode(), {bytes(s[3][i]).decode(): s[3][i + 1]
-                                     for i in range(0, len(s[3]) - 1, 2)})
-             for f in frames if bytes(f[3]).upper().startswith(b"BF.") for s in f[7]]
+    spans = _span_attrs(frames)
     kernels = [a for n, a in spans if n == "kernel"]
     assert kernels and all(int(a["stacked"]) == F0 and 1 <= int(a["members"]) <= F0
                            for a in kernels)
-    assert sum(int(a["members"]) for a in kernels) == 3 + 12
+    rode = {}
+    for a in kernels:
+        verb = bytes(a["verb"]).decode()
+        rode[verb] = rode.get(verb, 0) + int(a["members"])
+    # every command of the frame rode a stacked dispatch, wave by wave
+    assert rode == {"BF.MADD64": 3, "BF.MEXISTS64": 12, "SETBITSB": 12, "BITOP OR": 12,
+                    "BITOP XOR": 12, "BITCOUNT": 12}
+    # at most one wave a form and lane: 12 tenants over 4 lanes
+    assert len(kernels) <= 6 * 4
+    members = [bytes(a["key"]).decode() for n, a in spans if n == "kernel.member"]
+    assert sorted(members) == sorted(
+        [names(t)[0] for t in range(3)] + [names(t)[0] for t in range(12)]
+        + [names(t)[1] for t in range(12)] * 3 + [names(t)[2] for t in range(12)])
     grouped = [a for n, a in spans if n == "readback" and int(a.get("grouped", 0))]
     assert grouped and all({"parts", "fetches", "bucket"} <= set(a) for a in grouped)
     assert all(int(a["fetches"]) <= int(a["parts"]) <= int(a["grouped"]) for a in grouped)
     assert max(int(a["bucket"]) for a in grouped) in ioplane.GATHER_STACK_RUNGS
+    # a lane owes a handful of device values, not three a tenant
+    assert max(int(a["parts"]) for a in grouped) <= 6 * 4
 
+
+def test_metrics_count_offered_and_fused_commands(served):
+    """A known frame on ONE lane: five tenants' set, or, xor, count (20
+    commands with a stacked form), one BITOP AND and one SETBIT (none)."""
+    st, conn, tenants = served
+    placement = st.server.engine.placement
+    home = placement.device_id_for_name(names(0)[0])
+    mine = [t for t in range(TENANTS)
+            if placement.device_id_for_name(names(t)[0]) == home][:5]
+    rng = np.random.default_rng(8)
+    cmds, checks = by_verb_frame(rng, tenants, mine, 0)
+    cmds, checks = cmds[len(mine):], checks[len(mine):]  # the bitset part alone
+    _f, a, b = names(mine[0])
+    cmds += [("BITOP", "AND", a, a, b), ("SETBIT", a, 7, 1)]
+    m0 = _metrics(conn)
+    replies = send_in_pieces(conn, cmds, [])
+    m1 = _metrics(conn)
+    for reply, check in zip(replies, checks):
+        check(reply)
+    ref = tenants[mine[0]]  # the two per-record commands, after the tenant's four
+    ref.a.bits &= ref.b.bits
+    assert int(replies[-2]) == ref.a.byte_length()
+    assert int(replies[-1]) == int(ref.a.bits[7])
+    ref.a.bits[7] = True
+
+    def delta(name):
+        return m1[name] - m0[name]
+
+    assert delta("rtpu_coalesce_cmds_offered_total") == 22
+    assert delta("rtpu_coalesce_cmds_fused_total") == 20
+    assert delta("rtpu_coalesce_planes_asked_total") == 20
+    assert delta("rtpu_coalesce_planes_stacked_total") == 4 * F0  # four waves
 
 
 def test_a_frame_on_one_lane_is_one_job_not_a_hop_a_command(served):
@@ -518,3 +717,232 @@ def test_a_frame_on_one_lane_is_one_job_not_a_hop_a_command(served):
     hops = [{bytes(s[3][i]).decode(): s[3][i + 1] for i in range(0, len(s[3]) - 1, 2)}
             for s in frame[7] if bytes(s[0]) == b"hop"]
     assert [bytes(h["to"]).decode() for h in hops].count("dispatch") == 1, hops
+
+
+# -- waves against per-record sequential dispatch ---------------------------------------
+
+
+@pytest.fixture(scope="module")
+def plain():
+    """A server with no placement: its frames take the sequential loop, every
+    bitset command its per-record handler."""
+    from redisson_tpu.server import ServerThread
+
+    with ServerThread() as st:
+        with st.client() as conn:
+            yield st, conn
+
+
+MIXED = 24       # tenants {m0}..{m23}, made by the test on both servers
+GROWN = 2 * BITS
+
+
+def mixed_names(t: int):
+    tag = "{m%d}" % t
+    return "bf" + tag, "ba" + tag, "bb" + tag, "bc" + tag
+
+
+def mixed_frame(rng, n_cmds: int):
+    """Commands of every kind a bucket can hold, over few tenants: the four
+    stacked forms among what they do not cover."""
+    cmds = []
+    for _ in range(n_cmds):
+        t = int(rng.integers(0, MIXED))
+        bf, a, b, c = mixed_names(t)
+        idx = rng.integers(0, BITS, int(rng.choice([1, 30, 200, 700]))).astype(np.int32)
+        cmds.append([
+            ("SETBITSB", a, blob4(idx)), ("SETBITSB", b, blob4(idx[:20])),
+            ("BITOP", "OR", a, a, b), ("BITOP", "XOR", b, b, a), ("BITOP", "OR", a, b),
+            ("BITCOUNT", a), ("BITCOUNT", b),
+            ("BITOP", "AND", a, a, b), ("BITOP", "NOT", b, a),
+            ("SETBITSB", a, blob4([3, BITS + 77])),               # grows the plane
+            ("SETBITSB", a, blob4([5, 2**31 - 1])),               # out of range
+            ("SETBITSB", a, blob4([-4, 9])),                      # negative
+            ("SETBITSB", a, b""),                                 # no index
+            ("SETBITSB", a, b"\x01\x02\x03"),                     # not a blob of int32
+            ("SETBIT", a, int(idx[0]), 1), ("GETBITSB", a, blob4(idx[:50])),
+            ("BITCOUNT", c),                                      # may not exist yet
+            ("BITCOUNT", bf),                                     # not a bitset
+            ("BITOP", "OR", a, a, bf),                            # wrong-typed source
+            ("BITOP", "OR", c, c, a),                             # dest may not exist yet
+            ("BITOP", "XOR", a, a, c),                            # source may not exist yet
+            ("BITOP", "OR", a, a, b, c),                          # two other sources
+            ("BITOP", "XOR", a, a),                               # none
+            ("BF.MADD64", bf, blob8(idx.astype(np.int64) + (t << 32))),
+            ("BF.MEXISTS64", bf, blob8(idx.astype(np.int64) + (t << 32))),
+        ][int(rng.integers(0, 25))])
+    return cmds
+
+
+def plain_reply(r):
+    return ("error", str(r)) if isinstance(r, Exception) else r
+
+
+@pytest.mark.parametrize("seed", [271, 272, 273, 274])
+def test_buckets_of_random_composition_equal_per_record_dispatch(served, plain, seed):
+    """The same frames to the four-lane server (waves) and to a server whose
+    frames take the sequential loop: reply for reply — error texts too — and
+    afterwards plane for plane and version for version."""
+    st, conn, _tenants = served
+    pst, pconn = plain
+    rng = np.random.default_rng(seed)
+    fill = []
+    for t in range(MIXED):
+        bf, a, b, c = mixed_names(t)
+        fill += [("BF.RESERVE", bf, repr(FPP), CAPACITY),
+                 ("SETBITSB", a, blob4(rng.integers(0, BITS, 150))),
+                 ("SETBITSB", b, blob4(rng.integers(0, BITS, 150)))]
+    frames = [fill] + [mixed_frame(rng, int(rng.integers(1, 90))) for _ in range(6)]
+    offered0, fused0 = CO.cmds_counted()
+    for k, frame in enumerate(frames):
+        got = conn.execute_many(frame, timeout=180.0)
+        want = pconn.execute_many(frame, timeout=180.0)
+        for i, (g, w) in enumerate(zip(got, want)):
+            assert plain_reply(g) == plain_reply(w), (k, i, frame[i][:3])
+    offered, fused = CO.cmds_counted()
+    n_cmds = sum(len(f) for f in frames)
+    # both kinds were met: commands that rode a wave, commands left per record
+    # (the counters are the process's: the plain server's BF runs count too)
+    assert 0.3 * n_cmds < fused - fused0 < offered - offered0
+    for t in range(MIXED):
+        for name in mixed_names(t)[1:]:
+            rec, prec = st.server.engine.store.get(name), pst.server.engine.store.get(name)
+            assert (rec is None) == (prec is None), name
+            if rec is not None:
+                assert rec.meta["nbits"] == prec.meta["nbits"] and rec.version == prec.version, name
+                np.testing.assert_array_equal(np.asarray(rec.arrays["bits"]),
+                                              np.asarray(prec.arrays["bits"]), err_msg=name)
+
+
+def _lane_mates(st, n):
+    placement = st.server.engine.placement
+    home = placement.device_id_for_name(names(0)[0])
+    mine = [t for t in range(TENANTS) if placement.device_id_for_name(names(t)[0]) == home]
+    assert len(mine) >= n
+    return mine[:n]
+
+
+def test_a_bitset_waves_padding_is_never_written_back(served):
+    """A set wave and an or wave of three on one lane: the three records get
+    their new planes, their versions move once a command, and no other record
+    of the lane is touched — the thirteen padding planes are stand-ins."""
+    st, conn, tenants = served
+    engine = st.server.engine
+    mine = _lane_mates(st, 8)
+    keys = [n for t in mine for n in names(t)[1:]]
+    before = {n: (engine.store.get(n).version, np.asarray(engine.store.get(n).arrays["bits"]))
+              for n in keys}
+    cmds = []
+    for t in mine[:3]:
+        cmds += [("SETBITSB", names(t)[1], blob4([1, 2, 3])), ("BITOP", "OR", names(t)[1], names(t)[2])]
+    asked0, stacked0 = CO.planes_counted()
+    replies = send_in_pieces(conn, cmds, [])
+    assert CO.planes_counted() == (asked0 + 6, stacked0 + 2 * F0)
+    for t, old, length in zip(mine[:3], replies[0::2], replies[1::2]):
+        ref = tenants[t]
+        np.testing.assert_array_equal(np.frombuffer(old, np.uint8).astype(bool),
+                                      ref.a.set_each(np.array([1, 2, 3])))
+        ref.a.or_(ref.b)
+        assert int(length) == ref.a.byte_length()
+    for t in mine:
+        for which, name in zip("ab", names(t)[1:]):
+            rec = engine.store.get(name)
+            moved = 2 if (t in mine[:3] and which == "a") else 0
+            assert rec.version == before[name][0] + moved, name
+            np.testing.assert_array_equal(
+                np.asarray(rec.arrays["bits"])[:BITS].astype(bool),
+                getattr(tenants[t], which).bits, err_msg=name)
+            if not moved:
+                np.testing.assert_array_equal(np.asarray(rec.arrays["bits"]), before[name][1])
+
+
+@pytest.mark.parametrize("form", ["SETBITSB", "BITOP"])
+def test_a_failed_writing_wave_replies_errors_and_applies_nothing_twice(served, monkeypatch, form):
+    """The stacked program fails under a writing wave: every member replies
+    an error, nothing is dispatched a second time (no per-record retry), and
+    the records are as they were."""
+    from redisson_tpu.core import kernels as K
+
+    st, conn, tenants = served
+    engine = st.server.engine
+    mine = _lane_mates(st, 4)
+    calls = []
+
+    def boom(*args, **kw):
+        calls.append(1)
+        raise ValueError("boom")
+
+    def no_per_record(*args, **kw):
+        raise AssertionError("a failed writing wave was dispatched again, per record")
+
+    monkeypatch.setattr(K, "bitset_stack_set" if form == "SETBITSB" else "bitset_stack_op", boom)
+    monkeypatch.setattr(K, "bitset_set", no_per_record)
+    monkeypatch.setattr(K, "bitset_or", no_per_record)
+    if form == "SETBITSB":
+        cmds = [("SETBITSB", names(t)[1], blob4([11, 12])) for t in mine]
+    else:
+        cmds = [("BITOP", "OR", names(t)[1], names(t)[1], names(t)[2]) for t in mine]
+    before = {names(t)[1]: (engine.store.get(names(t)[1]).version,
+                            np.asarray(engine.store.get(names(t)[1]).arrays["bits"])) for t in mine}
+    replies = conn.execute_many(cmds, timeout=60.0)
+    assert len(calls) == 1
+    assert [str(r) for r in replies] == ["ERR internal: ValueError: boom"] * len(mine)
+    for name, (version, plane) in before.items():
+        rec = engine.store.get(name)
+        assert rec.version == version
+        np.testing.assert_array_equal(np.asarray(rec.arrays["bits"]), plane)
+    monkeypatch.undo()
+    # the lane serves on: the same commands now ride
+    replies = send_in_pieces(conn, cmds, [])
+    for t, r in zip(mine, replies):
+        ref = tenants[t]
+        if form == "SETBITSB":
+            np.testing.assert_array_equal(np.frombuffer(r, np.uint8).astype(bool),
+                                          ref.a.set_each(np.array([11, 12])))
+        else:
+            ref.a.or_(ref.b)
+            assert int(r) == ref.a.byte_length()
+
+
+def test_a_failed_count_wave_falls_back_to_per_record(served, monkeypatch):
+    from redisson_tpu.core import kernels as K
+
+    st, conn, tenants = served
+    mine = _lane_mates(st, 4)
+
+    def boom(*args, **kw):
+        raise ValueError("boom")
+
+    monkeypatch.setattr(K, "bitset_stack_popcount", boom)
+    replies = send_in_pieces(conn, [("BITCOUNT", names(t)[1]) for t in mine], [])
+    assert [int(r) for r in replies] == [tenants[t].a.count() for t in mine]
+
+
+def test_a_lane_that_refuses_a_bucket_replies_tryagain_in_frame_position(served):
+    """A kernel-launch fault met at the lane's gate (ISSUE 19): none of the
+    bucket's commands ran, each replies the retryable fault, the other
+    lanes' buckets serve, and the connection lives."""
+    from redisson_tpu.chaos.faults import FaultSchedule
+    from redisson_tpu.net.client import install_fault_plane
+
+    st, conn, tenants = served
+    placement = st.server.engine.placement
+    picked = list(range(8))
+    cmds = [("BITCOUNT", names(t)[1]) for t in picked]
+    sched = FaultSchedule(0)
+    sched.add("device_kernel", after=0, count=1)  # the first lane to dispatch
+    prev = install_fault_plane(sched.plane())
+    try:
+        replies = conn.execute_many(cmds, timeout=60.0)
+    finally:
+        install_fault_plane(prev)
+    refused = {placement.device_id_for_name(names(t)[1])
+               for t, r in zip(picked, replies) if isinstance(r, Exception)}
+    assert len(refused) == 1  # one lane's bucket, whole
+    for t, r in zip(picked, replies):
+        if placement.device_id_for_name(names(t)[1]) in refused:
+            assert str(r).startswith("TRYAGAIN device fault"), r
+        else:
+            assert int(r) == tenants[t].a.count()
+    again = send_in_pieces(conn, cmds, [])
+    assert [int(r) for r in again] == [tenants[t].a.count() for t in picked]
