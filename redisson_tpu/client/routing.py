@@ -151,51 +151,6 @@ def group_by_slot_owner(
     return groups
 
 
-# blob sketch verbs whose same-verb frame runs the server may fuse into one
-# stacked-bank kernel dispatch (server/verbs/sketch.py coalesce_bloom_run —
-# the adaptive coalescing plane, ISSUE 2).  Listed HERE because run shape is
-# routing-adjacent pure logic: clients that order a shard's frame to keep
-# same-verb commands adjacent (the natural order of a fan-out batch) get
-# maximal runs server-side for free.
-COALESCIBLE_BLOB_VERBS = frozenset((b"BF.MADD64", b"BF.MEXISTS64"))
-
-
-def coalescible_frame_runs(cmds: List[Any], min_len: int = 2
-                           ) -> List[Tuple[int, int]]:
-    """Maximal [start, end) runs (len >= `min_len`) of CONSECUTIVE same-verb
-    coalescible blob commands in one pipelined frame.  Pure scan: the server
-    frame loop replaces each run with a single fused dispatch; everything
-    outside the runs dispatches per command, so frame order is untouched.
-    A device-sharded server asks for `min_len` 1: which commands of a frame
-    stand alone on a device is the frame's composition, and a lone command
-    dispatched per record would be a program of its own on every lane."""
-    def verb_of(cmd) -> Optional[bytes]:
-        # malformed frames carry non-bytes elements (nested arrays, ints);
-        # they are NOT runs — the per-command path replies their errors
-        if (
-            isinstance(cmd, list)
-            and cmd
-            and isinstance(cmd[0], (bytes, bytearray))
-        ):
-            return bytes(cmd[0]).upper()
-        return None
-
-    out: List[Tuple[int, int]] = []
-    i, n = 0, len(cmds)
-    while i < n:
-        verb = verb_of(cmds[i])
-        if verb not in COALESCIBLE_BLOB_VERBS:
-            i += 1
-            continue
-        j = i + 1
-        while j < n and verb_of(cmds[j]) == verb:
-            j += 1
-        if j - i >= min_len:
-            out.append((i, j))
-        i = j
-    return out
-
-
 def group_by_slot(keys: List[Any]) -> Dict[int, List[Any]]:
     """Keys grouped by slot (cross-slot DEL/UNLINK splitting: one multi-key
     sub-command per slot, NEVER one round trip per key)."""

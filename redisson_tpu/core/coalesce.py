@@ -73,6 +73,136 @@ class CoalesceIneligible(Exception):
     """Run cannot fuse; caller must dispatch per group."""
 
 
+# -- which commands have a stacked form, and the frame plans made from it ------
+
+# blob sketch verbs whose commands ride one stacked-bank kernel dispatch
+# (server/verbs/sketch.py coalesce_bloom_run).  A client that orders a
+# shard's frame to keep same-verb commands adjacent (the natural order of a
+# fan-out batch) gets maximal runs for free.
+COALESCIBLE_BLOB_VERBS = frozenset((b"BF.MADD64", b"BF.MEXISTS64"))
+
+_STACKED_BITOPS = (b"OR", b"XOR")
+
+
+def _frame_verb(cmd) -> Optional[bytes]:
+    """The verb of one parsed command; None for a malformed one (an empty
+    array, nested arrays, ints): it joins no group, and the per-command
+    path replies its error."""
+    if (
+        isinstance(cmd, list)
+        and cmd
+        and all(isinstance(a, (bytes, bytearray)) for a in cmd)
+    ):
+        return bytes(cmd[0]).upper()
+    return None
+
+
+def _verb_runs(verbs: List[Optional[bytes]]) -> List[Tuple[int, int]]:
+    out: List[Tuple[int, int]] = []
+    i, n = 0, len(verbs)
+    while i < n:
+        verb = verbs[i]
+        if verb not in COALESCIBLE_BLOB_VERBS:
+            i += 1
+            continue
+        j = i + 1
+        while j < n and verbs[j] == verb:
+            j += 1
+        if j - i >= 2:
+            out.append((i, j))
+        i = j
+    return out
+
+
+def coalescible_frame_runs(cmds: List[Any]) -> List[Tuple[int, int]]:
+    """Maximal [start, end) runs of two or more CONSECUTIVE same-verb
+    coalescible blob commands in one pipelined frame.  Pure scan: each run
+    is dispatched as one group; everything outside the runs dispatches per
+    command, so frame order is untouched."""
+    return _verb_runs([_frame_verb(c) for c in cmds])
+
+
+def _admitted(lo: int, hi: int, shed_mask) -> List[int]:
+    if shed_mask is None:
+        return list(range(lo, hi))
+    return [i for i in range(lo, hi) if not shed_mask[i]]
+
+
+def serial_plan(n: int, shed_mask=None) -> List[Tuple[str, Any]]:
+    """The frame plan that is right for every frame: each admitted command
+    alone, in frame order (plan_frame_runs says what a plan is)."""
+    admitted = _admitted(0, n, shed_mask)
+    return [("serial", admitted)] if admitted else []
+
+
+def plan_frame_runs(commands: List[Any], shed_mask=None) -> List[Tuple[str, Any]]:
+    """The frame plan of an engine with NO placement (a placed one:
+    server/placement.py plan_frame).  A plan is a list of segments the
+    server runs one after the other:
+
+        ("serial", [i, ...])            the commands in frame order
+        ("buckets", {lane: [i, ...]})   a lane's commands are one job, the
+                                        lanes' jobs run side by side; only
+                                        per-key order is kept
+
+    over the frame positions of the ADMITTED commands — a shed position
+    (``shed_mask``, QoS) is answered in place, is in no segment, and no
+    group spans one (runs_within_admission).  With no placement the one
+    lane is None and a bucket is a run of two or more consecutive same-verb
+    blob commands (coalescible_frame_runs); everything else is serial.  A
+    frame that holds MULTI is serial throughout: every later command must
+    append to the transaction queue in frame order, and a bucket regroups
+    its commands."""
+    n = len(commands)
+    verbs = [_frame_verb(c) for c in commands]
+    if b"MULTI" in verbs:
+        return serial_plan(n, shed_mask)
+    segments: List[Tuple[str, Any]] = []
+    at = 0
+    for s, e in runs_within_admission(_verb_runs(verbs), shed_mask) + [(n, n)]:
+        between = _admitted(at, s, shed_mask)
+        if between:
+            segments.append(("serial", between))
+        if e > s:
+            segments.append(("buckets", {None: list(range(s, e))}))
+        at = e
+    return segments
+
+
+def wave_entry(cmd):
+    """(form, writes, reads, rows) of one bucket command for plan_waves:
+    which stacked program the command can ride (None: per record) and the
+    keys that order it against the bucket's others.  `cmd` is a list of
+    bytes (placement.device_index_for_command, _frame_verb).  Forms: a BF
+    blob verb (its rows add up in the wave's window); SETBITSB at the row
+    bucket of its own indexes; BITOP OR / XOR; BITCOUNT.  What a record
+    holds is looked at when the wave is dispatched (verbs/sketch.py
+    coalesce_bitset_wave)."""
+    verb = bytes(cmd[0]).upper()
+    n = len(cmd)
+    if verb in COALESCIBLE_BLOB_VERBS and n >= 2:
+        key = (bytes(cmd[1]),)
+        rows = len(cmd[2]) // 8 if n > 2 else 0
+        if verb == b"BF.MADD64":
+            return (verb,), key, (), rows
+        return (verb,), (), key, rows
+    if verb == b"SETBITSB" and n == 3:
+        bucket = stacked_row_bucket(len(cmd[2]) // 4)
+        form = (verb, bucket) if bucket is not None and len(cmd[2]) >= 4 else None
+        return form, (bytes(cmd[1]),), (), 0
+    if verb == b"BITCOUNT" and n == 2:
+        return (verb,), (), (bytes(cmd[1]),), 0
+    if verb == b"BITOP" and n >= 4:
+        op = bytes(cmd[1]).upper()
+        form = (verb, op) if op in _STACKED_BITOPS else None
+        return form, (bytes(cmd[2]),), tuple(bytes(a) for a in cmd[3:]), 0
+    # any other verb, per record: every key counts as written
+    from redisson_tpu.net import commands as C
+
+    keys = C.command_keys(verb.decode(), cmd[1:])
+    return None, tuple(bytes(k) for k in keys), (), 0
+
+
 def runs_within_admission(runs, shed_mask) -> List[Tuple[int, int]]:
     """Split each [start, end) coalescible run at QoS shed boundaries
     (ISSUE 10): a shed command never dispatches, so a run spanning one would
@@ -258,9 +388,8 @@ def _count_planes(asked: int) -> None:
 
 
 def count_offered(n: int) -> None:
-    """`n` commands reached a place where the server decides between a
-    stacked dispatch and per-record dispatch: a device bucket, or a run of
-    the sequential path."""
+    """`n` commands reached the place where the server decides between a
+    stacked dispatch and per-record dispatch: a bucket of a frame's plan."""
     global _cmds_offered
     with _PLANES_LOCK:
         _cmds_offered += n

@@ -31,6 +31,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from redisson_tpu.core.coalesce import serial_plan
 from redisson_tpu.utils.crc16 import MAX_SLOT, calc_slot
 
 
@@ -207,8 +208,8 @@ class SlotPlacement:
     def device_index_for_command(self, cmd, owner=None) -> Optional[int]:
         """Owning device index of one whitelisted single-device command,
         else None (non-parallel verb, malformed, keyless, or keys spanning
-        devices).  The shared eligibility test of plan_frame and the
-        sequential path's per-command lane accounting.
+        devices).  The shared eligibility test of plan_frame and a serial
+        command's lane accounting.
 
         ``owner``: resolve against this owner-table SNAPSHOT instead of the
         live table — plan_frame passes one snapshot for the whole frame so
@@ -240,49 +241,34 @@ class SlotPlacement:
         }
         return next(iter(ids)) if len(ids) == 1 else None
 
-    def plan_frame(self, commands: List[List[bytes]],
-                   single_device_ok: bool = False):
-        """Partition one pipelined frame into dispatch segments:
+    def plan_frame(self, commands: List[List[bytes]], shed_mask=None):
+        """Partition one pipelined frame into dispatch segments (the plan
+        core/coalesce.py plan_frame_runs describes), run in list order:
 
-            ("sharded", {dev_index: [cmd_index, ...]})  — per-device queues
+            ("buckets", {dev_index: [cmd_index, ...]})  — per-device queues
                                                           dispatch CONCURRENTLY
             ("serial", [cmd_index, ...])                — in-order barrier run
 
-        Returns None when the frame has no cross-device parallelism to
-        exploit (single device touched, or too small) — callers keep the
-        plain sequential loop, byte-identical behavior.  Eligibility per
+        Every frame gets one, whatever its composition — a frame of one
+        command, a frame on one device: every keyed command of a placed
+        engine is dispatched by the SAME code, in a device bucket, and a
+        frame with nothing laneable is one serial segment.  Eligibility per
         command: whitelisted verb AND every key on ONE device (a cross-
         device multi-key command is a barrier; correctness never depends
-        on the plan — ineligible commands simply serialize).
-
-        ``single_device_ok`` returns a plan even when everything lands on
-        ONE device, a frame of one command too: the server asks for it so
-        that every keyed command of a placed engine is dispatched by the
-        SAME code, in a device bucket, whatever the frame's composition."""
-        if not single_device_ok and (self.n_devices <= 1 or len(commands) < 2):
-            return None
+        on the plan — ineligible commands simply serialize).  A shed
+        position (``shed_mask``, QoS) never dispatches: it is in no segment
+        and ends the one before it, so no group spans a shed command."""
         # ONE owner-table snapshot for the whole frame: a rebalance racing
         # the planner must not split same-key commands into different
         # concurrently-dispatched buckets (per-key order would break)
         owner = self.owner_snapshot()
         segments: List[Tuple[str, Any]] = []
-        cur_sharded: Optional[Dict[int, List[int]]] = None
-        cur_serial: Optional[List[int]] = None
-        devs_touched: set = set()
-
-        def flush_sharded():
-            nonlocal cur_sharded
-            if cur_sharded:
-                segments.append(("sharded", cur_sharded))
-            cur_sharded = None
-
-        def flush_serial():
-            nonlocal cur_serial
-            if cur_serial:
-                segments.append(("serial", cur_serial))
-            cur_serial = None
-
+        buckets: Optional[Dict[int, List[int]]] = None
+        serial: Optional[List[int]] = None
         for i, cmd in enumerate(commands):
+            if shed_mask is not None and shed_mask[i]:
+                buckets = serial = None
+                continue
             if (
                 isinstance(cmd, list) and cmd
                 and isinstance(cmd[0], (bytes, bytearray))
@@ -291,24 +277,17 @@ class SlotPlacement:
                 # MULTI arms queueing MID-frame: every later command of the
                 # frame must append to the transaction queue in frame order,
                 # which concurrent per-device buckets cannot guarantee —
-                # the whole frame stays on the sequential path
-                return None
+                # the whole frame is serial
+                return serial_plan(len(commands), shed_mask)
             dev = self.device_index_for_command(cmd, owner=owner)
             if dev is None:
-                flush_sharded()
-                if cur_serial is None:
-                    cur_serial = []
-                cur_serial.append(i)
+                if serial is None:
+                    serial, buckets = [], None
+                    segments.append(("serial", serial))
+                serial.append(i)
             else:
-                flush_serial()
-                if cur_sharded is None:
-                    cur_sharded = {}
-                cur_sharded.setdefault(dev, []).append(i)
-                devs_touched.add(dev)
-        flush_sharded()
-        flush_serial()
-        if len(devs_touched) <= 1 and not single_device_ok:
-            return None  # one lane: the sequential loop is already optimal
-        if not devs_touched:
-            return None  # nothing shardable at all: keep the plain loop
+                if buckets is None:
+                    buckets, serial = {}, None
+                    segments.append(("buckets", buckets))
+                buckets.setdefault(dev, []).append(i)
         return segments

@@ -35,15 +35,14 @@ import threading
 import time
 import uuid
 from concurrent.futures import ThreadPoolExecutor
-from contextlib import ExitStack, nullcontext
+from contextlib import ExitStack
 from typing import Any, Dict, List, Optional, Tuple
 
-from redisson_tpu.client import routing as _routing
 from redisson_tpu.core import ioplane
 from redisson_tpu.core import coalesce as _coalesce
 from redisson_tpu.core.coalesce import (
-    STACK_PLANES, plan_stacked_chunks, plan_subwindows, plan_waves,
-    runs_within_admission, stacked_row_bucket,
+    COALESCIBLE_BLOB_VERBS, STACK_PLANES, plan_frame_runs, plan_subwindows,
+    plan_waves, serial_plan, stacked_row_bucket, wave_entry,
 )
 from redisson_tpu.core.engine import Engine
 from redisson_tpu.net import resp
@@ -110,110 +109,81 @@ _STOP_DRAIN_S = 5.0
 _DEVICE_FAULT_TRYAGAIN = "TRYAGAIN device fault during dispatch; retry"
 
 
-def _blob_keys(cmd) -> int:
-    """Keys a BF.MADD64 / BF.MEXISTS64 command carries (8 bytes each)."""
-    return len(cmd[2]) // 8 if len(cmd) > 2 else 0
-
-
-_STACKED_BITOPS = (b"OR", b"XOR")
-
-
-def _wave_entry(cmd):
-    """(form, writes, reads, rows) of one device-bucket command for
-    coalesce.plan_waves: which stacked program the command can ride (None:
-    per record) and the keys that order it against the bucket's others.
-    `cmd` is a list of bytes with a whitelisted verb
-    (placement.device_index_for_command).  Forms: a BF blob verb (its rows
-    add up in the wave's window); SETBITSB at the row bucket of its own
-    indexes; BITOP OR / XOR; BITCOUNT.  What a record holds is looked at
-    when the wave is dispatched (verbs/sketch.py coalesce_bitset_wave)."""
-    verb = bytes(cmd[0]).upper()
-    n = len(cmd)
-    if verb in _routing.COALESCIBLE_BLOB_VERBS and n >= 2:
-        key = (bytes(cmd[1]),)
-        if verb == b"BF.MADD64":
-            return (verb,), key, (), _blob_keys(cmd)
-        return (verb,), (), key, _blob_keys(cmd)
-    if verb == b"SETBITSB" and n == 3:
-        bucket = stacked_row_bucket(len(cmd[2]) // 4)
-        form = (verb, bucket) if bucket is not None and len(cmd[2]) >= 4 else None
-        return form, (bytes(cmd[1]),), (), 0
-    if verb == b"BITCOUNT" and n == 2:
-        return (verb,), (), (bytes(cmd[1]),), 0
-    if verb == b"BITOP" and n >= 4:
-        op = bytes(cmd[1]).upper()
-        form = (verb, op) if op in _STACKED_BITOPS else None
-        return form, (bytes(cmd[2]),), tuple(bytes(a) for a in cmd[3:]), 0
-    # any other verb, per record: every key counts as written
-    from redisson_tpu.net import commands as C
-
-    keys = C.command_keys(verb.decode(), cmd[1:])
-    return None, tuple(bytes(k) for k in keys), (), 0
-
-
 def _quarantined_tryagain(dev_id: int) -> str:
     return f"TRYAGAIN device {dev_id} quarantined; retry after evacuation"
 
 
-def _force_lazies(results: list, server, trace=None) -> None:
+def _on_worker(trace, to: str, fn, *args):
+    """The one entry of a frame's work on a worker thread.  `trace` (tracing
+    armed only) closes the `hop` its submit site opened and is this
+    thread's current trace while `fn` runs, so the lane, kernel and
+    readback spans recorded below land on the right frame."""
+    if trace is None:
+        return fn(*args)
+    trace.hopped(to)
+    _obs.set_current(trace)
+    try:
+        return fn(*args)
+    finally:
+        _obs.clear_current()
+
+
+class _Laneless:
+    """What stands where a dispatch has no lane to occupy (no placement, or
+    keys on several lanes): nothing is held; with tracing armed it records
+    the `dispatch` span the lane gate records otherwise."""
+
+    __slots__ = ("_cur", "_t0")
+
+    def __enter__(self):
+        self._cur = _obs.current_trace() if _obs._tracer is not None else None
+        if self._cur is not None:
+            self._t0 = time.monotonic()
+
+    def __exit__(self, *exc):
+        if self._cur is not None:
+            self._cur.add_span("dispatch", self._t0, time.monotonic())
+        return False
+
+
+def _force_lazies(results: list, server) -> None:
     """Materialize every LazyReply of a frame in place.  Device-form lazies
     are fetched with one grouped fetch a device (the whole frame pays ~1
-    device->host sync a lane); callable-form lazies force individually.
-    `trace` (tracing armed only) is activated on this worker thread so the
-    readback spans recorded inside the gather land on the right frame."""
+    device->host sync a lane); callable-form lazies force individually.  A
+    fault surfacing at force time (watchdog timeout, kernel-launch failure)
+    is that reply's error (_error_reply: retryable -TRYAGAIN), never a
+    wedged writer (ISSUE 19)."""
     from redisson_tpu.server.registry import gather_lazy_device_results
 
-    if trace is not None:
-        trace.hopped("force")
-        _obs.set_current(trace)
-
-    def fail(i, e):
-        server.stats["errors"] += 1
-        if isinstance(e, RespError):
-            results[i] = _Encoded(resp.encode_error(str(e.args[0])))
-        elif ioplane.is_retryable_device_fault(e):
-            # watchdog timeout / kernel-launch failure surfacing at force
-            # time: a clean retryable -TRYAGAIN, never a wedged writer or
-            # an opaque internal error (ISSUE 19)
-            results[i] = _Encoded(resp.encode_error(_DEVICE_FAULT_TRYAGAIN))
-        else:
-            results[i] = _Encoded(
-                resp.encode_error(f"ERR internal: {type(e).__name__}: {e}")
-            )
-
-    try:
-        dev_idx = [
-            i for i, r in enumerate(results)
-            if isinstance(r, LazyReply) and r.device is not None
-        ]
-        if dev_idx:
-            try:
-                host_vals = gather_lazy_device_results([results[i] for i in dev_idx])
-            except ioplane.LaneWatchdogTimeout as e:
-                # the grouped drain tripped the armed lane watchdog: the
-                # frame's device-form lazies rode ONE hung transfer — fail
-                # them all retryable instead of re-forcing through the
-                # same wedged device one by one
-                for i in dev_idx:
-                    fail(i, e)
-                dev_idx, host_vals = [], None
-            except Exception:  # noqa: BLE001 — grouped path failed; force singly
-                host_vals = None
-            if host_vals is not None:
-                for i, vals in zip(dev_idx, host_vals):
-                    try:
-                        results[i] = results[i].finish(vals)
-                    except Exception as e:  # noqa: BLE001 — per-reply isolation
-                        fail(i, e)
-        for i, r in enumerate(results):
-            if isinstance(r, LazyReply):
+    dev_idx = [
+        i for i, r in enumerate(results)
+        if isinstance(r, LazyReply) and r.device is not None
+    ]
+    if dev_idx:
+        try:
+            host_vals = gather_lazy_device_results([results[i] for i in dev_idx])
+        except ioplane.LaneWatchdogTimeout as e:
+            # the grouped drain tripped the armed lane watchdog: the
+            # frame's device-form lazies rode ONE hung transfer — fail
+            # them all retryable instead of re-forcing through the
+            # same wedged device one by one
+            for i in dev_idx:
+                results[i] = server._error_reply(e)
+            dev_idx, host_vals = [], None
+        except Exception:  # noqa: BLE001 — grouped path failed; force singly
+            host_vals = None
+        if host_vals is not None:
+            for i, vals in zip(dev_idx, host_vals):
                 try:
-                    results[i] = r.force()
+                    results[i] = results[i].finish(vals)
                 except Exception as e:  # noqa: BLE001 — per-reply isolation
-                    fail(i, e)
-    finally:
-        if trace is not None:
-            _obs.clear_current()
+                    results[i] = server._error_reply(e)
+    for i, r in enumerate(results):
+        if isinstance(r, LazyReply):
+            try:
+                results[i] = r.force()
+            except Exception as e:  # noqa: BLE001 — per-reply isolation
+                results[i] = server._error_reply(e)
 
 
 # Commands whose handlers may PARK the worker thread (blocking verbs hold it
@@ -379,9 +349,8 @@ class TpuServer:
         self.metrics.gauge(
             "coalesce_planes_stacked_total", lambda: _coalesce.planes_counted()[1]
         )
-        # how often the coalescer engages: commands offered to it (device
-        # buckets, runs of the sequential path) against commands that rode
-        # a stacked dispatch
+        # how often the coalescer engages: commands offered to it (the
+        # buckets' commands) against commands that rode a stacked dispatch
         self.metrics.gauge(
             "coalesce_cmds_offered_total", lambda: _coalesce.cmds_counted()[0]
         )
@@ -1228,12 +1197,62 @@ class TpuServer:
     def paused(self) -> bool:
         return not self._pause_gate.is_set()
 
-    def _dispatch_gated(self, ctx, cmd):
+    def _await_resume(self) -> None:
+        """A dispatch job's first line: park while the server is paused."""
         if not self._pause_gate.is_set():
             # bounded so a forgotten resume() degrades to a long stall, not
             # a permanently wedged worker pool
             self._pause_gate.wait(timeout=60.0)
-        return REGISTRY.dispatch(self, ctx, cmd)
+
+    def _error_reply(self, e: BaseException, n: int = 1) -> _Encoded:
+        """THE translation of a failed dispatch into a reply, for `n`
+        commands that share it.  A stopping worker pool drops the
+        connection instead (ConnectionResetError): it never replies
+        per-command errors.  Any other failure is a per-command one — reply
+        an error, keep the connection (dropping it would kill every other
+        pipelined command on this socket)."""
+        if isinstance(e, ConnectionResetError):
+            raise e
+        if isinstance(e, RuntimeError) and "shutdown" in str(e):
+            raise ConnectionResetError(str(e)) from e
+        self.stats["errors"] += n
+        if isinstance(e, RespError):
+            text = str(e.args[0])
+        elif ioplane.is_retryable_device_fault(e):
+            # device-layer fault (kernel launch, watchdog timeout): a clean
+            # retryable -TRYAGAIN, never an opaque internal error; what may
+            # have applied is NEVER re-dispatched here — at-most-once is
+            # the client's to spend (ISSUE 19)
+            text = _DEVICE_FAULT_TRYAGAIN
+        else:  # uninitialized object, state errors, handler bugs: sandboxed
+            text = f"ERR internal: {type(e).__name__}: {e}"
+        return _Encoded(resp.encode_error(text))
+
+    def _dispatch_one(self, ctx, cmd, qos_class: Optional[str] = None,
+                      held: bool = False):
+        """One command through its handler, whatever it raises a reply
+        (_error_reply).  A serial command is its own worker job: it waits
+        at the pause gate and occupies its lane when every key maps to ONE
+        device — single-command frames (pipelined blobs bigger than one
+        recv chunk arrive one command per parse batch) and transaction
+        members still account their device occupancy against the owning
+        lane: dispatches from CONCURRENT connections bound for different
+        devices overlap, same-device ones serialize, exactly like N
+        per-chip streams.  `held`: the caller is a bucket that holds the
+        lane already — the gate is not re-entrant, so a per-record member
+        never takes it again."""
+        if not isinstance(cmd, list) or not all(
+            isinstance(a, (bytes, bytearray)) for a in cmd
+        ):
+            return _Encoded(resp.encode_error("ERR bad request frame"))
+        try:
+            if held:
+                return REGISTRY.dispatch(self, ctx, cmd)
+            self._await_resume()
+            with self._occupancy(self._lane_of(cmd), (cmd,), qos_class):
+                return REGISTRY.dispatch(self, ctx, cmd)
+        except Exception as e:  # noqa: BLE001 — sandboxed per command
+            return self._error_reply(e)
 
     def _fused_add_error_invalidate(self, track, run_names) -> None:
         """A failed fused BF.MADD64 run may have PARTIALLY applied (that is
@@ -1249,33 +1268,17 @@ class TpuServer:
                 pass
 
     def _dispatch_bloom_run(self, ctx, cmds):
-        """Coalesced execution of a same-verb BF blob run inside one frame
-        (the adaptive coalescing plane): the run is cut, at command
-        boundaries, into as many stacked dispatches as its planes and rows
-        need (coalesce.plan_stacked_chunks: one for a run of up to 64
-        commands and 16,384 keys), each a self-contained fused run with the
-        add-run at-most-once discipline of the preemptible sub-windows — a
-        failed chunk errors per command and is never re-dispatched, earlier
-        chunks already applied.  Replies extend in frame order."""
-        if not self._pause_gate.is_set():
-            self._pause_gate.wait(timeout=60.0)
-        chunks = plan_stacked_chunks([_blob_keys(c) for c in cmds])
-        if len(chunks) == 1:
-            return self._dispatch_bloom_chunk(ctx, cmds)
-        out = []
-        for s, e in chunks:
-            out.extend(self._dispatch_bloom_chunk(ctx, cmds[s:e]))
-        return out
-
-    def _dispatch_bloom_chunk(self, ctx, cmds):
-        """ONE stacked-bank kernel dispatch for `cmds` instead of one per
+        """ONE stacked-bank kernel dispatch for a wave of same-verb BF blob
+        commands (the adaptive coalescing plane) instead of one per
         command, per-command LazyReplies riding the frame's grouped d2h
-        gather.  Ineligible chunks (and a chunk of one: a command too long
-        to stack) fall back to sequential per-command dispatch with
-        identical semantics; an unexpected failure of the fused path falls
-        back only for CONTAINS runs (read-only) — add runs reply per-command
-        errors instead, so a possibly-applied mutation is never
-        re-dispatched (at-most-once)."""
+        gather.  A wave holds what one stacked dispatch holds
+        (coalesce.plan_waves: up to 16 commands and 16,384 keys).
+        Ineligible waves (and a wave of one command too long to stack) fall
+        back to sequential per-command dispatch with identical semantics;
+        an unexpected failure of the fused path falls back only for
+        CONTAINS waves (read-only) — add waves reply per-command errors
+        instead, so a possibly-applied mutation is never re-dispatched
+        (at-most-once)."""
         from redisson_tpu.server.verbs.sketch import coalesce_bloom_run
 
         cur = _obs.current_trace() if _obs._tracer is not None else None
@@ -1295,35 +1298,20 @@ class TpuServer:
             ]
             if not is_add:
                 track.note_read(ctx, run_names)
+        fused = None
         try:
-            fused = None
-            if len(cmds) > 1 or stacked_row_bucket(_blob_keys(cmds[0])) is not None:
+            if len(cmds) > 1 or (
+                len(cmds[0]) > 2
+                and stacked_row_bucket(len(cmds[0][2]) // 8) is not None
+            ):
                 fused = coalesce_bloom_run(self, ctx, cmds)
-        except RuntimeError as e:
-            if "shutdown" in str(e):
-                # same contract as the per-command path: a stopping worker
-                # pool drops the connection, never replies per-command errors
-                raise ConnectionResetError(str(e)) from e
-            if is_add:
-                self._fused_add_error_invalidate(track, run_names)
-                self.stats["errors"] += len(cmds)
-                # a device fault mid-run replies retryably (-TRYAGAIN);
-                # the possibly-applied run is NEVER re-dispatched here —
-                # at-most-once is the client's to spend (ISSUE 19)
-                enc = resp.encode_error(
-                    _DEVICE_FAULT_TRYAGAIN
-                    if ioplane.is_retryable_device_fault(e)
-                    else f"ERR internal: {type(e).__name__}: {e}"
-                )
-                return [_Encoded(enc) for _ in cmds]
-            fused = None
         except Exception as e:  # noqa: BLE001 — per-run isolation
+            # first, so that a stopping pool drops the connection whichever
+            # verb failed; a failed probe wave counts no error of its own
+            enc = self._error_reply(e, len(cmds) if is_add else 0)
             if is_add:
                 self._fused_add_error_invalidate(track, run_names)
-                self.stats["errors"] += len(cmds)
-                enc = resp.encode_error(f"ERR internal: {type(e).__name__}: {e}")
-                return [_Encoded(enc) for _ in cmds]
-            fused = None
+                return [enc for _ in cmds]
         if fused is not None:
             if cur is not None:
                 self._stacked_kernel_span(
@@ -1332,7 +1320,7 @@ class TpuServer:
             if track is not None and is_add:
                 track.note_write(run_names, ctx)
             return fused
-        return [self._dispatch_per_record(ctx, cmd) for cmd in cmds]
+        return [self._dispatch_one(ctx, cmd, held=True) for cmd in cmds]
 
     @staticmethod
     def _stacked_kernel_span(cur, k0: float, verb: str, cmds, key_at: int = 1) -> None:
@@ -1350,27 +1338,6 @@ class TpuServer:
                 key=bytes(c[key_at]).decode(errors="replace"),
             )
 
-    def _dispatch_per_record(self, ctx, cmd):
-        """One command of a run or wave the stacked path did not take: the
-        per-record handler, its errors translated per command."""
-        try:
-            return REGISTRY.dispatch(self, ctx, cmd)
-        except RespError as e:
-            self.stats["errors"] += 1
-            return _Encoded(resp.encode_error(str(e.args[0])))
-        except RuntimeError as e:
-            if "shutdown" in str(e):
-                raise ConnectionResetError(str(e)) from e
-            self.stats["errors"] += 1
-            return _Encoded(
-                resp.encode_error(f"ERR internal: {type(e).__name__}: {e}")
-            )
-        except Exception as e:  # noqa: BLE001 — sandbox per-command
-            self.stats["errors"] += 1
-            return _Encoded(
-                resp.encode_error(f"ERR internal: {type(e).__name__}: {e}")
-            )
-
     def _dispatch_bitset_wave(self, ctx, cmds):
         """ONE stacked dispatch for a wave of same-form SETBITSB, BITOP OR /
         XOR or BITCOUNT commands on different keys (coalesce.plan_waves),
@@ -1385,6 +1352,7 @@ class TpuServer:
         cur = _obs.current_trace() if _obs._tracer is not None else None
         k0 = time.monotonic() if cur is not None else 0.0
         verb = bytes(cmds[0][0]).upper()
+        writes = verb != b"BITCOUNT"
         # the tracking hooks Registry.dispatch runs a command: reads register
         # BEFORE the dispatch, writes invalidate after it (also where it
         # failed: possibly applied).  A member that ends per record runs
@@ -1393,29 +1361,21 @@ class TpuServer:
         if track is not None:
             for c in cmds:
                 track.pre_dispatch(ctx, verb, c[1:])
+        fused = None
         try:
             fused = coalesce_bitset_wave(self, ctx, cmds)
         except Exception as e:  # noqa: BLE001 — per-wave isolation
-            if isinstance(e, RuntimeError) and "shutdown" in str(e):
-                raise ConnectionResetError(str(e)) from e
-            if verb == b"BITCOUNT":
-                fused = None
-            else:
+            enc = self._error_reply(e, len(cmds) if writes else 0)
+            if writes:
                 if track is not None:
                     for c in cmds:
                         try:
                             track.post_dispatch(ctx, verb, c[1:])
                         except Exception:  # noqa: BLE001 — never mask the primary error
                             pass
-                self.stats["errors"] += len(cmds)
-                enc = resp.encode_error(
-                    _DEVICE_FAULT_TRYAGAIN
-                    if ioplane.is_retryable_device_fault(e)
-                    else f"ERR internal: {type(e).__name__}: {e}"
-                )
-                return [_Encoded(enc) for _ in cmds]
+                return [enc for _ in cmds]
         if fused is None:
-            return [self._dispatch_per_record(ctx, cmd) for cmd in cmds]
+            return [self._dispatch_one(ctx, cmd, held=True) for cmd in cmds]
         rode = [c for c, r in zip(cmds, fused) if r is not None]
         if cur is not None:
             if verb == b"BITOP":  # the operator is part of the form
@@ -1428,7 +1388,7 @@ class TpuServer:
             for c in rode:
                 track.post_dispatch(ctx, verb, c[1:])
         return [
-            r if r is not None else self._dispatch_per_record(ctx, cmd)
+            r if r is not None else self._dispatch_one(ctx, cmd, held=True)
             for cmd, r in zip(cmds, fused)
         ]
 
@@ -1529,49 +1489,31 @@ class TpuServer:
         self.engine.residency.fence_check = self._residency_fence_check
         _res.set_tier(True)
 
-    @staticmethod
-    def _estimate_device_items(cmds) -> int:
-        """Rough op count a command list dispatches to one device — the
-        occupancy unit the per-device lane accounts (and, under the bench
-        CPU-replica knob, the modeled per-chip compute time).  The sizing
-        rule itself lives in server/scheduler.py (ISSUE 10) so lane
-        accounting and tenant budgets cannot diverge."""
-        return _sched.estimate_device_items(cmds)
-
-    def _occupancy_gate(self, cmds, qos_class: Optional[str] = None):
-        """Lane-occupancy context for one sequential-path dispatch (a single
-        command or one same-verb coalesced run): the owning device's lane
-        when every key maps to ONE device, else None (no gate).  This is how
-        single-command frames — pipelined blobs bigger than one recv chunk
-        arrive one command per parse batch — still account their device
-        occupancy against the owning lane: dispatches from CONCURRENT
-        connections bound for different devices overlap, same-device ones
-        serialize, exactly like N per-chip streams."""
-        lane = self._lane_for(cmds)
+    def _occupancy(self, lane, cmds, qos_class: Optional[str] = None):
+        """The context one dispatch of `cmds` runs under: the occupancy of
+        `lane` for their estimated device items, or where there is no lane
+        the nothing that _Laneless is.  The sizing rule lives in
+        server/scheduler.py (ISSUE 10) so lane accounting and tenant
+        budgets cannot diverge."""
         if lane is None:
-            return None
+            return _Laneless()
         if lane.quarantined:
             # a QUARANTINED lane rejects new keyed work retryably while its
             # slots evacuate / await a probe — never a dispatch into a
             # faulted device stream (ISSUE 19)
             raise RespError(_quarantined_tryagain(lane.dev_id))
         return lane.occupy(
-            self._estimate_device_items(cmds), qos_class=qos_class,
+            _sched.estimate_device_items(cmds), qos_class=qos_class,
             nbytes=_sched._frame_nbytes(cmds) if qos_class is not None else 0,
         )
 
-    def _lane_for(self, cmds):
-        """The one device lane every key of `cmds` maps to, else None
-        (laneless or mixed-device: no occupancy gate)."""
+    def _lane_of(self, cmd):
+        """The one device lane every key of `cmd` maps to, else None
+        (laneless or mixed-device: nothing to occupy)."""
         eng = self.engine
         if eng.placement is None or eng.lanes is None:
             return None
-        dev = None
-        for cmd in cmds:
-            d = eng.placement.device_index_for_command(cmd)
-            if d is None or (dev is not None and d != dev):
-                return None
-            dev = d
+        dev = eng.placement.device_index_for_command(cmd)
         if dev is None:
             return None
         return eng.lanes.lane(eng.placement.devices[dev])
@@ -1584,102 +1526,6 @@ class TpuServer:
             return 0
         return ioplane.bulk_subwindow_items()
 
-    def _dispatch_laned(self, ctx, cmd, qos_class: Optional[str] = None,
-                        trace=None):
-        """Sequential-path single-command dispatch with lane accounting.
-        `trace` (tracing armed only) is activated on this worker thread so
-        lane/readback spans land on the frame; laneless dispatches record
-        their own `dispatch` span (the lane gate records it otherwise)."""
-        if trace is not None:
-            trace.hopped("dispatch")
-            _obs.set_current(trace)
-        try:
-            gate = self._occupancy_gate((cmd,), qos_class)
-            if gate is None:
-                if trace is not None:
-                    t0 = time.monotonic()
-                    try:
-                        return self._dispatch_gated(ctx, cmd)
-                    finally:
-                        trace.add_span("dispatch", t0, time.monotonic())
-                return self._dispatch_gated(ctx, cmd)
-            with gate:
-                return self._dispatch_gated(ctx, cmd)
-        finally:
-            if trace is not None:
-                _obs.clear_current()
-
-    def _dispatch_bloom_run_laned(self, ctx, cmds,
-                                  qos_class: Optional[str] = None,
-                                  trace=None):
-        """Sequential-path coalesced run with lane accounting (a run whose
-        filters span devices gets no gate — the coalescer itself falls back
-        to per-record dispatch on a mixed-device group).
-
-        Preemptible sub-windows (ISSUE 18): an oversized bulk run splits at
-        command boundaries into chunks of at most qos-bulk-subwindow-items
-        estimated device items, each chunk a SELF-CONTAINED fused dispatch
-        — its own lane occupancy, its own record locks — with
-        ``lane.preempt_point()`` between chunks so a waiting interactive
-        frame jumps the inter-sub-window boundary instead of the drained
-        window.  At-most-once survives splitting because a chunk is a
-        complete fused add run: a failed chunk replies per-command errors
-        and is never re-dispatched, while earlier chunks already applied
-        and replied (the ``runs_within_admission`` sub-run shape).  Chunk
-        replies extend in frame order, so per-connection FIFO and reply
-        bytes are identical to the unsplit dispatch."""
-        if trace is not None:
-            trace.hopped("dispatch")
-            _obs.set_current(trace)
-        _coalesce.count_offered(len(cmds))
-        try:
-            lane = self._lane_for(cmds)
-            if lane is not None and lane.quarantined:
-                # per-command retryable rejection (ISSUE 19): the run was
-                # never dispatched, so at-most-once is trivially preserved
-                self.stats["errors"] += len(cmds)
-                enc = _Encoded(
-                    resp.encode_error(_quarantined_tryagain(lane.dev_id))
-                )
-                return [enc for _ in cmds]
-            if lane is None:
-                if trace is not None:
-                    t0 = time.monotonic()
-                    try:
-                        return self._dispatch_bloom_run(ctx, cmds)
-                    finally:
-                        trace.add_span("dispatch", t0, time.monotonic())
-                return self._dispatch_bloom_run(ctx, cmds)
-            target = self._subwindow_target(qos_class)
-            chunks = None
-            if target > 0:
-                per = [_sched.estimate_command_items(c) for c in cmds]
-                plan = plan_subwindows(per, target)
-                if len(plan) > 1:
-                    chunks = plan
-            nb = _sched._frame_nbytes(cmds) if qos_class is not None else 0
-            if chunks is None:
-                with lane.occupy(self._estimate_device_items(cmds),
-                                 qos_class=qos_class, nbytes=nb):
-                    return self._dispatch_bloom_run(ctx, cmds)
-            out = []
-            for k, (s, e) in enumerate(chunks):
-                if k:
-                    lane.preempt_point()
-                sub = cmds[s:e]
-                with lane.occupy(
-                    self._estimate_device_items(sub), qos_class=qos_class,
-                    nbytes=(
-                        _sched._frame_nbytes(sub)
-                        if qos_class is not None else 0
-                    ),
-                ):
-                    out.extend(self._dispatch_bloom_run(ctx, sub))
-            return out
-        finally:
-            if trace is not None:
-                _obs.clear_current()
-
     def _pool_for(self, adm):
         """Worker pool for one frame's dispatch: interactive-class frames
         (scheduler armed) run on the reserved interactive pool so a bulk
@@ -1689,82 +1535,22 @@ class TpuServer:
             return self._qos_pool
         return self._pool
 
-    def _dispatch_one_sync(self, ctx, cmd, trace=None):
-        """One command, dispatched with the per-command error translation of
-        the connection loop (RespError -> -ERR reply, shutdown -> drop the
-        connection, anything else sandboxed per command).  `trace` (tracing
-        armed only, serial-segment path) activates the frame's trace on
-        this worker thread and records the handler window as `dispatch`."""
-        if not isinstance(cmd, list) or not all(
-            isinstance(a, (bytes, bytearray)) for a in cmd
-        ):
-            return _Encoded(resp.encode_error("ERR bad request frame"))
-        if trace is not None:
-            trace.hopped("dispatch")
-            _obs.set_current(trace)
-            t0 = time.monotonic()
-            try:
-                return self._dispatch_one_sync(ctx, cmd)
-            finally:
-                trace.add_span("dispatch", t0, time.monotonic())
-                _obs.clear_current()
-        try:
-            return self._dispatch_gated(ctx, cmd)
-        except RespError as e:
-            self.stats["errors"] += 1
-            return _Encoded(resp.encode_error(str(e.args[0])))
-        except ConnectionResetError:
-            raise
-        except RuntimeError as e:
-            if "shutdown" in str(e):
-                raise ConnectionResetError(str(e)) from e
-            self.stats["errors"] += 1
-            if ioplane.is_retryable_device_fault(e):
-                # device-layer fault (kernel launch, watchdog timeout):
-                # clean retryable -TRYAGAIN, connection survives (ISSUE 19)
-                return _Encoded(resp.encode_error(_DEVICE_FAULT_TRYAGAIN))
-            return _Encoded(
-                resp.encode_error(f"ERR internal: {type(e).__name__}: {e}")
-            )
-        except Exception as e:  # noqa: BLE001 — sandbox handler bugs per-command
-            self.stats["errors"] += 1
-            return _Encoded(
-                resp.encode_error(f"ERR internal: {type(e).__name__}: {e}")
-            )
-
-    def _dispatch_device_bucket(self, ctx, dev_index: int, items,
-                                qos_class: Optional[str] = None,
-                                trace=None):
-        """One device's ordered slice of a pipelined frame (placement
-        plan_frame 'sharded' segment): runs on a worker thread WHILE the
-        other devices' buckets run on theirs — the per-chip dispatch lanes
-        of device-sharded serving.  Inside the bucket, commands on
-        different keys regroup into same-verb waves, one stacked dispatch
-        each (all on this device).  Returns [(frame_index, result), ...]."""
-        if trace is not None:
-            trace.hopped("dispatch")
-            _obs.set_current(trace)
-            try:
-                return self._dispatch_device_bucket(
-                    ctx, dev_index, items, qos_class
-                )
-            finally:
-                _obs.clear_current()
-        if not self._pause_gate.is_set():
-            self._pause_gate.wait(timeout=60.0)
+    def _dispatch_bucket(self, ctx, dev_index: Optional[int], items,
+                         qos_class: Optional[str] = None):
+        """One lane's ordered slice of a pipelined frame (a 'buckets'
+        segment of the frame's plan), one worker job: it runs WHILE the
+        other devices' buckets run on their workers — the per-chip dispatch
+        lanes of device-sharded serving; with no placement (`dev_index`
+        None) the one bucket is a run of blob commands and there is no lane
+        to hold.  Inside the bucket, commands on different keys regroup
+        into same-verb waves, one stacked dispatch each (all on this
+        device).  Returns [(frame_index, result), ...]."""
+        self._await_resume()
         eng = self.engine
         lane = (
             eng.lanes.lane(eng.placement.devices[dev_index])
-            if eng.lanes is not None else None
+            if dev_index is not None and eng.lanes is not None else None
         )
-        if lane is not None and lane.quarantined:
-            # the whole bucket rejects retryably in frame position —
-            # the other devices' buckets still serve (ISSUE 19)
-            self.stats["errors"] += len(items)
-            enc = _Encoded(
-                resp.encode_error(_quarantined_tryagain(lane.dev_id))
-            )
-            return [(i, enc) for i, _c in items]
         cmds = [c for _i, c in items]
         out = []
         # The bucket's commands regrouped into same-form waves on disjoint
@@ -1775,96 +1561,148 @@ class TpuServer:
         # dispatched per record would be a program of its own on every lane.
         waves = [
             (form, members, [cmds[i] for i in members])
-            for form, members in plan_waves([_wave_entry(c) for c in cmds])
+            for form, members in plan_waves([wave_entry(c) for c in cmds])
         ]
         _coalesce.count_offered(len(cmds))
-        def dispatch_waves(lo: int, hi: int) -> None:
-            for form, members, wave in waves[lo:hi]:
-                if form is None:
-                    replies = [self._dispatch_one_sync(ctx, wave[0])]
-                elif form[0] in _routing.COALESCIBLE_BLOB_VERBS:
-                    replies = self._dispatch_bloom_run(ctx, wave)
-                else:
-                    replies = self._dispatch_bitset_wave(ctx, wave)
-                out.extend((items[i][0], r) for i, r in zip(members, replies))
-
-        def occupied(lo: int, hi: int) -> None:
-            """waves[lo:hi] under ONE occupancy of the lane.  A lane that
-            refuses the dispatch at its gate (a kernel-launch fault, ISSUE
-            19) has run none of them: they reply the retryable fault the
-            sequential path replies a refused command."""
-            seg_cmds = [c for _f, _m, wave in waves[lo:hi] for c in wave]
-            gate = (
-                lane.occupy(
-                    self._estimate_device_items(seg_cmds), qos_class=qos_class,
-                    nbytes=(
-                        _sched._frame_nbytes(seg_cmds)
-                        if qos_class is not None else 0
-                    ),
-                )
-                if lane is not None else nullcontext()
-            )
-            with ExitStack() as held:
-                try:
-                    held.enter_context(gate)
-                except RuntimeError as e:
-                    if not ioplane.is_retryable_device_fault(e):
-                        raise
-                    self.stats["errors"] += len(seg_cmds)
-                    enc = _Encoded(resp.encode_error(_DEVICE_FAULT_TRYAGAIN))
-                    out.extend(
-                        (items[i][0], enc) for _f, members, _w in waves[lo:hi]
-                        for i in members
-                    )
-                    return
-                dispatch_waves(lo, hi)
-
-        # preemptible sub-windows (ISSUE 18): an oversized bucket splits its
-        # ONE bucket-wide occupancy into per-segment gates with a lane
-        # preemption point between segments.  Segments cut at wave
-        # boundaries, so a stacked writing dispatch is never split mid-apply
-        # (at-most-once).
+        # preemptible sub-windows (ISSUE 18): an oversized bulk bucket splits
+        # its ONE bucket-wide occupancy into segments of at most
+        # qos-bulk-subwindow-items estimated device items, each under an
+        # occupancy of its own, with ``lane.preempt_point()`` between them so
+        # a waiting interactive frame jumps the boundary instead of the
+        # drained bucket.  Waves are first cut at COMMAND boundaries, never
+        # inside one command's key batch, each piece a SELF-CONTAINED stacked
+        # dispatch with its own record locks: at-most-once survives because
+        # a failed piece replies per-command errors and is never
+        # re-dispatched, while earlier pieces already applied and replied
+        # (the ``runs_within_admission`` sub-run shape).  Replies land by
+        # frame index, so per-connection FIFO and reply bytes are identical
+        # to the unsplit dispatch.
         target = self._subwindow_target(qos_class) if lane is not None else 0
         segs = [(0, len(waves))]
         if target > 0:
+            waves = [
+                (form, members[s:e], wave[s:e])
+                for form, members, wave in waves
+                for s, e in plan_subwindows(
+                    [_sched.estimate_command_items(c) for c in wave], target
+                )
+            ]
             segs = plan_subwindows(
-                [self._estimate_device_items(wave) for _f, _m, wave in waves],
+                [_sched.estimate_device_items(wave) for _f, _m, wave in waves],
                 target,
             )
         for k, (lo, hi) in enumerate(segs):
             if k:
                 lane.preempt_point()
-            occupied(lo, hi)
+            seg = waves[lo:hi]
+            seg_cmds = [c for _f, _m, wave in seg for c in wave]
+            with ExitStack() as held:
+                try:
+                    held.enter_context(
+                        self._occupancy(lane, seg_cmds, qos_class)
+                    )
+                except Exception as e:  # noqa: BLE001
+                    # a lane that refuses the dispatch at its gate
+                    # (quarantined, a kernel-launch fault: ISSUE 19) has run
+                    # none of them: they reply in frame position what a
+                    # refused serial command replies, and the other devices'
+                    # buckets still serve
+                    enc = self._error_reply(e, len(seg_cmds))
+                    out.extend(
+                        (items[i][0], enc) for _f, members, _w in seg
+                        for i in members
+                    )
+                    continue
+                for form, members, wave in seg:
+                    if form is None:
+                        replies = [self._dispatch_one(ctx, wave[0], held=True)]
+                    elif form[0] in COALESCIBLE_BLOB_VERBS:
+                        replies = self._dispatch_bloom_run(ctx, wave)
+                    else:
+                        replies = self._dispatch_bitset_wave(ctx, wave)
+                    out.extend(
+                        (items[i][0], r) for i, r in zip(members, replies)
+                    )
         return out
 
-    async def _run_frame_sharded(self, ctx, commands, plan, loop, adm=None,
-                                 trace=None):
-        """Execute one pipelined frame under a placement plan: 'sharded'
-        segments fan their per-device buckets out on the worker pool
-        CONCURRENTLY (each bucket FIFO on its device lane — per-key order
-        is preserved because a key maps to exactly one device), 'serial'
-        segments run in frame order as barriers.  Reply order is by frame
-        index regardless of completion order."""
-        qos_class = adm.qos_class if adm is not None else None
+    def _plan_frame(self, ctx, commands, shed_mask):
+        """The one plan a frame is run by (core/coalesce.py plan_frame_runs
+        says what a plan is).  A connection in a state the grouped
+        dispatchers do not serve — inside MULTI, unauthenticated, ASKING —
+        gets the serial plan, as does a frame of one command with no
+        placement (nothing to group: every frame of a bulk flush) and a
+        frame whose planning failed: planning must never break a frame."""
+        placement = self.engine.placement
+        if (
+            (len(commands) > 1 or placement is not None)
+            and ctx.multi_queue is None
+            and ctx.authenticated
+            and not ctx.asking
+        ):
+            try:
+                if placement is None:
+                    return plan_frame_runs(commands, shed_mask)
+                # a frame that lands on ONE lane is planned too: its bucket
+                # is one job for one worker, where a serial segment is a
+                # hop a command — nothing on an idle pool, and a second of
+                # queueing on a busy one for the few commands a socket read
+                # leaves at the end of a long frame (fanout-4: one request
+                # in five took 2.2 s against 1.1; PERF.md section 6, PR 26).
+                # A frame of ONE command too: what a read leaves is the
+                # frame's composition, and in a bucket the command rides
+                # the stacked program every lane has compiled, where a
+                # serial one would run a per-record program of its own.
+                return placement.plan_frame(commands, shed_mask)
+            except Exception:  # noqa: BLE001
+                pass
+        return serial_plan(len(commands), shed_mask)
+
+    async def _run_frame(self, ctx, commands, loop, adm=None, trace=None):
+        """Dispatch every command of one pipelined frame under its plan and
+        return the replies by frame index, whatever order they completed
+        in.  Handlers may return LazyReply — device work enqueued, NOT
+        forced: the frame's lazies are forced together afterwards
+        (_finish_frame), one device->host sync a frame and lane instead of
+        one a command.  A 'serial' segment runs its commands in frame
+        order, one hop each, as barriers; a 'buckets' segment fans its
+        per-lane buckets out on the worker pool CONCURRENTLY (each bucket
+        FIFO on its device lane — per-key order is preserved because a key
+        maps to exactly one device)."""
+        qos_class = shed_mask = None
         results: list = [None] * len(commands)
-        for seg_kind, seg in plan:
+        if adm is not None:
+            qos_class, shed_mask = adm.qos_class, adm.shed_mask
+        if shed_mask is not None:
+            # load-shed: -BUSY in frame position, NO dispatch, no queue
+            # residency (the reply FIFO is untouched — the error encodes
+            # exactly where the command's reply goes).  QoS shed boundary
+            # (ISSUE 10): no group of the plan spans a shed command — a
+            # fused window covers ADMITTED ops only, so a partially-applied
+            # coalesced add run can never be created by (or re-dispatched
+            # after) a shed decision
+            shed = _Encoded(resp.encode_error(_sched.busy_error(adm.tenant)))
+            for i, refused in enumerate(shed_mask):
+                if refused:
+                    results[i] = shed
+        pool = self._pool_for(adm)
+        for seg_kind, seg in self._plan_frame(ctx, commands, shed_mask):
             if seg_kind == "serial":
                 for i in seg:
                     cmd = commands[i]
                     self.stats["commands"] += 1
-                    pool = (
-                        self._slow_pool
-                        if (
-                            isinstance(cmd, list) and cmd
-                            and isinstance(cmd[0], (bytes, bytearray))
-                            and bytes(cmd[0]).upper() in _SLOW_COMMANDS
-                        )
-                        else self._pool_for(adm)
+                    # OBJCALL (user methods may park) and blocking verbs go
+                    # to the wide slow pool: a parked handler must never
+                    # starve the small fast pool every connection shares
+                    slow = (
+                        isinstance(cmd, list) and cmd
+                        and isinstance(cmd[0], (bytes, bytearray))
+                        and bytes(cmd[0]).upper() in _SLOW_COMMANDS
                     )
                     if trace is not None:
                         trace.hop_at = time.monotonic()
                     results[i] = await loop.run_in_executor(
-                        pool, self._dispatch_one_sync, ctx, cmd, trace
+                        self._slow_pool if slow else pool, _on_worker, trace,
+                        "dispatch", self._dispatch_one, ctx, cmd, qos_class,
                     )
                 continue
             jobs = []
@@ -1873,9 +1711,8 @@ class TpuServer:
             for dev_index, idxs in seg.items():
                 self.stats["commands"] += len(idxs)
                 jobs.append(loop.run_in_executor(
-                    self._pool_for(adm), self._dispatch_device_bucket, ctx,
-                    dev_index, [(i, commands[i]) for i in idxs], qos_class,
-                    trace,
+                    pool, _on_worker, trace, "dispatch", self._dispatch_bucket,
+                    ctx, dev_index, [(i, commands[i]) for i in idxs], qos_class,
                 ))
             outs = await asyncio.gather(*jobs, return_exceptions=True)
             err = next((o for o in outs if isinstance(o, BaseException)), None)
@@ -1885,6 +1722,46 @@ class TpuServer:
                 for i, r in out:
                     results[i] = r
         return results
+
+    async def _finish_frame(self, ctx, results, loop, write_q, readback_slots,
+                            alive, adm=None, trace=None) -> bool:
+        """The reply tail of a dispatched frame: force its lazies, encode,
+        hand the bytes to the connection's writer task.  Returns False when
+        the connection must stop reading (writer task dead)."""
+        if any(isinstance(r, LazyReply) for r in results):
+            if self.overlap:
+                # overlap plane: hand the readback to the writer task
+                # as a completion-queue entry and go straight back to
+                # reading — frame N+1's upload/dispatch overlaps this
+                # frame's D2H.  FIFO queue order preserves the reply
+                # order; proto is snapshotted at dispatch time.
+                await readback_slots.acquire()
+                if not alive["writer"]:
+                    return False  # connection is going down; stop dispatching
+                if trace is not None:
+                    trace.mark_dispatched()
+                    trace.hop_at = trace.dispatched_at
+                fut = loop.run_in_executor(
+                    self._pool_for(adm), _on_worker, trace, "force",
+                    _force_lazies, results, self,
+                )
+                write_q.put_nowait(
+                    _PendingFrame(results, fut, ctx.proto, trace)
+                )
+                return True
+            if trace is not None:
+                trace.hop_at = time.monotonic()
+            await loop.run_in_executor(
+                self._pool_for(adm), _on_worker, trace, "force",
+                _force_lazies, results, self,
+            )
+        # one queue item per frame — the whole frame's replies
+        # encode in one pass and write in one syscall batch
+        if trace is not None:
+            write_q.put_nowait(_TracedEncoded(results, ctx.proto, trace))
+        else:
+            write_q.put_nowait(_encode_frame(results, ctx.proto))
+        return True
 
     def replication_source(self):
         """Lazy master-side record shipper (server/replication.py)."""
@@ -2012,6 +1889,8 @@ class TpuServer:
         per-class in-flight ledger for its whole residency.  `trace`
         (tracing armed only) records admit + bulk-gate wait as the frame's
         `qos` span, annotated tenant/class/items/shed."""
+        if not commands:
+            return True  # a read that completed no frame
         sched = self.scheduler
         adm = None
         bulk_gate = None
@@ -2019,7 +1898,6 @@ class TpuServer:
         tq0 = time.monotonic() if trace is not None else 0.0
         if (
             sched.armed
-            and commands
             and ctx.authenticated
             and ctx.multi_queue is None
         ):
@@ -2059,9 +1937,9 @@ class TpuServer:
                         tenant=adm.tenant, cls=adm.qos_class,
                         items=adm.items, shed=adm.shed_count,
                     )
-            ok = await self._dispatch_frame(
-                ctx, commands, loop, write_q, readback_slots, alive, adm,
-                trace,
+            ok = await self._finish_frame(
+                ctx, await self._run_frame(ctx, commands, loop, adm, trace),
+                loop, write_q, readback_slots, alive, adm, trace,
             )
         finally:
             if begun:
@@ -2076,215 +1954,6 @@ class TpuServer:
             # traffic is delayed
             await asyncio.sleep(sched.shed_penalty_ms / 1000.0)
         return ok
-
-    async def _dispatch_frame(self, ctx, commands, loop, write_q,
-                              readback_slots, alive, adm=None,
-                              trace=None) -> bool:
-        # Two-phase frame execution: dispatch every command of the
-        # pipelined frame first (handlers may return LazyReply —
-        # device work enqueued, NOT forced), then force all lazy
-        # replies together and write the replies in order.  One
-        # device->host sync per frame instead of per command; per-
-        # connection ordering is untouched (dispatch stays
-        # sequential, and the device stream is in-order).
-        # Same-verb BF blob RUNS additionally collapse into one
-        # fused kernel dispatch each (_dispatch_bloom_run — the
-        # coalescing plane; runs never cross a verb change, so
-        # frame order is preserved exactly).
-        # Device-sharded frame plan (ISSUE 8): with the slot table
-        # placed over >1 device, the frame's single-device keyed
-        # data commands split into per-device queues dispatched
-        # CONCURRENTLY (one worker per device lane) instead of
-        # serializing through one lane; everything else barriers in
-        # frame order.  plan is None when there is nothing to shard
-        # — the sequential loop below is byte-identical to before.
-        qos_class = adm.qos_class if adm is not None else None
-        shed_mask = adm.shed_mask if adm is not None else None
-        shed_enc = (
-            resp.encode_error(_sched.busy_error(adm.tenant))
-            if shed_mask is not None else None
-        )
-        plan = None
-        if (
-            self.engine.placement is not None
-            and ctx.multi_queue is None
-            and ctx.authenticated
-            and not ctx.asking
-            and shed_mask is None  # a partially-shed frame stays sequential
-        ):
-            try:
-                # a frame that lands on ONE lane is planned too: its bucket
-                # is one job for one worker, where the sequential loop below
-                # is a hop a command — nothing on an idle pool, and a second
-                # of queueing on a busy one for the few commands a socket
-                # read leaves at the end of a long frame (fanout-4: one
-                # request in five took 2.2 s against 1.1; PERF.md section 6,
-                # PR 26).  A frame of ONE command too: what a read leaves is
-                # the frame's composition, and in a bucket the command rides
-                # the stacked program every lane has compiled, where the
-                # loop below would run a per-record program of its own.  Not
-                # where bulk sub-windows are armed: the sequential loop cuts
-                # a run finer than a bucket does.
-                plan = self.engine.placement.plan_frame(
-                    commands,
-                    single_device_ok=self._subwindow_target(qos_class) == 0,
-                )
-            except Exception:  # noqa: BLE001 — planning must never
-                plan = None    # break a frame; fall back to serial
-        if plan is not None:
-            results = await self._run_frame_sharded(
-                ctx, commands, plan, loop, adm, trace
-            )
-            if any(isinstance(r, LazyReply) for r in results):
-                if self.overlap:
-                    await readback_slots.acquire()
-                    if not alive["writer"]:
-                        return False
-                    if trace is not None:
-                        trace.mark_dispatched()
-                        trace.hop_at = trace.dispatched_at
-                    fut = loop.run_in_executor(
-                        self._pool_for(adm), _force_lazies, results, self,
-                        trace,
-                    )
-                    write_q.put_nowait(
-                        _PendingFrame(results, fut, ctx.proto, trace)
-                    )
-                    return True
-                if trace is not None:
-                    trace.hop_at = time.monotonic()
-                await loop.run_in_executor(
-                    self._pool_for(adm), _force_lazies, results, self, trace
-                )
-            if results:
-                if trace is not None:
-                    write_q.put_nowait(
-                        _TracedEncoded(results, ctx.proto, trace)
-                    )
-                else:
-                    write_q.put_nowait(_encode_frame(results, ctx.proto))
-            return True
-        run_at: Dict[int, int] = {}
-        if len(commands) > 1:
-            runs = [
-                (s, e)
-                for s, e in _routing.coalescible_frame_runs(
-                    commands, 1 if self.engine.placement is not None else 2
-                )
-                if all(
-                    isinstance(a, (bytes, bytearray))
-                    for c in commands[s:e]
-                    for a in c
-                )
-            ]
-            # QoS shed boundary (ISSUE 10): a run never spans a shed
-            # command — the fused window covers ADMITTED ops only, so a
-            # partially-applied coalesced add run can never be created by
-            # (or re-dispatched after) a shed decision
-            run_at = dict(runs_within_admission(runs, shed_mask))
-        results = []
-        ci = -1
-        for cmd in commands:
-            ci += 1
-            if len(results) > ci:
-                continue  # covered by an already-dispatched run
-            if shed_mask is not None and shed_mask[ci]:
-                # load-shed: -BUSY in frame position, NO dispatch, no
-                # queue residency (the reply FIFO is untouched — the
-                # error encodes exactly where the command's reply goes)
-                results.append(_Encoded(shed_enc))
-                continue
-            run_end = run_at.get(ci)
-            if run_end is not None:
-                run_cmds = commands[ci:run_end]
-                self.stats["commands"] += len(run_cmds)
-                if trace is not None:
-                    trace.hop_at = time.monotonic()
-                results.extend(
-                    await loop.run_in_executor(
-                        self._pool_for(adm), self._dispatch_bloom_run_laned,
-                        ctx, run_cmds, qos_class, trace,
-                    )
-                )
-                continue
-            if not isinstance(cmd, list) or not all(
-                isinstance(a, (bytes, bytearray)) for a in cmd
-            ):
-                results.append(_Encoded(resp.encode_error("ERR bad request frame")))
-                continue
-            self.stats["commands"] += 1
-            # OBJCALL (user methods may park) and blocking verbs go
-            # to the wide slow pool: a parked handler must never
-            # starve the small fast pool every connection shares
-            pool = (
-                self._slow_pool
-                if bytes(cmd[0]).upper() in _SLOW_COMMANDS
-                else self._pool_for(adm)
-            )
-            if trace is not None:
-                trace.hop_at = time.monotonic()
-            try:
-                results.append(
-                    await loop.run_in_executor(
-                        pool, self._dispatch_laned, ctx, cmd, qos_class,
-                        trace,
-                    )
-                )
-            except RespError as e:
-                self.stats["errors"] += 1
-                results.append(_Encoded(resp.encode_error(str(e.args[0]))))
-            except ConnectionResetError:
-                raise
-            except RuntimeError as e:
-                if "shutdown" in str(e):  # worker pool stopped: drop conn
-                    raise ConnectionResetError(str(e)) from e
-                # any other RuntimeError (uninitialized object, state
-                # errors) is a per-command failure — reply -ERR, keep
-                # the connection (dropping it would kill every other
-                # pipelined command on this socket)
-                self.stats["errors"] += 1
-                results.append(_Encoded(resp.encode_error(
-                    _DEVICE_FAULT_TRYAGAIN
-                    if ioplane.is_retryable_device_fault(e)
-                    else f"ERR internal: {type(e).__name__}: {e}"
-                )))
-            except Exception as e:  # noqa: BLE001 — sandbox handler bugs per-command
-                self.stats["errors"] += 1
-                results.append(
-                    _Encoded(resp.encode_error(f"ERR internal: {type(e).__name__}: {e}"))
-                )
-        if any(isinstance(r, LazyReply) for r in results):
-            if self.overlap:
-                # overlap plane: hand the readback to the writer task
-                # as a completion-queue entry and go straight back to
-                # reading — frame N+1's upload/dispatch overlaps this
-                # frame's D2H.  FIFO queue order preserves the reply
-                # order; proto is snapshotted at dispatch time.
-                await readback_slots.acquire()
-                if not alive["writer"]:
-                    return False  # connection is going down; stop dispatching
-                if trace is not None:
-                    trace.mark_dispatched()
-                    trace.hop_at = trace.dispatched_at
-                fut = loop.run_in_executor(
-                    self._pool_for(adm), _force_lazies, results, self, trace
-                )
-                write_q.put_nowait(_PendingFrame(results, fut, ctx.proto,
-                                                 trace))
-                return True
-            if trace is not None:
-                trace.hop_at = time.monotonic()
-            await loop.run_in_executor(
-                self._pool_for(adm), _force_lazies, results, self, trace
-            )
-        if results:
-            # one queue item per frame — the whole frame's replies
-            # encode in one pass and write in one syscall batch
-            if trace is not None:
-                write_q.put_nowait(_TracedEncoded(results, ctx.proto, trace))
-            else:
-                write_q.put_nowait(_encode_frame(results, ctx.proto))
-        return True
 
     # -- asyncio plumbing ----------------------------------------------------
 

@@ -119,7 +119,7 @@ def test_plan_frame_partitions_and_barriers():
     ]
     plan = p.plan_frame(cmds)
     kinds = [k for k, _ in plan]
-    assert kinds == ["sharded", "serial", "sharded"]
+    assert kinds == ["buckets", "serial", "buckets"]
     first, barrier, second = (seg for _k, seg in plan)
     assert sorted(i for idxs in first.values() for i in idxs) == [0, 1]
     assert barrier == [2]
@@ -130,20 +130,25 @@ def test_plan_frame_partitions_and_barriers():
             assert idxs == sorted(idxs)
 
 
-def test_plan_frame_none_when_no_parallelism():
+def test_plan_frame_plans_every_frame():
+    """Whatever a frame's composition it gets a plan, so that one code
+    dispatches it: a lone command and a one-device frame are a bucket, a
+    frame with nothing laneable is one serial segment."""
     p = SlotPlacement()
     one = _names_on_distinct_devices(p, 1)[0].encode()
-    # single command / single device / nothing shardable -> None
-    assert p.plan_frame([[b"SET", one, b"x"]]) is None
-    assert p.plan_frame([[b"SET", one, b"x"], [b"GET", one]]) is None
-    assert p.plan_frame([[b"PING"], [b"PING"]]) is None
-    # the bench A/B's 1-device leg: single_device_ok forces a plan
-    forced = p.plan_frame(
-        [[b"SET", one, b"x"], [b"GET", one]], single_device_ok=True
-    )
-    assert forced is not None and forced[0][0] == "sharded"
-    # but a frame with NOTHING laneable stays None even forced
-    assert p.plan_frame([[b"PING"], [b"PING"]], single_device_ok=True) is None
+    dev = p.device_index_for_command([b"SET", one, b"x"])
+    assert p.plan_frame([[b"SET", one, b"x"]]) == [("buckets", {dev: [0]})]
+    assert p.plan_frame([[b"SET", one, b"x"], [b"GET", one]]) == [
+        ("buckets", {dev: [0, 1]})
+    ]
+    assert p.plan_frame([[b"PING"], [b"PING"]]) == [("serial", [0, 1])]
+    assert p.plan_frame([]) == []
+    # a shed position is in no segment and ends the one before it
+    cmds = [[b"SET", one, b"x"], [b"GET", one], [b"GET", one], [b"PING"]]
+    assert p.plan_frame(cmds, [False, True, False, False]) == [
+        ("buckets", {dev: [0]}), ("buckets", {dev: [2]}), ("serial", [3]),
+    ]
+    assert p.plan_frame(cmds, [True] * 4) == []
 
 
 def test_cross_device_multikey_command_is_barrier():
@@ -156,7 +161,7 @@ def test_cross_device_multikey_command_is_barrier():
     ]
     assert p.device_index_for_command(cmds[1]) is None
     plan = p.plan_frame(cmds)
-    assert [k for k, _ in plan] == ["sharded", "serial", "sharded"]
+    assert [k for k, _ in plan] == ["buckets", "serial", "buckets"]
 
 
 # -- record placement ---------------------------------------------------------
@@ -648,7 +653,7 @@ def test_mixed_journal_dir_resume_paths_never_cross(engine, tmp_path):
 def test_plan_frame_aborts_on_in_frame_multi():
     """MULTI arms transaction queueing mid-frame: every later command must
     append to the queue in frame order, which concurrent buckets cannot
-    guarantee — the planner refuses the whole frame."""
+    guarantee — the planner makes the whole frame serial."""
     p = SlotPlacement()
     a, b = (n.encode() for n in _names_on_distinct_devices(p, 2))
     cmds = [
@@ -657,8 +662,10 @@ def test_plan_frame_aborts_on_in_frame_multi():
         [b"SET", b, b"2"],
         [b"EXEC"],
     ]
-    assert p.plan_frame(cmds) is None
-    assert p.plan_frame(cmds, single_device_ok=True) is None
+    assert p.plan_frame(cmds) == [("serial", [0, 1, 2, 3])]
+    assert p.plan_frame(cmds, [True, False, False, False]) == [
+        ("serial", [1, 2, 3])
+    ]
 
 
 def test_transaction_in_one_frame_on_sharded_server(sharded_server):

@@ -73,9 +73,9 @@ def test_stacked_row_buckets_are_a_short_ladder():
 
 
 def entry(cmd):
-    from redisson_tpu.server.server import _wave_entry
+    from redisson_tpu.core.coalesce import wave_entry
 
-    return _wave_entry([a if isinstance(a, bytes) else str(a).encode() for a in cmd])
+    return wave_entry([a if isinstance(a, bytes) else str(a).encode() for a in cmd])
 
 
 def tenant_cmds(t, n_bits=50):
@@ -185,8 +185,6 @@ def test_random_buckets_plan_to_ordered_waves_and_bf_runs_no_shorter(seed):
     hold, and every run of consecutive BF commands on different filters that
     coalescible_frame_runs + plan_stacked_chunks dispatch together today
     still shares one wave."""
-    from redisson_tpu.client import routing
-
     rng = np.random.default_rng(seed)
     cmds = []
     for _ in range(int(rng.integers(5, 120))):
@@ -203,7 +201,7 @@ def test_random_buckets_plan_to_ordered_waves_and_bf_runs_no_shorter(seed):
     enc = [[x if isinstance(x, bytes) else str(x).encode() for x in c] for c in cmds]
     waves = CO.plan_waves([entry(c) for c in cmds])
     where = check_waves(cmds, waves)
-    for s, e in routing.coalescible_frame_runs(enc, 1):
+    for s, e in CO.coalescible_frame_runs(enc):
         for cs, ce in CO.plan_stacked_chunks([len(c[2]) // 8 for c in enc[s:e]]):
             chunk = range(s + cs, s + ce)
             keys = [enc[i][1] for i in chunk]
@@ -698,25 +696,44 @@ def test_metrics_count_offered_and_fused_commands(served):
     assert delta("rtpu_coalesce_planes_stacked_total") == 4 * F0  # four waves
 
 
-def test_a_frame_on_one_lane_is_one_job_not_a_hop_a_command(served):
-    """What a socket read leaves at the end of a long frame — one tenant's
-    last commands, all on one lane — is dispatched as one bucket by one
-    worker: on a busy pool a hop a command was a second of queueing."""
-    st, conn, tenants = served
-    rng = np.random.default_rng(9)
-    cmds, checks = by_verb_frame(rng, tenants, [5], 1)  # six commands, one tenant
+def _hops_of(conn, cmds, send):
+    """The `to` of each `hop` span of the frame that carried `cmds`."""
     conn.execute("CONFIG", "SET", "trace-enabled", "yes")
     try:
         conn.execute("TRACE", "RESET")
-        for reply, check in zip(send_in_pieces(conn, cmds, []), checks):
-            check(reply)
+        send()
         frames = conn.execute("TRACE", "GET", 20)
     finally:
         conn.execute("CONFIG", "SET", "trace-enabled", "no")
-    frame = next(f for f in frames if int(f[4]) == len(cmds))
+    verb = cmds[0][0].encode()
+    frame = next(f for f in frames if int(f[4]) == len(cmds) and bytes(f[3]).startswith(verb))
     hops = [{bytes(s[3][i]).decode(): s[3][i + 1] for i in range(0, len(s[3]) - 1, 2)}
             for s in frame[7] if bytes(s[0]) == b"hop"]
-    assert [bytes(h["to"]).decode() for h in hops].count("dispatch") == 1, hops
+    return [bytes(h["to"]).decode() for h in hops]
+
+
+@pytest.mark.parametrize("case", ["one lane of four", "no placement, one command"])
+def test_a_frame_on_one_lane_is_one_job_not_a_hop_a_command(served, three_servers, case):
+    """What a socket read leaves at the end of a long frame — one tenant's
+    last commands, all on one lane — is dispatched as one bucket by one
+    worker: on a busy pool a hop a command was a second of queueing.  And
+    the frame of the bulk cells, one command with no placement, is two
+    hops: its dispatch and the force of its reply."""
+    if case == "one lane of four":
+        st, conn, tenants = served
+        rng = np.random.default_rng(9)
+        cmds, checks = by_verb_frame(rng, tenants, [5], 1)  # six commands, one tenant
+
+        def send():
+            for reply, check in zip(send_in_pieces(conn, cmds, []), checks):
+                check(reply)
+
+        assert _hops_of(conn, cmds, send).count("dispatch") == 1
+        return
+    with three_servers["no placement"].client() as conn:
+        conn.execute("BF.RESERVE", "hop{x}", repr(FPP), CAPACITY)
+        cmds = [("BF.MEXISTS64", "hop{x}", blob8(np.arange(16)))]
+        assert _hops_of(conn, cmds, lambda: send_in_pieces(conn, cmds, [])) == ["dispatch", "force"]
 
 
 # -- waves against per-record sequential dispatch ---------------------------------------
@@ -946,3 +963,152 @@ def test_a_lane_that_refuses_a_bucket_replies_tryagain_in_frame_position(served)
             assert int(r) == tenants[t].a.count()
     again = send_in_pieces(conn, cmds, [])
     assert [int(r) for r in again] == [tenants[t].a.count() for t in picked]
+
+
+def test_a_kernel_launch_fault_is_the_same_tryagain_per_record_and_serial(served):
+    """One translation of a failed dispatch: the fault replies the same
+    retryable text from a member of a wave that went per record (armed, the
+    chaos plane sends every bitset command there) and from a serial command
+    refused at its lane's gate."""
+    from redisson_tpu.chaos.faults import FaultSchedule
+    from redisson_tpu.net.client import install_fault_plane
+
+    st, conn, tenants = served
+    mine = _lane_mates(st, 3)
+    home = st.server.engine.placement.device_id_for_name(names(mine[0])[1])
+    cmds = [("BITCOUNT", names(t)[1]) for t in mine]
+
+    def faulted(after, send):
+        sched = FaultSchedule(0)
+        sched.add("device_kernel", port=home, after=after, count=1)
+        prev = install_fault_plane(sched.plane())
+        try:
+            return send()
+        finally:
+            install_fault_plane(prev)
+
+    def serial():  # after ASKING a frame is planned serial: one command, one job
+        assert conn.execute("ASKING") == b"OK"
+        return conn.execute_many(cmds[:1], timeout=60.0)
+
+    streak = ioplane.set_quarantine_after(100)  # this file's faults in a row must not quarantine the lane
+    try:
+        # the lane's dispatch stream: 0 the bucket at its gate, 1 its first member
+        in_bucket = faulted(1, lambda: conn.execute_many(cmds, timeout=60.0))
+        alone = faulted(0, serial)
+    finally:
+        ioplane.set_quarantine_after(streak)
+    assert isinstance(in_bucket[0], Exception)
+    assert [int(r) for r in in_bucket[1:]] == [tenants[t].a.count() for t in mine[1:]]
+    assert str(in_bucket[0]) == str(alone[0]) == "TRYAGAIN device fault during dispatch; retry"
+    again = send_in_pieces(conn, cmds, [])  # a clean fetch ends the lane's streak
+    assert [int(r) for r in again] == [tenants[t].a.count() for t in mine]
+
+
+# -- one frame executor: the same frames, placed or not --------------------------------
+
+
+@pytest.fixture(scope="module")
+def three_servers():
+    from contextlib import ExitStack
+
+    from redisson_tpu.server import ServerThread
+
+    with ExitStack() as stack:
+        yield {name: stack.enter_context(ServerThread(workers=4, **kw))
+               for name, kw in (("no placement", {}), ("one device", {"devices": 1}),
+                                ("eight devices", {"devices": "all"}))}
+
+
+def _converse(st, frames):
+    """One connection; each frame sent whole, its replies' raw bytes read back."""
+    import socket
+
+    from redisson_tpu.net import resp
+
+    out = []
+    parser = resp.RespParser(use_native=False)
+    with socket.create_connection((st.server.host, st.server.port), timeout=60) as s:
+        for frame in frames:
+            s.sendall(b"".join(c if isinstance(c, bytes) else resp.encode_command_python(*c)
+                               for c in frame))
+            got, n = b"", 0
+            while n < len(frame):
+                data = s.recv(1 << 16)
+                assert data, "the server closed the connection"
+                got += data
+                n += len(parser.feed(data))
+            assert n == len(frame)
+            out.append(got)
+    return out
+
+
+def _frames_of(case):
+    """The frames of one case, and read-backs of everything they touched."""
+    tag = case.replace(" ", "")
+    a, b, c = ("%s{%s}:%d" % (case[:2], tag, i) for i in range(3))
+    bits, string = "bits{%s}" % tag, "str{%s}" % tag
+    k = [blob8(np.arange(100) + 70 * i) for i in range(4)]  # overlapping key windows
+    reserve = [[("BF.RESERVE", n, repr(FPP), CAPACITY)] for n in (a, b, c)]
+    audit = [[("BF.MEXISTS64", n, blob8(np.arange(400))) for n in (a, b, c)]
+             + [("BITCOUNT", bits), ("GET", string)]]
+    if case == "a lone command":
+        body = [[("BF.MADD64", a, k[0])], [("BF.MEXISTS64", a, k[1])],
+                [("SETBITSB", bits, blob4([3, 9, 27]))], [("PING",)]]
+    elif case == "a blob run naming a filter twice":
+        body = [[("BF.MADD64", a, k[0]), ("BF.MADD64", b, k[1]), ("BF.MADD64", a, k[1]),
+                 ("BF.MADD64", c, k[2]), ("BF.MEXISTS64", a, k[2]), ("BF.MEXISTS64", b, k[2]),
+                 ("BF.MEXISTS64", a, k[0])]]
+    elif case == "a run cut by another verb":
+        body = [[("BF.MADD64", a, k[0]), ("BF.MADD64", b, k[1]), ("ECHO", "cut"),
+                 ("BF.MADD64", c, k[2]), ("BF.MEXISTS64", a, k[1]), ("SETBITSB", bits, blob4([5, 6])),
+                 ("BF.MEXISTS64", b, k[1]), ("BITCOUNT", bits), ("BF.MEXISTS64", c, k[3])]]
+    elif case == "a malformed element":
+        nested = b"*3\r\n$9\r\nBF.MADD64\r\n*1\r\n$1\r\nx\r\n$8\r\n12345678\r\n"
+        body = [[("BF.MADD64", a, k[0]), nested, ("BF.MADD64", b, k[1]), ("PING",)]]
+    elif case == "commands that fail":
+        body = [[("SET", string, "v")],
+                [("BF.MADD64", a, k[0]), ("BF.MADD64", "nofilter{x}", k[0]), ("BF.MEXISTS64", a),
+                 ("NOSUCHVERB", a), ("BITCOUNT", string),
+                 ("BITOP", "NAND", bits, bits), ("BF.MEXISTS64", a, b"123"),
+                 ("BF.MEXISTS64", b, k[0])]]
+    elif case == "MULTI met mid-frame":
+        body = [[("BF.MADD64", a, k[0]), ("MULTI",), ("BF.MADD64", b, k[1]), ("BF.MADD64", a, k[1]),
+                 ("BF.MADD64", b, k[2]), ("SET", string, "v"), ("EXEC",),
+                 ("BF.MEXISTS64", a, k[1]), ("BF.MEXISTS64", b, k[1])]]
+    elif case == "a partly shed frame":
+        # 100 items a command against 250 tokens: two admitted, two shed
+        body = [[("CONFIG", "SET", "qos-tenant-burst", "250"), ("CONFIG", "SET", "qos-tenant-rate", "1")],
+                [("BF.MADD64", a, k[0]), ("BF.MADD64", b, k[1]), ("BF.MADD64", c, k[2]),
+                 ("BF.MEXISTS64", a, k[0])],
+                [("CONFIG", "SET", "qos-tenant-rate", "0")]]
+    elif case == "sub-windows armed":
+        body = [[("CLIENT", "QOS", "CLASS", "bulk"), ("CONFIG", "SET", "qos-bulk-subwindow-items", "128")],
+                [("BF.MADD64", a, k[0]), ("BF.MADD64", b, k[1]), ("BF.MADD64", a, k[1]),
+                 ("BF.MADD64", c, k[2]), ("ECHO", "x"), ("SETBITSB", bits, blob4(np.arange(200))),
+                 ("BF.MEXISTS64", a, k[2]), ("BF.MEXISTS64", b, k[2]), ("BITCOUNT", bits)],
+                [("CONFIG", "SET", "qos-bulk-subwindow-items", "0")]]
+    return reserve + body + audit
+
+
+@pytest.mark.parametrize("case", [
+    "a lone command", "a blob run naming a filter twice", "a run cut by another verb",
+    "a malformed element", "commands that fail", "MULTI met mid-frame", "a partly shed frame",
+    "sub-windows armed"])
+def test_one_executor_answers_the_same_bytes_placed_or_not(three_servers, case):
+    """The same pipelined frames to a server with no placement, one placed
+    on one device and one placed on eight: reply bytes and final state (the
+    read-backs that close each conversation) are identical."""
+    frames = _frames_of(case)
+    try:
+        said = {name: _converse(st, frames) for name, st in three_servers.items()}
+    finally:
+        ioplane.set_bulk_subwindow_items(0)
+    plain = said.pop("no placement")
+    assert all(len(f) > 0 for f in plain)
+    if case == "a partly shed frame":
+        assert plain[4].count(b"-BUSY") == 2
+    if case == "a malformed element":
+        assert b"-ERR bad request frame" in plain[3]
+    for name, got in said.items():
+        assert got == plain, name
