@@ -10,7 +10,8 @@ and each chokepoint it crosses appends a **stage span**:
 
   ``recv``      — the read that brought the frame's first byte -> the read
                   that completed it (``reads``/``nbytes``/``feed_us``: a
-                  1 MB frame crosses many 64 KB reads).  It lies BEFORE the
+                  1 MB command crosses several reads of at most the
+                  server's _FRAME_CAP).  It lies BEFORE the
                   frame's t0, so its ``off_us`` is negative;
   ``parse``     — RESP bytes -> command list (read loop), from offset 0;
   ``qos``       — WindowScheduler classify/charge + bulk-gate wait

@@ -104,6 +104,22 @@ class _TracedEncoded:
 # bound on the graceful drain after SIGTERM/SIGINT (serve_until_signal)
 _STOP_DRAIN_S = 5.0
 
+# The most bytes ONE frame takes of what has arrived on a connection.  A
+# frame is what the stream has buffered when the read loop looks — one read
+# of up to this many bytes, never a byte waited for — so a pipelined request
+# that has landed is parsed, planned, dispatched and fetched once (fanout-4:
+# 326 commands over 64 tenants, 198 KB in one sendall, were 3.8 frames at
+# 64 KiB a read: PERF.md section 6, PR 29).  The cap is what one recv of
+# asyncio's selector transport brings at most (256 KiB), so a burst that has
+# landed whole is taken whole, and it bounds what a client that pipelines
+# without end can make of one plan and how long its first reply is held: the
+# parser returns whole commands only, so a longer pipeline is cut at a
+# command boundary and the rest is the next frame.  One command larger than
+# this (a 1.2 MB bulk flush) is buffered by the parser across reads, as it
+# always was.  Also the stream's `limit`, so the transport is not paused
+# under a burst the next read takes whole.
+_FRAME_CAP = 256 * 1024
+
 # fixed -TRYAGAIN texts (ISSUE 19): byte-identical whichever layer detects
 # the fault and whether the chaos plane is armed or not
 _DEVICE_FAULT_TRYAGAIN = "TRYAGAIN device fault during dispatch; retry"
@@ -1645,22 +1661,26 @@ class TpuServer:
                 # a frame that lands on ONE lane is planned too: its bucket
                 # is one job for one worker, where a serial segment is a
                 # hop a command — nothing on an idle pool, and a second of
-                # queueing on a busy one for the few commands a socket read
-                # leaves at the end of a long frame (fanout-4: one request
-                # in five took 2.2 s against 1.1; PERF.md section 6, PR 26).
-                # A frame of ONE command too: what a read leaves is the
-                # frame's composition, and in a bucket the command rides
-                # the stacked program every lane has compiled, where a
-                # serial one would run a per-record program of its own.
+                # queueing on a busy one.  Such frames are what is left of
+                # a pipelined request when the read loop ran before its
+                # last bytes had landed, or when the request is longer
+                # than _FRAME_CAP (with 64 KiB reads every request ended on
+                # one: PERF.md section 6, PR 26).  A frame of ONE command
+                # too: what has arrived is the frame's composition, and in
+                # a bucket the command rides the stacked program every
+                # lane has compiled, where a serial one would run a
+                # per-record program of its own.
                 return placement.plan_frame(commands, shed_mask)
             except Exception:  # noqa: BLE001
                 pass
         return serial_plan(len(commands), shed_mask)
 
     async def _run_frame(self, ctx, commands, loop, adm=None, trace=None):
-        """Dispatch every command of one pipelined frame under its plan and
-        return the replies by frame index, whatever order they completed
-        in.  Handlers may return LazyReply — device work enqueued, NOT
+        """Dispatch every command of one pipelined frame — the commands that
+        had arrived whole when the read loop looked (_handle), up to
+        _FRAME_CAP of bytes — under its plan and return the replies by
+        frame index, whatever order they completed in.  Handlers may
+        return LazyReply — device work enqueued, NOT
         forced: the frame's lazies are forced together afterwards
         (_finish_frame), one device->host sync a frame and lane instead of
         one a command.  A 'serial' segment runs its commands in frame
@@ -1958,6 +1978,13 @@ class TpuServer:
     # -- asyncio plumbing ----------------------------------------------------
 
     async def _handle(self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter):
+        """One connection: the read loop and its writer task.  A FRAME is
+        the whole commands among the bytes the stream has buffered when the
+        loop looks: one read of up to _FRAME_CAP, never a byte waited for.
+        Each frame is parsed once, admitted once (_serve_frame),
+        planned and dispatched once (_run_frame) and answered in frame
+        position; a command cut by the network stays in the parser until
+        its rest comes."""
         self.stats["connections"] += 1
         self._writers.add(writer)
         ctx = CommandContext(self)
@@ -2107,7 +2134,7 @@ class TpuServer:
         rx_t0 = rx_feed = 0.0
         try:
             while True:
-                data = await reader.read(1 << 16)
+                data = await reader.read(_FRAME_CAP)
                 if not data:
                     break
                 # tracing (observe/trace.py): frames are stamped AT PARSE
@@ -2246,7 +2273,7 @@ class TpuServer:
         self._loop = asyncio.get_running_loop()
         self._server = await asyncio.start_server(
             self._handle, self.host, self.port, reuse_address=True,
-            ssl=self._server_ssl_context(),
+            ssl=self._server_ssl_context(), limit=_FRAME_CAP,
         )
         if self.port == 0:
             self.port = self._server.sockets[0].getsockname()[1]

@@ -41,6 +41,19 @@ def tls_server(certs):
         yield st, cert
 
 
+def test_a_tls_connection_serves_a_request_larger_than_one_old_read(tls_server):
+    """The read loop takes up to _FRAME_CAP of the stream's buffer a frame
+    (ISSUE 29), under TLS as on a plain socket: a pipelined request larger
+    than one old 64 KiB read is answered whole and in order."""
+    st, _cert = tls_server
+    cmds = []
+    for i in range(100):
+        cmds += [("SET", f"tls:{i % 7}", "%06d" % i + "v" * 900), ("GET", f"tls:{i % 7}")]
+    with st.client() as conn:
+        replies = conn.execute_many(cmds, timeout=60.0)
+    assert [bytes(r) for r in replies[1::2]] == [c[2].encode() for c in cmds[0::2]]
+
+
 def test_tls_handshake_and_commands(tls_server):
     st, cert = tls_server
     ctx = client_ssl_context(ca_file=cert)  # verify_hostname default ON
