@@ -16,8 +16,13 @@ has run.  So adds count only through a later read on their connection: each
 connection ends its window with a read frame (``closing``), and the checks
 below read the bank after that.
 
+A stream client's unit of work is a CYCLE: ``read_every - 1`` add frames and
+the read frame that makes them count.  With ``"latency_over": "cycle"`` in
+the traffic file the judged latency is a cycle's wall time over its frames
+(``benchmark/latency.py``); ``cycle_ends`` below says which frames end one.
+
 Traffic parameters: pairs_per_add (100000), pairs_per_read (1000),
-read_every (10), skew.
+read_every (10), skew, latency_over.
 """
 import numpy as np
 
@@ -68,6 +73,14 @@ def _sample(seed: int, sizes: dict, zipf) -> np.ndarray:
 def _zipf(sizes: dict, params: dict, seed: int):
     return D.Zipf(sizes["counters"], params["skew"], seed,
                   among=np.arange(_live(sizes)))
+
+
+def cycle_ends(params: dict, ops) -> np.ndarray:
+    """Which requests of one connection, given their operation counts, end a
+    cycle: the read frames, the closing one too."""
+    if params["pairs_per_read"] == params["pairs_per_add"]:
+        raise ValueError("a read frame cannot be told from an add frame by its size")
+    return np.asarray(ops) == params["pairs_per_read"]
 
 
 def reference(sizes: dict, params: dict, seed: int) -> dict:

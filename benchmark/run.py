@@ -308,7 +308,7 @@ def name_gaps(device: dict, frames: list) -> list:
 
 
 def run(args) -> int:
-    from benchmark import loadgen, spans
+    from benchmark import latency, loadgen, spans
     from benchmark.reduce_trace import find_xplane
 
     cell = load_cell(ROOT, args.workload, args.rehearse_cpu, args.set)
@@ -422,7 +422,8 @@ def run(args) -> int:
     failed = int((~ok).sum()) + unsent
     for r in reports:
         failures += r["failures"] + r["errors"]
-    latency_ms = (rows[ok, 3] - rows[ok, 1]) * 1e3
+    latency_ms = latency.request_ms(rows)
+    judged_ms = latency.judged_ms(reports, params, gen)  # the requests', unless the mix says
     elapsed = max(float(args.seconds), float(rows[:, 3].max() - t_start)) if len(rows) else 0.0
     ops_per_s = float(rows[ok, 5].sum()) / elapsed if elapsed else 0.0
     i0, i1, m0, m1 = before["info"], after["info"], before["metrics"], after["metrics"]
@@ -445,17 +446,20 @@ def run(args) -> int:
         failures.append(f"server exit code {rc} after SIGTERM ({exit_s:.1f}s)")
     if not len(latency_ms):
         failures.append("no request was answered")
+    elif not len(judged_ms):
+        failures.append(f"no complete {params['latency_over']} in the window")
 
     e2e = {
         "ops_per_s": ops_per_s,
-        "req_p50_ms": float(np.median(latency_ms)) if len(latency_ms) else 0.0,
-        "req_p95_ms": float(np.percentile(latency_ms, 95)) if len(latency_ms) else 0.0,
-        "req_p99_ms": float(np.percentile(latency_ms, 99)) if len(latency_ms) else 0.0,
-        "req_max_ms": float(latency_ms.max()) if len(latency_ms) else 0.0,
+        "req_p50_ms": float(np.median(judged_ms)) if len(judged_ms) else 0.0,
+        "req_p95_ms": float(np.percentile(judged_ms, 95)) if len(judged_ms) else 0.0,
+        "req_p99_ms": float(np.percentile(judged_ms, 99)) if len(judged_ms) else 0.0,
+        "req_max_ms": float(judged_ms.max()) if len(judged_ms) else 0.0,
         "setup_s": setup_s,
     }
     obs = Observations()
-    obs.latency_ms, obs.ops_per_s = latency_ms, ops_per_s
+    obs.latency_ms, obs.judged_ms, obs.ops_per_s = latency_ms, judged_ms, ops_per_s
+    obs.request_ops, obs.params = rows[ok, 5], params
     obs.metrics_before, obs.metrics_after = m0, m1
     obs.memory_peak_bytes = memory_peak(after)
     if params["loop"] == "open":
@@ -497,7 +501,8 @@ def run(args) -> int:
                   "cache_hits": int(i0["compile_cache_hits"]),
                   "cache_writes": int(i0["compile_cache_writes"]),
                   "server_exit_s": exit_s, "timeline": timeline, **populated},
-        "client": {**e2e, "samples": int(len(latency_ms)), "elapsed_s": elapsed,
+        "client": {**e2e, "samples": int(len(judged_ms)), "requests": int(len(latency_ms)),
+                   "elapsed_s": elapsed,
                    "gen_late_p99_ms": (float(np.percentile(obs.gen_late_ms, 99))
                                        if obs.gen_late_ms is not None and len(obs.gen_late_ms)
                                        else None),

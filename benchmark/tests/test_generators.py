@@ -173,3 +173,20 @@ def test_fanout_check_passes_right_replies_and_catches_a_wrong_count(tmp_path):
     at = next(i for i, (_j, what) in enumerate(slots) if what == "count")
     replies[at] += 1
     assert any("BITCOUNT" in f for f in s.verify()["failures"])
+
+
+def test_hll_cycles_every_read_every_th_frame_reads_and_every_window_closes_on_a_read(tmp_path):
+    sizes, params = _cell("hll-10k", "stream-add-merge")
+    gen = load_generator("hll_bank")
+    np.save(tmp_path / "sample_lut.npy", gen.reference(sizes, params, 17)["sample_lut"])
+    s = gen.Stream(StreamContext(sizes, params, 17, 1, params["connections"], str(tmp_path)))
+    reqs = [s.make(i) for i in range(25)]
+    assert [i for i, r in enumerate(reqs) if r[0] == gen.KIND_READ] == [9, 19]
+    assert [r[0] for r in s.warmup()] == [gen.KIND_ADD, gen.KIND_READ]
+    closing = s.closing(25)  # whatever the last frame was, the window ends on a read
+    assert closing[0] == gen.KIND_READ
+    # a frame's operation count tells a cycle's end: the reads, the closing one too
+    ops = [s.ops(r) for r in reqs + [closing]]
+    assert np.flatnonzero(gen.cycle_ends(params, ops)).tolist() == [9, 19, 25]
+    with pytest.raises(ValueError):
+        gen.cycle_ends({**params, "pairs_per_read": params["pairs_per_add"]}, ops)

@@ -1,4 +1,4 @@
-"""``--rehearse-cpu`` for every cell, and a further cell added by entries alone.
+"""``--rehearse-cpu`` for every cell, and a further cell added by entries and a data file alone.
 
 The rehearsal is the script at tiny size on the CPU (four forced host
 devices for a four-chip cell): it must run to its end, check every reply,
@@ -53,12 +53,11 @@ def test_cell_rehearses(cell, trace):
 
 
 def test_a_further_cell_is_entries_in_the_manifest(tmp_path):
-    """What a later PR does, shown on the cell that waits for the program
-    (PERF.md section 7, first row): `fanout-4`, whose configuration, traffic
-    file, generator and readers are here already.  Its entries in a copy of
-    the manifest — one in ``configs[]``, one in ``workloads[]``, its readers
-    in ``per_layer[]``, its name on the scoped metrics it reports — and no
-    edit to any file there."""
+    """What a later PR does, shown on a cell the manifest does not have: a
+    traffic mix that is a new data file (the stream mix with three
+    connections that read every fifth frame), one entry in ``workloads[]``,
+    its name on the scoped metrics it reports — and no edit to any file
+    there."""
     root = tmp_path / "repo"
     root.mkdir()
     shutil.copytree(BENCH, root / "benchmark", ignore=shutil.ignore_patterns(
@@ -66,28 +65,33 @@ def test_a_further_cell_is_entries_in_the_manifest(tmp_path):
     for name in ("redisson_tpu", "native"):
         os.symlink(os.path.join(ROOT, name), root / name)
     m = cells()
-    m["configs"].append({"name": "cluster-mixed-8m", "source": "test", "reduced": ["masters"],
-                         "file": "benchmark/configs/cluster-mixed-8m.json", "why": "test"})
-    m["workloads"].append({"name": "fanout-4", "config": "cluster-mixed-8m",
-                           "traffic": "fanout-64-by-verb", "chips": 4, "why": "test"})
+    assert "hll-stream-3c" not in {w["name"] for w in m["workloads"]}
+    with open(root / "benchmark" / "traffic" / "stream-add-merge.json") as fh:
+        mix = json.load(fh)
+    mix["rehearse"].update(connections=3, processes=3)
+    mix.update(read_every=5)
+    with open(root / "benchmark" / "traffic" / "stream-3c-read-5th.json", "w") as fh:
+        json.dump(mix, fh)
+    m["workloads"].append({"name": "hll-stream-3c", "config": "hll-10k",
+                           "traffic": "stream-3c-read-5th", "chips": 1, "why": "test"})
     for x in m["end_to_end"] + m["per_layer"]:
-        if "workloads" in x and "bank-bulk" in x["workloads"]:
-            x["workloads"].append("fanout-4")
-    waiting = ("coalesce.cmds_per_kernel", "ioplane.stage_wait_ms", "wire.frames_per_request",
-               "device.idle_share_min", "device.idle_share_max")
-    m["per_layer"] += [{"name": n, "unit": "", "better": "lower", "source": "program_span",
-                        "layer": n.split(".")[0], "moves": "ops_per_s",
-                        "workloads": ["fanout-4"]} for n in waiting]
+        if "hll-stream" in x.get("workloads", []):
+            x["workloads"].append("hll-stream-3c")
     with open(root / "BENCHMARK.json", "w") as fh:
         json.dump(m, fh)
-    last, detail = rehearse(str(root), "fanout-4", 1)
-    assert last["device"]["count"] == 4 and last["failed"] == 0 and last["attempted"] > 0
-    # (the CPU's profile has one plane for all host devices: no min and max)
-    assert set(waiting[:3]) <= set(last["metrics"]) <= {x["name"] for x in m["per_layer"]}
-    assert last["metrics"]["coalesce.cmds_per_kernel"]["value"] > 1
-    # every reply is right; what keeps the cell out of the manifest is that the
-    # server compiles a program for every new composition of a frame
-    assert all("compiled inside the window" in f for f in detail["failures"]), detail["failures"]
+    for trace, kind in ((0, "end_to_end"), (1, "per_layer")):
+        last, detail = rehearse(str(root), "hll-stream-3c", trace, seconds="3")
+        assert detail["cell"] == "hll-stream-3c" and last["device"]["count"] == 1
+        assert detail["failures"] == [] and last["failed"] == 0 and last["attempted"] > 0
+        allowed = {x["name"] for x in m[kind] if "hll-stream-3c" in x.get("workloads",
+                                                                          ["hll-stream-3c"])}
+        assert set(last["metrics"]) <= allowed
+        if trace:
+            assert {"client.read_req_p50_ms", "kernel.device_ms_per_mop"} <= set(last["metrics"])
+        else:
+            assert set(last["metrics"]) == allowed
+            # three connections, each closing on a read after reads every fifth frame
+            assert detail["client"]["checked_reads"][0] >= 3
 
 
 def test_a_checkout_without_the_program_gives_no_result(tmp_path):

@@ -5,9 +5,10 @@
     python benchmark/tools/measure.py --cell bank-point --sweep 1000,2000,4000
 
 Runs ``benchmark/run.py`` as the driver does — one process a run, a new
-``--seed`` each — and prints, for every end-to-end metric, each set's median
-and spread (distance between the quartiles over the median) the way the
-builder's contract measures them; bounds are set from the wider spread.
+``--seed`` each run of a set, the same seeds in every set — and prints, for
+every end-to-end metric, each set's median and spread (distance between the
+quartiles of ``statistics.quantiles(values, n=4)`` over the median) the way
+the builder's contract measures them; bounds are set from the wider spread.
 ``--sweep`` runs an open-loop cell once at each total rate (the knee sweep:
 p50, p99, how late the generator ran, backlog at the end).  Everything is
 also written to ``chiprun_out/benchmark/measure-<cell>-<tag>.json``.
@@ -15,11 +16,10 @@ also written to ``chiprun_out/benchmark/measure-<cell>-<tag>.json``.
 import argparse
 import json
 import os
+import statistics
 import subprocess
 import sys
 import time
-
-import numpy as np
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
@@ -51,7 +51,10 @@ def one_run(cell: str, seed: int, seconds: float, trace: int, extra=()) -> dict:
 
 
 def spread(values) -> float:
-    q1, med, q3 = np.percentile(values, [25, 50, 75])
+    if len(values) < 2:
+        return float("nan")
+    q1, _q2, q3 = statistics.quantiles(values, n=4)
+    med = statistics.median(values)
     return float((q3 - q1) / med) if med else float("nan")
 
 
@@ -83,11 +86,12 @@ def main() -> int:
     else:
         for s in range(args.sets):
             print(f"set {s + 1}", flush=True)
-            rows = []
+            rows, seed = [], args.seed0
             for _ in range(args.runs):
                 rows.append(one_run(args.cell, seed, seconds, 0, args.extra))
                 seed += 1
             record["sets"].append(rows)
+        seed = args.seed0 + args.runs
         for _ in range(args.traced):
             record["traced"].append(one_run(args.cell, seed, seconds, 1, args.extra))
             seed += 1
@@ -102,7 +106,7 @@ def main() -> int:
                 if name == "setup_s" and rows is record["sets"][0]:
                     vals = vals[1:]
                 if vals:
-                    per_set.append({"median": float(np.median(vals)), "spread": spread(vals),
+                    per_set.append({"median": statistics.median(vals), "spread": spread(vals),
                                     "values": vals})
             summary[name] = per_set
             print(f"{name}: " + "; ".join(
