@@ -168,6 +168,9 @@ class RObject:
 
     def _touch_version(self, rec) -> None:
         rec.version += 1
+        hook = self._engine.ingest_hook
+        if hook is not None:
+            hook(self._name)
 
 
 class RExpirable(RObject):

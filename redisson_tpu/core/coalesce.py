@@ -83,6 +83,15 @@ COALESCIBLE_BLOB_VERBS = frozenset((b"BF.MADD64", b"BF.MEXISTS64"))
 
 _STACKED_BITOPS = (b"OR", b"XOR")
 
+# FT.SEARCH / FT.MSEARCH with a KNN arm: the commands of a frame's run that
+# name one index and one query text score as ONE stacked dispatch over the
+# index's embedding bank (server/verbs/modules.py coalesce_knn_run), which
+# reads the bank once for all of them.  A wave holds as many commands as the
+# largest query bucket holds vectors (services/vector.py KNN_QUERY_BUCKETS).
+KNN_VERBS = frozenset((b"FT.SEARCH", b"FT.MSEARCH"))
+KNN_FORM = b"FT.KNN"
+KNN_STACK_MAX = 64
+
 
 def _frame_verb(cmd) -> Optional[bytes]:
     """The verb of one parsed command; None for a malformed one (an empty
@@ -97,16 +106,25 @@ def _frame_verb(cmd) -> Optional[bytes]:
     return None
 
 
+def _run_family(verb: Optional[bytes]) -> Optional[bytes]:
+    """What consecutive commands must share to be one run: the verb of a BF
+    blob command, KNN_FORM for either search verb, None for the rest."""
+    if verb in COALESCIBLE_BLOB_VERBS:
+        return verb
+    return KNN_FORM if verb in KNN_VERBS else None
+
+
 def _verb_runs(verbs: List[Optional[bytes]]) -> List[Tuple[int, int]]:
     out: List[Tuple[int, int]] = []
+    families = [_run_family(v) for v in verbs]
     i, n = 0, len(verbs)
     while i < n:
-        verb = verbs[i]
-        if verb not in COALESCIBLE_BLOB_VERBS:
+        family = families[i]
+        if family is None:
             i += 1
             continue
         j = i + 1
-        while j < n and verbs[j] == verb:
+        while j < n and families[j] == family:
             j += 1
         if j - i >= 2:
             out.append((i, j))
@@ -116,9 +134,10 @@ def _verb_runs(verbs: List[Optional[bytes]]) -> List[Tuple[int, int]]:
 
 def coalescible_frame_runs(cmds: List[Any]) -> List[Tuple[int, int]]:
     """Maximal [start, end) runs of two or more CONSECUTIVE same-verb
-    coalescible blob commands in one pipelined frame.  Pure scan: each run
-    is dispatched as one group; everything outside the runs dispatches per
-    command, so frame order is untouched."""
+    coalescible blob commands, or search commands of either verb, in one
+    pipelined frame.  Pure scan: each run is dispatched as one group;
+    everything outside the runs dispatches per command, so frame order is
+    untouched."""
     return _verb_runs([_frame_verb(c) for c in cmds])
 
 
@@ -177,7 +196,8 @@ def wave_entry(cmd):
     blob verb (its rows add up in the wave's window); SETBITSB at the row
     bucket of its own indexes; BITOP OR / XOR; BITCOUNT.  What a record
     holds is looked at when the wave is dispatched (verbs/sketch.py
-    coalesce_bitset_wave)."""
+    coalesce_bitset_wave).  FT.SEARCH / FT.MSEARCH with a KNN arm: by index
+    and query text (verbs/modules.py coalesce_knn_run)."""
     verb = bytes(cmd[0]).upper()
     n = len(cmd)
     if verb in COALESCIBLE_BLOB_VERBS and n >= 2:
@@ -196,6 +216,12 @@ def wave_entry(cmd):
         op = bytes(cmd[1]).upper()
         form = (verb, op) if op in _STACKED_BITOPS else None
         return form, (bytes(cmd[2]),), tuple(bytes(a) for a in cmd[3:]), 0
+    if verb in KNN_VERBS and n >= 3:
+        # a search reads its index and writes no key; what differs in the
+        # query text (filter, field, k) is another form
+        index, query = bytes(cmd[1]), bytes(cmd[2])
+        form = (KNN_FORM, index, query) if b"=>" in query else None
+        return form, (), (b"__ftq__:" + index,), 0
     # any other verb, per record: every key counts as written
     from redisson_tpu.net import commands as C
 
@@ -328,7 +354,7 @@ def plan_waves(entries) -> List[Tuple[Any, List[int]]]:
     the end.  Two commands on one key therefore never swap, and never share
     a wave unless both only read it; a command with no key keeps its place
     against every other.  Room is the stacked shape's: STACK_PLANES members
-    and the largest row bucket (a command too long for any bucket stands
+    (KNN_STACK_MAX searches) and the largest row bucket (a command too long for any bucket stands
     alone), so consecutive same-verb commands on different keys form the
     chunks plan_stacked_chunks cuts."""
     waves: List[list] = []  # [form, positions, rows]
@@ -345,9 +371,10 @@ def plan_waves(entries) -> List[Tuple[Any, List[int]]]:
             after = max(after, last_write.get(k, -1))
         at = None
         if form is not None:
+            room = KNN_STACK_MAX if form[0] == KNN_FORM else STACK_PLANES
             at = next(
                 (w for w in range(after + 1, len(waves))
-                 if waves[w][0] == form and len(waves[w][1]) < STACK_PLANES
+                 if waves[w][0] == form and len(waves[w][1]) < room
                  and waves[w][2] + rows <= top),
                 None,
             )
@@ -369,6 +396,7 @@ _PLANES_LOCK = threading.Lock()
 _planes_asked = 0
 _planes_stacked = 0
 _cmds_offered = 0
+_knn_fused = 0
 
 
 def planes_counted() -> tuple:
@@ -395,13 +423,22 @@ def count_offered(n: int) -> None:
         _cmds_offered += n
 
 
+def count_knn_fused(n: int) -> None:
+    """`n` search commands rode one stacked KNN dispatch (no plane is
+    stacked for them: they share the index's bank)."""
+    global _knn_fused
+    with _PLANES_LOCK:
+        _knn_fused += n
+
+
 def cmds_counted() -> tuple:
     """(offered, fused) command totals: commands the server offered to the
     coalescer (count_offered) against commands that rode a stacked dispatch
-    — a member of a stacked dispatch is one plane it was asked for.  METRICS
+    — a member of a stacked dispatch is one plane it was asked for, or one
+    search of a stacked KNN.  METRICS
     exports both (coalesce_cmds_offered_total, coalesce_cmds_fused_total),
     always on."""
-    return _cmds_offered, _planes_asked
+    return _cmds_offered, _planes_asked + _knn_fused
 
 
 def _concat_segments(engine, keys_list) -> Tuple[np.ndarray, np.ndarray, List[int]]:

@@ -60,6 +60,11 @@ class Engine:
         # the ONE shared wheel timer (ServiceManager's HashedWheelTimer role)
         self._renewals: dict[tuple, Any] = {}
         self._services: dict = {}
+        # fired with a record's NAME after an object handle changed its
+        # value in place (RObject._touch_version): how a search index learns
+        # of a write at the write (services/search.py arms it while a
+        # hash-mode index exists; None costs a write one load and is-None)
+        self.ingest_hook = None
         # overlapped device I/O plane (core/ioplane): double-buffered host
         # staging shared by every flush packer of this engine
         from redisson_tpu.core import ioplane

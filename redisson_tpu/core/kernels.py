@@ -801,11 +801,13 @@ def wc_extract_words(buf, end_deltas, n_words, base):
 # --------------------------------------------------------------------------
 # Vector-search kernels (FT VECTOR / KNN, ISSUE 11).
 #
-# FLAT (exact) KNN is a dense score matrix + a top-k: queries (Q, d) against
-# a bank (C, d) is ONE (Q, d) x (d, C) matmul — the MXU's native shape — and
-# jax.lax.top_k over the masked score rows.  Same no-Pallas rationale as the
-# probe kernels above: XLA already lowers dot_general to the systolic array
-# and top_k to the tuned sort unit; a hand kernel could only re-derive them.
+# FLAT (exact) KNN is a score matrix + a top-k: queries (Q, d) against a bank
+# (C, d) is a (Q, d) x (d, C) matmul — the MXU's native shape — and
+# jax.lax.top_k over the masked score rows.  The matrix is never whole: the
+# bank is walked in row blocks, each block cut to its k best, the candidates
+# merged (knn_flat_topk).  Same no-Pallas rationale as the probe kernels
+# above: XLA already lowers dot_general to the systolic array and top_k to
+# the tuned sort unit; a hand kernel could only re-derive them.
 #
 # Distance conventions (lower = better, the RediSearch FLAT shapes):
 #   L2     — squared euclidean ||q - b||^2 (expanded form so the matmul
@@ -830,27 +832,6 @@ def wc_extract_words(buf, end_deltas, n_words, base):
 _EXACT = jax.lax.Precision.HIGHEST
 
 
-def _knn_distances(bank, bias, q, n_rows, metric: str):
-    dots = jnp.dot(q, bank.T, preferred_element_type=jnp.float32,
-                   precision=_EXACT)  # (Q, C)
-    if metric == "L2":
-        q_sq = jnp.sum(q * q, axis=1, dtype=jnp.float32)
-        b_sq = jnp.sum(bank * bank, axis=1, dtype=jnp.float32)
-        dist = q_sq[:, None] - 2.0 * dots + b_sq[None, :]
-    elif metric == "COSINE":
-        qn = jnp.sqrt(jnp.sum(q * q, axis=1, dtype=jnp.float32))
-        bn = jnp.sqrt(jnp.sum(bank * bank, axis=1, dtype=jnp.float32))
-        denom = qn[:, None] * bn[None, :]
-        dist = 1.0 - jnp.where(denom > 0.0, dots / denom, 0.0)
-    elif metric == "IP":
-        dist = 1.0 - dots
-    else:  # pragma: no cover — metric validated at FT.CREATE
-        raise ValueError(f"unknown metric {metric!r}")
-    dist = dist + bias[None, :]
-    live = jnp.arange(bank.shape[0], dtype=jnp.int32) < n_rows
-    return jnp.where(live[None, :], dist, jnp.inf)
-
-
 def _bank_f32(bank, scale):
     """Decompress-in-kernel seam (ISSUE 14): quantized banks (FLOAT16, or
     INT8 + symmetric per-row scale) widen to float32 INSIDE the scoring
@@ -865,46 +846,93 @@ def _bank_f32(bank, scale):
     return rows
 
 
-def _knn_topk_body(bank, scale, bias, q, n_rows, k: int, metric: str):
-    dist = _knn_distances(_bank_f32(bank, scale), bias, q, n_rows, metric)
-    neg, idx = jax.lax.top_k(-dist, k)
-    return -neg, idx.astype(jnp.int32)
+# Rows one step of the FLAT scan scores.  The (Q, capacity) distance matrix
+# of a 1M-row bank is 256 MB a 64-query batch; a step holds (Q, KNN_BLOCK)
+# (16 MB at Q = 64) and keeps k of it.  Chosen on the v5e over 1,048,576 x
+# 128 f32 at Q = 64, k = 10 (my chip run, PR 32; PERF.md section 6): ms a
+# call by block 1,024 / 2,048 / 4,096 / 8,192 / 16,384 / 32,768 / 65,536 =
+# 40.3 / 21.9 / 12.7 / 7.7 / 5.4 / 4.2 / 3.5 — a step's fixed cost (the
+# per-block top_k's) is what a larger block saves; the whole matrix in one
+# top_k is 4.6.  Larger blocks were not probed.
+KNN_BLOCK = 65536
 
 
-def _knn_topk_masked_body(bank, scale, bias, qbias, q, n_rows, k: int,
-                          metric: str):
-    """Hybrid prefilter: per-query additive bias (Q, C) — 0 keeps a row,
-    +inf drops it (the planner's host mask lowered onto the score matrix)."""
-    dist = (
-        _knn_distances(_bank_f32(bank, scale), bias, q, n_rows, metric)
-        + qbias
-    )
-    neg, idx = jax.lax.top_k(-dist, k)
-    return -neg, idx.astype(jnp.int32)
+def _block_topk(dist, k: int):
+    """The k smallest of each row of one block, ascending, ties toward the
+    lower column (lax.top_k is stable): (values, columns)."""
+    neg, pos = jax.lax.top_k(-dist, k)
+    return -neg, pos
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5))
-def knn_topk(bank, bias, q, n_rows, k: int, metric: str):
-    return _knn_topk_body(bank, None, bias, q, n_rows, k, metric)
+def _knn_block_dists(tile, norms, q, q_norm, metric: str):
+    """Distances of one (B, W) tile of the bank against the queries: the
+    cross term on the MXU, the rows' squared norms from the plane kept
+    beside the bank (rowbank_write_norms) — never summed again here."""
+    dots = jnp.dot(q, tile.T, preferred_element_type=jnp.float32,
+                   precision=_EXACT)  # (Q, B)
+    if metric == "L2":
+        return q_norm[:, None] - 2.0 * dots + norms[None, :]
+    if metric == "COSINE":
+        denom = q_norm[:, None] * jnp.sqrt(norms)[None, :]
+        return 1.0 - jnp.where(denom > 0.0, dots / denom, 0.0)
+    if metric == "IP":
+        return 1.0 - dots
+    raise ValueError(f"unknown metric {metric!r}")  # pragma: no cover
 
 
-@functools.partial(jax.jit, static_argnums=(5, 6))
-def knn_topk_q(bank, scale, bias, q, n_rows, k: int, metric: str):
-    """INT8 banks: per-row symmetric scale dequantizes inside the kernel."""
-    return _knn_topk_body(bank, scale, bias, q, n_rows, k, metric)
+@functools.partial(jax.jit, static_argnums=(7, 8))
+def knn_flat_topk(bank, scale, bias, norms, qmask, q, n_rows, k: int,
+                  metric: str):
+    """Exact FLAT top-k, blocked: the bank is walked in KNN_BLOCK-row tiles,
+    each tile scored on the MXU and cut to its own k best, and the
+    (Q, blocks * k) candidates merged by one more top_k.  The ids are those
+    of a top_k over the whole (Q, capacity) matrix, ties included: a global
+    winner is among its own block's k best, blocks are concatenated in row
+    order and top_k is stable, so of equal distances the lower rowid wins
+    both times.
 
+    scale: the INT8 dequant column or None; qmask: a hybrid query's (C,)
+    additive 0 / +inf plane or None; norms: (C,) squared row norms.  A
+    capacity that is no multiple of the block ends on a tile that starts
+    early and masks the rows the tile before it covered."""
+    with jax.named_scope("knn_flat_topk"):
+        cap, width = bank.shape
+        block = min(KNN_BLOCK, cap)
+        blocks = -(-cap // block)
+        kb = min(k, block)
+        rowid = jnp.arange(cap, dtype=jnp.int32)
+        gate = jnp.where(rowid < n_rows, bias, jnp.inf)
+        if qmask is not None:
+            gate = gate + qmask
+        q_norm = jnp.sum(q * q, axis=1, dtype=jnp.float32)
+        if metric == "COSINE":
+            q_norm = jnp.sqrt(q_norm)
 
-@functools.partial(jax.jit, static_argnums=(5, 6))
-def knn_topk_masked(bank, bias, qbias, q, n_rows, k: int, metric: str):
-    return _knn_topk_masked_body(bank, None, bias, qbias, q, n_rows, k,
-                                 metric)
+        def score(start, first):
+            sl = lambda a: jax.lax.dynamic_slice_in_dim(a, start, block)  # noqa: E731
+            tile = _bank_f32(sl(bank), None if scale is None else sl(scale))
+            dist = _knn_block_dists(tile, sl(norms), q, q_norm, metric)
+            dist = dist + sl(gate)[None, :]
+            local = start + jnp.arange(block, dtype=jnp.int32)
+            dist = jnp.where((local >= first)[None, :], dist, jnp.inf)
+            top, pos = _block_topk(dist, kb)
+            return top, (pos + start).astype(jnp.int32)
 
+        if blocks == 1:
+            return score(jnp.int32(0), jnp.int32(0))
 
-@functools.partial(jax.jit, static_argnums=(6, 7))
-def knn_topk_masked_q(bank, scale, bias, qbias, q, n_rows, k: int,
-                      metric: str):
-    return _knn_topk_masked_body(bank, scale, bias, qbias, q, n_rows, k,
-                                 metric)
+        def step(_, i):
+            first = i * block
+            return None, score(jnp.minimum(first, cap - block), first)
+
+        _, (tops, ids) = jax.lax.scan(
+            step, None, jnp.arange(blocks, dtype=jnp.int32)
+        )
+        nq = q.shape[0]
+        tops = jnp.moveaxis(tops, 0, 1).reshape(nq, blocks * kb)
+        ids = jnp.moveaxis(ids, 0, 1).reshape(nq, blocks * kb)
+        top, pos = _block_topk(tops, min(k, cap))
+        return top, jnp.take_along_axis(ids, pos, axis=1)
 
 
 # -- IVF (inverted-file) KNN: sub-linear scoring (ISSUE 14) -------------------
@@ -1152,6 +1180,32 @@ def rowbank_grow_plane(plane, grown):
     """Grow ONE auxiliary per-row plane (the INT8 scale column) the same
     HBM-copy way."""
     return grown.at[: plane.shape[0]].set(plane)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def rowbank_write_norms(norms, bank, scale, packed, n_valid):
+    """The squared norms of the rows one packed write just installed, into
+    the (C,) plane kept beside the bank: read back from the bank as stored
+    (dequantized, so a quantized bank's norms are those of the values it
+    scores), whatever the bank's dtype.  A killed row is zeros: norm 0."""
+    idx = packed[:, 0].astype(jnp.int32)
+    mask = _valid_mask(packed.shape[0], n_valid)
+    safe = jnp.where(mask, idx, bank.shape[0])
+    rows = _bank_f32(
+        bank.at[safe].get(mode="fill", fill_value=0),
+        None if scale is None else scale.at[safe].get(mode="fill", fill_value=1),
+    )
+    return norms.at[safe].set(
+        jnp.sum(rows * rows, axis=1, dtype=jnp.float32), mode="drop"
+    )
+
+
+@jax.jit
+def rowbank_norms(bank, scale):
+    """The whole norms plane of a bank that came without one (a record
+    restored or shipped from before the plane existed): one pass, once."""
+    rows = _bank_f32(bank, scale)
+    return jnp.sum(rows * rows, axis=1, dtype=jnp.float32)
 
 
 def _wc_hash_prelude(buf):

@@ -87,6 +87,13 @@ class DeviceStore:
         # ONE seam.  None (the default) keeps today's default-device
         # behavior bit for bit.
         self.placement_hook: Optional[Callable[[str, StateRecord], None]] = None
+        # Hook fired with the NAMES a call installed, deleted, renamed, gave
+        # a TTL or reaped, and with None when the store was flushed — how a
+        # search index learns of a key's fate at the write
+        # (services/search.py; in-place mutations of a record's value report
+        # through Engine.ingest_hook).  Same contract as on_expired: it runs
+        # under the store lock and must not reenter the store.
+        self.on_change: Optional[Callable[[Optional[list]], None]] = None
         # residency manager (ISSUE 20): set by Engine.enable_residency —
         # the armed `_res._tier_plane` guard routes getter touches here so
         # multiple engines in one process never cross-wire.  None = the
@@ -101,7 +108,15 @@ class DeviceStore:
             self.placement_hook(name, rec)
         return rec
 
+    def _changed(self, names: Optional[list]) -> None:
+        if self.on_change is not None:
+            try:
+                self.on_change(names)
+            except Exception:  # noqa: BLE001 — an index must never fail a write
+                pass
+
     def _reaped(self, name: str) -> None:
+        self._changed([name])
         if self.on_expired is not None:
             try:
                 self.on_expired([name])
@@ -163,6 +178,7 @@ class DeviceStore:
             if (cur is None or cur.expired()) and self.absent_guard is not None:
                 self.absent_guard(name)
             self._states[name] = self._placed(name, rec)
+            self._changed([name])
 
     def put_unguarded(self, name: str, rec: StateRecord) -> None:
         """Install bypassing the absent guard — ONLY for migration/replication
@@ -170,18 +186,24 @@ class DeviceStore:
         (the importing side) or overwrite during a drain."""
         with self._lock:
             self._states[name] = self._placed(name, rec)
+            self._changed([name])
 
     def delete(self, name: str) -> bool:
         with self._lock:
             existed = self._states.pop(name, None) is not None
             if not existed and self.absent_guard is not None:
                 self.absent_guard(name)
+            if existed:
+                self._changed([name])
             return existed
 
     def delete_unguarded(self, name: str) -> bool:
         """Delete bypassing the absent guard (the drain's own removal)."""
         with self._lock:
-            return self._states.pop(name, None) is not None
+            existed = self._states.pop(name, None) is not None
+            if existed:
+                self._changed([name])
+            return existed
 
     def exists(self, name: str) -> bool:
         return self.get(name) is not None
@@ -213,6 +235,7 @@ class DeviceStore:
             if new != old:
                 self._states[new] = rec
                 del self._states[old]
+                self._changed([old, new])
             return True
 
     def expire(self, name: str, at: Optional[float]) -> bool:
@@ -221,6 +244,7 @@ class DeviceStore:
             if rec is None:
                 return False
             rec.expire_at = at
+            self._changed([name])
             return True
 
     def ttl(self, name: str) -> Optional[float]:
@@ -258,6 +282,8 @@ class DeviceStore:
             for name in [n_ for n_, r in self._states.items() if r.expired(now)]:
                 del self._states[name]
                 reaped.append(name)
+        if reaped:
+            self._changed(reaped)
         if reaped and self.on_expired is not None:
             try:
                 self.on_expired(reaped)
@@ -268,6 +294,7 @@ class DeviceStore:
     def flushall(self) -> None:
         with self._lock:
             self._states.clear()
+            self._changed(None)
 
     def __len__(self):
         with self._lock:

@@ -239,10 +239,8 @@ def _warm_vector_bank(engine, rec, buckets: Iterable[int], device=None) -> int:
             if scale is not None else None
         )
         dbias = _on(device, jnp.zeros((cap,), jnp.float32))
-        if dscale is not None:
-            out = K.knn_topk_q(dummy, dscale, dbias, q, nv, k, metric)
-        else:
-            out = K.knn_topk(dummy, dbias, q, nv, k, metric)
+        out = K.knn_flat_topk(dummy, dscale, dbias, dbias, None, q, nv, k,
+                              metric)
         if cells is not None and cents is not None:
             dc = _on(device, jnp.zeros(cents.shape, jnp.float32))
             dl = _on(device, jnp.zeros(cells.shape, jnp.int32))

@@ -31,7 +31,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from redisson_tpu.core.coalesce import serial_plan
+from redisson_tpu.core.coalesce import KNN_VERBS, _frame_verb, serial_plan
 from redisson_tpu.utils.crc16 import MAX_SLOT, calc_slot
 
 
@@ -265,9 +265,10 @@ class SlotPlacement:
         segments: List[Tuple[str, Any]] = []
         buckets: Optional[Dict[int, List[int]]] = None
         serial: Optional[List[int]] = None
+        searches: Optional[List[int]] = None
         for i, cmd in enumerate(commands):
             if shed_mask is not None and shed_mask[i]:
-                buckets = serial = None
+                buckets = serial = searches = None
                 continue
             if (
                 isinstance(cmd, list) and cmd
@@ -280,14 +281,22 @@ class SlotPlacement:
                 # the whole frame is serial
                 return serial_plan(len(commands), shed_mask)
             dev = self.device_index_for_command(cmd, owner=owner)
-            if dev is None:
+            if dev is None and _frame_verb(cmd) in KNN_VERBS:
+                # consecutive searches are one bucket of no lane (keyless:
+                # the index's bank takes its own device's lane), so a run of
+                # them rides one stacked KNN as on an unplaced engine
+                if searches is None:
+                    searches, buckets, serial = [], None, None
+                    segments.append(("buckets", {None: searches}))
+                searches.append(i)
+            elif dev is None:
                 if serial is None:
-                    serial, buckets = [], None
+                    serial, buckets, searches = [], None, None
                     segments.append(("serial", serial))
                 serial.append(i)
             else:
                 if buckets is None:
-                    buckets, serial = {}, None
+                    buckets, serial, searches = {}, None, None
                     segments.append(("buckets", buckets))
                 buckets.setdefault(dev, []).append(i)
         return segments

@@ -30,6 +30,7 @@ worker slice, bulk behind a bounded admission gate.  `--no-qos` /
 from __future__ import annotations
 
 import asyncio
+import gc
 import socket
 import threading
 import time
@@ -41,8 +42,8 @@ from typing import Any, Dict, List, Optional, Tuple
 from redisson_tpu.core import ioplane
 from redisson_tpu.core import coalesce as _coalesce
 from redisson_tpu.core.coalesce import (
-    COALESCIBLE_BLOB_VERBS, STACK_PLANES, plan_frame_runs, plan_subwindows,
-    plan_waves, serial_plan, stacked_row_bucket, wave_entry,
+    COALESCIBLE_BLOB_VERBS, KNN_FORM, STACK_PLANES, plan_frame_runs,
+    plan_subwindows, plan_waves, serial_plan, stacked_row_bucket, wave_entry,
 )
 from redisson_tpu.core.engine import Engine
 from redisson_tpu.net import resp
@@ -120,6 +121,23 @@ _STOP_DRAIN_S = 5.0
 # under a burst the next read takes whole.
 _FRAME_CAP = 256 * 1024
 
+# The collector's thresholds for a server PROCESS (``main``; an embedded
+# ServerThread leaves its host's collector alone).  What stays on this heap
+# is records — one tracked object a document — and what comes and goes is a
+# frame's lists, which live as long as the frame is in flight: tens of
+# milliseconds, several young collections at CPython's (700, 10, 10) under
+# four connections' frames, so each frame's objects were promoted to the
+# oldest generation before they died, counted there as growth, and a
+# quarter of the heap's worth of them set off a full collection — a walk
+# over every record, every few seconds, in a window that writes nothing (a
+# million documents: ~0.3 s a walk on the chip's host, the slowest frame
+# of every ann-batch window; PERF.md section 6, PR 32).  The first threshold counts allocations less frees:
+# at 50,000 the frames in flight never reach it, their objects die young by
+# reference count, a young collection comes when that many objects have
+# STAYED, and the heap's growth counts what stays; full collections still
+# come, every 100 middle ones, when the heap has grown by a quarter.
+GC_THRESHOLDS = (50_000, 20, 100)
+
 # fixed -TRYAGAIN texts (ISSUE 19): byte-identical whichever layer detects
 # the fault and whether the chaos plane is armed or not
 _DEVICE_FAULT_TRYAGAIN = "TRYAGAIN device fault during dispatch; retry"
@@ -142,6 +160,15 @@ def _on_worker(trace, to: str, fn, *args):
         return fn(*args)
     finally:
         _obs.clear_current()
+
+
+def _is_slow(cmd) -> bool:
+    """Whether a parsed command may park its worker (_SLOW_COMMANDS)."""
+    return (
+        isinstance(cmd, list) and bool(cmd)
+        and isinstance(cmd[0], (bytes, bytearray))
+        and bytes(cmd[0]).upper() in _SLOW_COMMANDS
+    )
 
 
 class _Laneless:
@@ -372,6 +399,26 @@ class TpuServer:
         )
         self.metrics.gauge(
             "coalesce_cmds_fused_total", lambda: _coalesce.cmds_counted()[1]
+        )
+        # the search plane (always on): documents indexed at a write against
+        # keys any keyspace scan walked for an index (services/search.py),
+        # and the device KNN's query vectors, the bucket slots they were
+        # padded to and live rows x queries (services/vector.py)
+        from redisson_tpu.services import search as _search
+        from redisson_tpu.services import vector as _vector
+
+        self.metrics.gauge(
+            "search_docs_indexed_total", lambda: _search.search_counted()[0]
+        )
+        self.metrics.gauge(
+            "search_scan_keys_total", lambda: _search.search_counted()[1]
+        )
+        self.metrics.gauge("knn_queries_total", lambda: _vector.knn_counted()[0])
+        self.metrics.gauge(
+            "knn_query_slots_total", lambda: _vector.knn_counted()[1]
+        )
+        self.metrics.gauge(
+            "knn_rows_scored_total", lambda: _vector.knn_counted()[2]
         )
         self.metrics.gauge(
             "gather_bytes_owed_total", lambda: ioplane.gather_bytes_counted()[0]
@@ -1270,6 +1317,11 @@ class TpuServer:
         except Exception as e:  # noqa: BLE001 — sandboxed per command
             return self._error_reply(e)
 
+    def _dispatch_serial(self, ctx, cmds, qos_class: Optional[str] = None):
+        """A serial segment's consecutive commands, each alone and in frame
+        order, as one worker job."""
+        return [self._dispatch_one(ctx, cmd, qos_class) for cmd in cmds]
+
     def _fused_add_error_invalidate(self, track, run_names) -> None:
         """A failed fused BF.MADD64 run may have PARTIALLY applied (that is
         why add runs never re-dispatch) — tracked near caches holding
@@ -1339,14 +1391,15 @@ class TpuServer:
         return [self._dispatch_one(ctx, cmd, held=True) for cmd in cmds]
 
     @staticmethod
-    def _stacked_kernel_span(cur, k0: float, verb: str, cmds, key_at: int = 1) -> None:
+    def _stacked_kernel_span(cur, k0: float, verb: str, cmds, key_at: int = 1,
+                             stacked: int = STACK_PLANES) -> None:
         """Coalescer fan-in: ONE kernel span for a stacked dispatch, its
         member commands recorded as child spans sharing the kernel's
         interval (bounded so a 1000-command blob run cannot bloat the
-        trace)."""
+        trace).  `stacked`: what the dispatch was padded to."""
         k1 = time.monotonic()
         cur.add_span(
-            "kernel", k0, k1, verb=verb, members=len(cmds), stacked=STACK_PLANES,
+            "kernel", k0, k1, verb=verb, members=len(cmds), stacked=stacked,
         )
         for c in cmds[:32]:
             cur.add_span(
@@ -1407,6 +1460,37 @@ class TpuServer:
             r if r is not None else self._dispatch_one(ctx, cmd, held=True)
             for cmd, r in zip(cmds, fused)
         ]
+
+    def _dispatch_knn_wave(self, ctx, cmds):
+        """ONE stacked KNN dispatch for a wave of FT.SEARCH / FT.MSEARCH
+        commands on one index and query text (coalesce.plan_waves), a
+        LazyReply a command riding the frame's grouped fetch.  A lone search
+        is dispatched as the command it is.  Read-only: a wave that cannot
+        ride goes per command, which replies whatever a command alone
+        replies; a stacked dispatch that fails with an error reply of its
+        own (-OOM, a device fault's -TRYAGAIN) answers every member with
+        it, as each would have met it alone."""
+        from redisson_tpu.server.verbs.modules import coalesce_knn_run
+
+        if len(cmds) == 1:
+            return [self._dispatch_one(ctx, cmds[0], held=True)]
+        cur = _obs.current_trace() if _obs._tracer is not None else None
+        k0 = time.monotonic() if cur is not None else 0.0
+        fused = None
+        try:
+            fused = coalesce_knn_run(self, ctx, cmds)
+        except Exception as e:  # noqa: BLE001 — per-wave isolation
+            worded = isinstance(e, RespError) or ioplane.is_retryable_device_fault(e)
+            enc = self._error_reply(e, len(cmds) if worded else 0)
+            if worded:
+                return [enc for _ in cmds]
+        if fused is None:
+            return [self._dispatch_one(ctx, cmd, held=True) for cmd in cmds]
+        replies, slots = fused
+        _coalesce.count_knn_fused(len(cmds))
+        if cur is not None:
+            self._stacked_kernel_span(cur, k0, "FT.SEARCH", cmds, stacked=slots)
+        return replies
 
     # -- device-sharded frame dispatch (ISSUE 8) ------------------------------
 
@@ -1634,6 +1718,8 @@ class TpuServer:
                         replies = [self._dispatch_one(ctx, wave[0], held=True)]
                     elif form[0] in COALESCIBLE_BLOB_VERBS:
                         replies = self._dispatch_bloom_run(ctx, wave)
+                    elif form[0] == KNN_FORM:
+                        replies = self._dispatch_knn_wave(ctx, wave)
                     else:
                         replies = self._dispatch_bitset_wave(ctx, wave)
                     out.extend(
@@ -1684,7 +1770,8 @@ class TpuServer:
         forced: the frame's lazies are forced together afterwards
         (_finish_frame), one device->host sync a frame and lane instead of
         one a command.  A 'serial' segment runs its commands in frame
-        order, one hop each, as barriers; a 'buckets' segment fans its
+        order as barriers, consecutive fast ones as one worker job
+        (_dispatch_serial); a 'buckets' segment fans its
         per-lane buckets out on the worker pool CONCURRENTLY (each bucket
         FIFO on its device lane — per-key order is preserved because a key
         maps to exactly one device)."""
@@ -1707,23 +1794,29 @@ class TpuServer:
         pool = self._pool_for(adm)
         for seg_kind, seg in self._plan_frame(ctx, commands, shed_mask):
             if seg_kind == "serial":
-                for i in seg:
-                    cmd = commands[i]
-                    self.stats["commands"] += 1
-                    # OBJCALL (user methods may park) and blocking verbs go
-                    # to the wide slow pool: a parked handler must never
-                    # starve the small fast pool every connection shares
-                    slow = (
-                        isinstance(cmd, list) and cmd
-                        and isinstance(cmd[0], (bytes, bytearray))
-                        and bytes(cmd[0]).upper() in _SLOW_COMMANDS
-                    )
+                self.stats["commands"] += len(seg)
+                # consecutive fast commands are ONE worker job, run in frame
+                # order (a hop a command was ~0.2 ms of one serial host: a
+                # frame of 467 HSETs paid it 467 times).  OBJCALL (user
+                # methods may park) and blocking verbs go one by one to the
+                # wide slow pool: a parked handler must never starve the
+                # small fast pool every connection shares
+                at = 0
+                while at < len(seg):
+                    slow = _is_slow(commands[seg[at]])
+                    end = at + 1
+                    while not slow and end < len(seg) and not _is_slow(commands[seg[end]]):
+                        end += 1
                     if trace is not None:
                         trace.hop_at = time.monotonic()
-                    results[i] = await loop.run_in_executor(
+                    replies = await loop.run_in_executor(
                         self._slow_pool if slow else pool, _on_worker, trace,
-                        "dispatch", self._dispatch_one, ctx, cmd, qos_class,
+                        "dispatch", self._dispatch_serial, ctx,
+                        [commands[i] for i in seg[at:end]], qos_class,
                     )
+                    for i, r in zip(seg[at:end], replies):
+                        results[i] = r
+                    at = end
                 continue
             jobs = []
             if trace is not None:
@@ -1741,6 +1834,12 @@ class TpuServer:
             for out in outs:
                 for i, r in out:
                     results[i] = r
+        # index at the write: the keys this frame's writes left dirty under
+        # a search index's prefix are indexed before the frame is answered
+        # (services/search.py; with no index, one dict lookup a frame)
+        search = self.engine._services.get("search")
+        if search is not None and search.has_dirty():
+            await loop.run_in_executor(pool, search.drain_all)
         return results
 
     async def _finish_frame(self, ctx, results, loop, write_q, readback_slots,
@@ -1798,6 +1897,7 @@ class TpuServer:
             "# Server\r\n"
             f"redis_version:7.2.0-rtpu\r\nrun_id:{self.node_id}\r\n"
             f"tcp_port:{self.port}\r\nuptime_in_seconds:{up}\r\nmode:{self.mode}\r\n"
+            f"gc_thresholds:{','.join(map(str, gc.get_threshold()))}\r\n"
             "# Clients\r\n"
             f"connected_clients:{self.stats['connections']}\r\n"
             "# Stats\r\n"
@@ -2671,6 +2771,7 @@ def main(argv=None):
 
         _os.environ["RTPU_RETRY_PROFILE"] = args.retry_profile
         _retry.set_retry_profile(args.retry_profile)
+    gc.set_threshold(*GC_THRESHOLDS)
     engine = Engine()
     srv = TpuServer(
         engine,

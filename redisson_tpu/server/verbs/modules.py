@@ -419,8 +419,7 @@ def cmd_ft_list(server, ctx, args):
 @_ft_cmd
 def cmd_ft_info(server, ctx, args):
     svc = _ft(server)
-    idx = svc._idx(_s(args[0]))  # KeyError -> Unknown Index via _ft_cmd
-    svc.sync(_s(args[0]))
+    svc.current(_s(args[0]))  # KeyError -> Unknown Index via _ft_cmd
     info = svc.info(_s(args[0]))
     vec_rows = {r["field"]: r for r in info.get("vector_fields", [])}
     flat_schema = []
@@ -585,65 +584,42 @@ def _ft_knn_reply(idx, hits, opts, score_field):
     return rows
 
 
-@register("FT.SEARCH")
-@_ft_cmd
-def cmd_ft_search(server, ctx, args):
-    """FT.SEARCH idx query [NOCONTENT] [SORTBY f [ASC|DESC]] [LIMIT off n]
-    [PARAMS n k v ...] [DIALECT d] [WITHCURSOR [COUNT n]]
-    -> [total, id, [f, v, ...], ...] (RediSearch reply shape).
-
-    The KNN arm ``(filter)=>[KNN k @f $vec]`` scores on the index's
-    device-resident embedding bank as ONE matmul-top-k kernel and replies
-    lazily: the (dist, idx) kernel outputs ride the frame-grouped readback
-    (LazyReply), so M concurrent KNN frames cost <= M+1 blocking syncs.
-    Results carry ``__<field>_score`` (distance, 4 decimals, ascending).
-    WITHCURSOR pages k > COUNT hits through FT.CURSOR READ (nested-row
-    shape, see _ft_knn_reply)."""
-    from redisson_tpu.server.registry import LazyReply
-
+def _ft_knn_plan(server, ctx, args, multi: bool):
+    """What one FT.SEARCH (``multi`` False) or FT.MSEARCH command asks, parsed
+    and checked, with the index brought up to date: the index, the filter
+    condition and its text, the KNN arm (None: a plain query), the options,
+    the (Q, dim) queries, and ``encode`` — per-query hit lists -> the reply.
+    A stacked run (coalesce_knn_run) and the single command both answer from
+    this, so a command's reply is the same bytes either way."""
     svc = _ft(server)
-    idx = svc._idx(_s(args[0]))  # KeyError -> Unknown Index via _ft_cmd
-    _ft_track_read(server, ctx, _s(args[0]))
-    svc.sync(svc.resolve(_s(args[0])))
+    name = _s(args[0])
+    _ft_track_read(server, ctx, name)
+    idx = svc.current(name)  # KeyError -> Unknown Index via _ft_cmd
     qstr, knn = _ft_split_knn(_s(args[1]))
     opts = _ft_parse_search_opts(args, 2)
     cond = _ft_parse_query(qstr, idx.schema)
-
-    if knn is None:
+    plan = {"name": name, "idx": idx, "qstr": qstr, "cond": cond, "knn": knn,
+            "opts": opts, "q": None, "encode": None}
+    if multi:
+        if knn is None:
+            raise RespError("ERR FT.MSEARCH requires a KNN query")
         if opts["withcursor"]:
-            raise RespError("ERR WITHCURSOR requires a KNN query")
-        res = svc.search(_s(args[0]), cond, sort_by=opts["sort_by"],
-                         descending=opts["desc"], offset=opts["off"],
-                         limit=opts["lim"])
-        out = [res.total]
-        for doc_id, fields in res.docs:
-            out.append(doc_id.encode())
-            if not opts["nocontent"]:
-                flat = []
-                for k, v in fields.items():
-                    flat += [str(k).encode(), _ft_field_blob(v)]
-                out.append(flat)
-        return out
-
-    # -- KNN path -------------------------------------------------------------
-    if knn["k"] <= 0:
-        raise RespError("ERR KNN k must be positive")
-    if opts["sort_by"] is not None and opts["sort_by"] != (
-        knn["alias"] or f"__{knn['field']}_score"
-    ):
-        raise RespError("ERR KNN results sort by the vector score")
-    q = _ft_knn_query_vectors(server, idx, knn, opts["params"])
-    try:
-        device, finish = svc.knn(
-            _s(args[0]), knn["field"], q, knn["k"], condition=cond,
-            nprobe=opts["nprobe"],
-        )
-    except ValueError as e:
-        raise RespError(f"ERR {e}")
+            raise RespError("ERR FT.MSEARCH does not support WITHCURSOR")
+    if knn is None:
+        return plan
+    if not multi:
+        if knn["k"] <= 0:
+            raise RespError("ERR KNN k must be positive")
+        if opts["sort_by"] is not None and opts["sort_by"] != (
+            knn["alias"] or f"__{knn['field']}_score"
+        ):
+            raise RespError("ERR KNN results sort by the vector score")
+    plan["q"] = _ft_knn_query_vectors(server, idx, knn, opts["params"],
+                                      expect_multiple=multi)
     score_field = knn["alias"] or f"__{knn['field']}_score"
 
-    def encode(vals):
-        hits = finish(vals)[0]
+    def encode_search(per_query):
+        hits = per_query[0]
         if opts["desc"]:
             hits = hits[::-1]  # SORTBY <score> DESC: farthest-first paging
         rows = _ft_knn_reply(idx, hits, opts, score_field)
@@ -659,9 +635,70 @@ def cmd_ft_search(server, ctx, args):
             out.append(flat)
         return out
 
+    def encode_msearch(per_query):
+        out = [len(per_query)]
+        for hits in per_query:
+            flat = []
+            for doc_id, dist in hits:
+                flat += [doc_id.encode(), _ft_score_bytes(dist)]
+            out.append(flat)
+        return out
+
+    plan["encode"] = encode_msearch if multi else encode_search
+    return plan
+
+
+def _ft_knn_answer(server, plan):
+    """One command's KNN, dispatched alone: the lazy (or, disarmed or over
+    an empty index, the finished) reply."""
+    from redisson_tpu.server.registry import LazyReply
+
+    try:
+        device, finish = _ft(server).knn(
+            plan["name"], plan["knn"]["field"], plan["q"], plan["knn"]["k"],
+            condition=plan["cond"], nprobe=plan["opts"]["nprobe"],
+        )
+    except ValueError as e:
+        raise RespError(f"ERR {e}")
+    encode = plan["encode"]
     if device is None:  # disarmed (RTPU_NO_VECTOR) or empty index/filter
-        return encode(None)
-    return LazyReply(device=device, finish=encode)
+        return encode(finish(None))
+    return LazyReply(device=device, finish=lambda vals: encode(finish(vals)))
+
+
+@register("FT.SEARCH")
+@_ft_cmd
+def cmd_ft_search(server, ctx, args):
+    """FT.SEARCH idx query [NOCONTENT] [SORTBY f [ASC|DESC]] [LIMIT off n]
+    [PARAMS n k v ...] [DIALECT d] [WITHCURSOR [COUNT n]]
+    -> [total, id, [f, v, ...], ...] (RediSearch reply shape).
+
+    The KNN arm ``(filter)=>[KNN k @f $vec]`` scores on the index's
+    device-resident embedding bank as ONE blocked matmul-top-k kernel and
+    replies lazily: the (dist, idx) kernel outputs ride the frame-grouped
+    readback (LazyReply), so M concurrent KNN frames cost <= M+1 blocking
+    syncs; a run of such commands in one pipelined frame is ONE stacked
+    dispatch (coalesce_knn_run).  Results carry ``__<field>_score``
+    (distance, 4 decimals, ascending).  WITHCURSOR pages k > COUNT hits
+    through FT.CURSOR READ (nested-row shape, see _ft_knn_reply)."""
+    plan = _ft_knn_plan(server, ctx, args, multi=False)
+    if plan["knn"] is not None:
+        return _ft_knn_answer(server, plan)
+    opts = plan["opts"]
+    if opts["withcursor"]:
+        raise RespError("ERR WITHCURSOR requires a KNN query")
+    res = _ft(server).search(_s(args[0]), plan["cond"], sort_by=opts["sort_by"],
+                             descending=opts["desc"], offset=opts["off"],
+                             limit=opts["lim"])
+    out = [res.total]
+    for doc_id, fields in res.docs:
+        out.append(doc_id.encode())
+        if not opts["nocontent"]:
+            flat = []
+            for k, v in fields.items():
+                flat += [str(k).encode(), _ft_field_blob(v)]
+            out.append(flat)
+    return out
 
 
 @register("FT.MSEARCH")
@@ -673,42 +710,83 @@ def cmd_ft_msearch(server, ctx, args):
     coalesced run of same-index KNN frames in a single command).  Reply:
     ``[Q, [id, score, id, score, ...] per query]`` — ids+scores only, the
     throughput projection."""
+    return _ft_knn_answer(server, _ft_knn_plan(server, ctx, args, multi=True))
+
+
+def coalesce_knn_run(server, ctx, cmds):
+    """ONE stacked KNN dispatch for a wave of FT.SEARCH / FT.MSEARCH commands
+    of one pipelined frame that name the same index and the same query text
+    (core/coalesce.py wave_entry: same filter, field, k), so the bank is
+    read once for all of them.  Returns (one LazyReply a command, the query
+    slots the dispatch was padded to), each reply its own queries' hits
+    encoded as the command alone would (NOCONTENT, LIMIT, SORTBY,
+    WITHCURSOR as parsed per command), or None where the wave cannot
+    ride — a command that does not parse, commands that differ in NPROBE, the
+    device plane disarmed, an empty index: the per-command path then replies,
+    errors included.  Prechecks as coalesce_bloom_run's."""
+    import numpy as np
+
     from redisson_tpu.server.registry import LazyReply
+    from redisson_tpu.services.vector import KNN_QUERY_BUCKETS, knn_query_bucket
+    from redisson_tpu.utils.metrics import run_hooks_end, run_hooks_start
 
-    svc = _ft(server)
-    idx = svc._idx(_s(args[0]))
-    _ft_track_read(server, ctx, _s(args[0]))
-    svc.sync(svc.resolve(_s(args[0])))
-    qstr, knn = _ft_split_knn(_s(args[1]))
-    if knn is None:
-        raise RespError("ERR FT.MSEARCH requires a KNN query")
-    opts = _ft_parse_search_opts(args, 2)
-    if opts["withcursor"]:
-        raise RespError("ERR FT.MSEARCH does not support WITHCURSOR")
-    cond = _ft_parse_query(qstr, idx.schema)
-    q = _ft_knn_query_vectors(server, idx, knn, opts["params"],
-                              expect_multiple=True)
+    if ctx.multi_queue is not None or not ctx.authenticated or ctx.asking:
+        return None
+    plans = []
     try:
-        device, finish = svc.knn(
-            _s(args[0]), knn["field"], q, knn["k"], condition=cond,
-            nprobe=opts["nprobe"],
+        for cmd in cmds:
+            verb = bytes(cmd[0]).upper()
+            if server.cluster_view or server.role == "replica":
+                server.check_routing(verb.decode(), cmd[1:], asking=False)
+            plans.append(_ft_knn_plan(server, ctx, cmd[1:],
+                                      multi=verb == b"FT.MSEARCH"))
+    except Exception:  # noqa: BLE001 — nothing was dispatched: per command
+        return None
+    first = plans[0]
+    if any(
+        p["knn"] is None or p["idx"] is not first["idx"]
+        or p["qstr"] != first["qstr"]
+        or (p["knn"]["field"], p["knn"]["k"]) != (
+            first["knn"]["field"], first["knn"]["k"])
+        or p["opts"]["nprobe"] != first["opts"]["nprobe"] for p in plans
+    ):
+        return None
+    sizes = [p["q"].shape[0] for p in plans]
+    if sum(sizes) > KNN_QUERY_BUCKETS[-1]:
+        return None  # more vectors than a warmed bucket holds
+    hooks = getattr(server, "hooks", None) or ()
+    tokens = run_hooks_start(hooks, "FT.SEARCH.COALESCED", (len(cmds),))
+    try:
+        device, finish = _ft(server).knn(
+            first["name"], first["knn"]["field"],
+            np.concatenate([p["q"] for p in plans]), first["knn"]["k"],
+            condition=first["cond"], nprobe=first["opts"]["nprobe"], warm=True,
         )
-    except ValueError as e:
-        raise RespError(f"ERR {e}")
-
-    def encode(vals):
-        per_query = finish(vals)
-        out = [len(per_query)]
-        for hits in per_query:
-            flat = []
-            for doc_id, dist in hits:
-                flat += [doc_id.encode(), _ft_score_bytes(dist)]
-            out.append(flat)
-        return out
-
+    except BaseException as e:
+        run_hooks_end(tokens, "FT.SEARCH.COALESCED", e)
+        if isinstance(e, ValueError):
+            return None  # the per-command path words the error
+        raise
+    run_hooks_end(tokens, "FT.SEARCH.COALESCED", None)
     if device is None:
-        return encode(None)
-    return LazyReply(device=device, finish=encode)
+        return None
+    done: list = []
+
+    def per_query(vals):  # rows -> doc ids -> scores once a run
+        if not done:
+            done.append(finish(vals))
+        return done[0]
+
+    out, off = [], 0
+    for p, n in zip(plans, sizes):
+        out.append(LazyReply(
+            device=device,
+            finish=lambda vals, p=p, a=off, b=off + n: p["encode"](
+                per_query(vals)[a:b]
+            ),
+        ))
+        off += n
+    return out, knn_query_bucket(off)
 
 
 @register("FT.AGGREGATE")
@@ -717,8 +795,7 @@ def cmd_ft_aggregate(server, ctx, args):
     """FT.AGGREGATE idx query [GROUPBY 1 @f REDUCE op n [@f] AS name ...]
     [SORTBY n @f [ASC|DESC]] [LIMIT off n] [WITHCURSOR [COUNT n]]."""
     svc = _ft(server)
-    idx = svc._idx(_s(args[0]))  # KeyError -> Unknown Index via _ft_cmd
-    svc.sync(svc.resolve(_s(args[0])))
+    idx = svc.current(_s(args[0]))  # KeyError -> Unknown Index via _ft_cmd
     cond = _ft_parse_query(_s(args[1]), idx.schema)
     group_by, reducers = None, {}
     sort_by, desc = None, False

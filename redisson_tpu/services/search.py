@@ -15,19 +15,79 @@ hash-table work the device has no advantage on; mixed queries intersect the
 host candidate set with the device numeric mask.
 
 Auto-indexing: the reference indexes every hash whose key matches a prefix.
-Here `sync()` scans matching maps through the engine store, and maps report
-into the index on write via the `document(...)`/`remove_document` hooks the
-client facade calls; `sync()` is also cheap enough to call before queries
-for read-your-writes freshness (it diffs record versions).
+A hash-mode index (the FT.* wire verbs) learns of a write AT THE WRITE: every
+mutation of a record and every delete, rename, expiry or flush of a key tells
+the service the key's name (Engine.ingest_hook, DeviceStore.on_change), the
+key joins the dirty set of each index whose prefix covers it, and the set is
+drained — O(dirty), never the keyspace — at the end of the frame that wrote
+and before any FT.* answers.  One scan of the keyspace is left: the one
+FT.CREATE / FT.ALTER makes over keys that were there before the index.  An
+entry-mode index (the embedded facade's) keeps `sync()`, the version-diffed
+scan the caller asks for.
 """
 from __future__ import annotations
 
 import re
 import threading
+from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
+
+_COUNT_LOCK = threading.Lock()
+_docs_indexed = 0
+_scan_keys = 0
+
+
+def _count(docs: int = 0, keys: int = 0) -> None:
+    global _docs_indexed, _scan_keys
+    with _COUNT_LOCK:
+        _docs_indexed += docs
+        _scan_keys += keys
+
+
+def search_counted() -> tuple:
+    """(documents indexed at a write, keys walked by a keyspace scan) of this
+    process's search indexes.  METRICS exports both (search_docs_indexed_total,
+    search_scan_keys_total), always on."""
+    return _docs_indexed, _scan_keys
+
+
+class _RowDocs:
+    """row -> doc id, as an object array that grows by doubling: a KNN
+    reply's (Q, k) rows become doc ids by ONE lookup (take), where a list
+    was a Python loop a hit."""
+
+    def __init__(self):
+        self._ids = np.empty(256, object)
+        self._n = 0
+
+    def __len__(self) -> int:
+        return self._n
+
+    def __getitem__(self, row: int):
+        return self._ids[row] if 0 <= row < self._n else None
+
+    def __setitem__(self, row: int, doc_id) -> None:
+        self._ids[row] = doc_id
+
+    def append(self, doc_id) -> None:
+        if self._n == len(self._ids):
+            grown = np.empty(2 * self._n, object)
+            grown[: self._n] = self._ids
+            self._ids = grown
+        self._ids[self._n] = doc_id
+        self._n += 1
+
+    def take(self, rows: np.ndarray) -> np.ndarray:
+        """Doc ids of `rows` (any shape); None where a row is out of range
+        or its document is gone."""
+        ok = (rows >= 0) & (rows < self._n)
+        out = self._ids[np.where(ok, rows, 0)]
+        out[~ok] = None
+        return out
+
 
 # -- schema ------------------------------------------------------------------
 
@@ -238,7 +298,7 @@ class SearchIndex:
         self.doc_mode = doc_mode
         self.docs: Dict[str, Dict[str, Any]] = {}          # doc_id -> fields
         self._rowid: Dict[str, int] = {}                   # doc_id -> numeric row
-        self._rowdoc: List[Optional[str]] = []             # row -> doc_id
+        self._rowdoc = _RowDocs()                          # row -> doc_id
         self._text: Dict[str, Dict[str, set]] = {
             f: {} for f, t in schema.items() if t == FieldType.TEXT
         }                                                   # field -> word -> ids
@@ -248,7 +308,17 @@ class SearchIndex:
         self._numeric = _NumericPlane(
             [f for f, t in schema.items() if t == FieldType.NUMERIC]
         )
-        self._synced_versions: Dict[str, int] = {}          # map name -> version
+        self._synced_versions: Dict[str, Any] = {}          # map name -> stamp
+        # index-at-write (hash mode): keys written since the last drain (the
+        # hooks append and the one drainer pops, lock-free: a deque's ends
+        # are atomic, so no write is lost between the two), the flush count
+        # the documents are of, and the keys that carry a TTL
+        self._dirty: deque = deque()
+        self._prefix_tuple = tuple(self.prefixes)
+        self._flushes = 0
+        self._expiring: Dict[str, float] = {}
+        self._next_expiry = float("inf")
+        self._drain_lock = threading.Lock()
         # synonym groups (FT.SYNUPDATE/SYNDUMP): group id -> lowercase terms,
         # and the reverse map consulted at query time
         self.synonyms: Dict[str, set] = {}
@@ -430,6 +500,10 @@ class SearchService:
         self._cursors: Dict[int, Tuple[List[Any], float]] = {}
         self._next_cursor = 1
         self._lock = threading.Lock()
+        # the hash-mode indexes the write hooks report to; a new tuple at
+        # every FT.CREATE / DROPINDEX / ALTER, so the hooks read it unlocked
+        self._hooked: Tuple[SearchIndex, ...] = ()
+        self._flushes = 0  # FLUSHALLs the store has told of
 
     CURSOR_TTL = 300.0
     CURSOR_MAX = 128
@@ -481,7 +555,8 @@ class SearchService:
                 engine=self._engine, vector_specs=specs,
             )
             self._indexes[name] = idx
-        self.sync(name)
+            self._rehook_locked()
+        self._scan(idx)  # the hooks are armed: a write racing the scan is dirty
         return idx
 
     def create(
@@ -500,6 +575,7 @@ class SearchService:
     def drop_index(self, name: str) -> bool:
         with self._lock:
             idx = self._indexes.pop(name, None)
+            self._rehook_locked()
         if idx is not None and idx.vectors:
             # bank records leave the store with the index — device memory is
             # released through the ordinary teardown path, so the census's
@@ -545,7 +621,8 @@ class SearchService:
                 fresh.add(doc_id, fields)
         with self._lock:
             self._indexes[old.name] = fresh
-        self.sync(old.name)
+            self._rehook_locked()
+        self._scan(fresh)
 
     # -- FT.ALIAS* -----------------------------------------------------------
 
@@ -751,10 +828,11 @@ class SearchService:
 
     def knn(self, index: str, field: str, queries, k: int,
             condition: Optional[Condition] = None,
-            nprobe: Optional[int] = None):
+            nprobe: Optional[int] = None, warm: bool = False):
         """One stacked KNN over the index's embedding bank (FLAT exact, or
         routed IVF once the field's coarse quantizer trained; ``nprobe``
-        overrides the IVF field's probe width for this query).
+        overrides the IVF field's probe width for this query; ``warm``: the
+        queries are a frame's stacked run — EmbeddingBank.knn_async).
 
         Returns ``(device, finish)``: with the device plane armed, `device`
         is the (dist, idx) kernel-output pair — the caller wraps it in a
@@ -790,7 +868,8 @@ class SearchService:
                 return None, lambda _vals: [[] for _ in range(nq)]
         armed = V.vector_enabled()
         out = (
-            bank.knn_async(q, k, allowed_rows=allowed, nprobe=nprobe)
+            bank.knn_async(q, k, allowed_rows=allowed, nprobe=nprobe,
+                           warm=warm)
             if armed else None
         )
         if armed and out is None:
@@ -802,41 +881,28 @@ class SearchService:
                                      nprobe=nprobe)
                 if host is None:
                     return [[] for _ in range(nq)]
-                dist_h, idx_h, _nq, k_eff = host
+                dist_h, idx_h = host[0], host[1]
             else:
                 # the bank decodes its own device outputs to GLOBAL rowids:
                 # (dist, idx) for plain banks, (dist, shard, local) for the
                 # mesh-sharded facade (gmap decode off the readback path)
                 dist_h, idx_h = bank.resolve_hits(vals)
-                k_eff = dist_h.shape[1]
-            picked = []   # (qi, rowid, doc) winners, reply order
-            for qi in range(nq):
-                for j in range(k_eff):
-                    if not np.isfinite(dist_h[qi, j]):
-                        continue  # k exceeded the live rows: padding entry
-                    r = int(idx_h[qi, j])
-                    doc = (
-                        idx._rowdoc[r]
-                        if 0 <= r < len(idx._rowdoc) else None
-                    )
-                    if doc is None:
-                        continue  # doc deleted between dispatch and fetch
-                    picked.append((qi, r, doc))
+            dist_h, idx_h = dist_h[:nq], idx_h[:nq]  # the bucket's padding
+            # rows -> doc ids in one lookup; a non-finite distance is a
+            # padding entry (k exceeded the live rows), a None a document
+            # deleted between dispatch and fetch
+            docs = idx._rowdoc.take(idx_h)
+            ok = np.isfinite(dist_h) & (docs != None)  # noqa: E711 — elementwise
+            qis, _j = np.nonzero(ok)  # row-major: reply order
+            if not len(qis):
+                return [[] for _ in range(nq)]
             # the kernel/NumPy paths choose WHICH rows win; the scores on
             # the wire come from ONE canonical per-pair routine so armed
             # and disarmed replies are byte-identical (vector.pair_scores)
-            res = [[] for _ in range(nq)]
-            if picked:
-                scores = bank.pair_scores(
-                    q,
-                    np.fromiter((p[0] for p in picked), np.int64,
-                                count=len(picked)),
-                    np.fromiter((p[1] for p in picked), np.int64,
-                                count=len(picked)),
-                )
-                for (qi, _r, doc), d in zip(picked, scores):
-                    res[qi].append((doc, float(d)))
-            return res
+            scores = bank.pair_scores(q, qis, idx_h[ok])
+            flat = list(zip(docs[ok].tolist(), scores.tolist()))
+            ends = np.cumsum(ok.sum(axis=1)).tolist()
+            return [flat[a:b] for a, b in zip([0] + ends[:-1], ends)]
 
         if not armed:
             return None, finish
@@ -853,63 +919,184 @@ class SearchService:
     def remove_document(self, index: str, doc_id: str) -> bool:
         return self._idx(index).remove(doc_id)
 
-    def sync(self, name: str) -> int:
-        """Pull documents from every map whose name matches a prefix — the
-        reference's hash auto-indexing, done as a version-diffed scan (maps
-        whose record version is unchanged are skipped).  The index's
-        doc_mode decides the document model (see SearchIndex.__init__)."""
+    # -- index at the write ----------------------------------------------------
+
+    def _rehook_locked(self) -> None:
+        """Point the engine's and the store's write hooks at the hash-mode
+        indexes there are; with none, take the hooks off — a write then
+        costs one attribute load and an is-None."""
+        self._hooked = tuple(
+            i for i in self._indexes.values() if i.doc_mode == "hash"
+        )
+        armed = bool(self._hooked)
+        self._engine.ingest_hook = self._note_key if armed else None
+        self._engine.store.on_change = self._note_keys if armed else None
+
+    def _note_key(self, name: str) -> None:
+        """A record of this name changed (Engine.ingest_hook).  Lock-free and
+        store-free: it runs under the writer's record lock."""
+        for idx in self._hooked:
+            if name.startswith(idx._prefix_tuple):
+                idx._dirty.append(name)
+
+    def _note_keys(self, names) -> None:
+        """Keys were installed, deleted, renamed, given a TTL or reaped
+        (DeviceStore.on_change); None: the store was flushed."""
+        if names is None:
+            self._flushes += 1
+            return
+        for name in names:
+            self._note_key(name)
+
+    def has_dirty(self) -> bool:
+        return any(i._dirty for i in self._hooked)
+
+    def drain_all(self) -> int:
+        """Bring every hash-mode index up to the writes applied so far (the
+        server calls it at the end of a frame that left keys dirty)."""
+        return sum(self._drain(i) for i in self._hooked if i._dirty)
+
+    def current(self, name: str) -> SearchIndex:
+        """The index an FT.* command answers from, up to date with every
+        write applied before the call: a hash-mode index drains its dirty
+        keys (and stands empty again after a FLUSHALL); an entry-mode one is
+        as its last sync() left it."""
         idx = self._idx(name)
+        if idx.doc_mode == "hash":
+            self._drain(idx)
+            idx = self._idx(name)  # a flush replaced it
+        return idx
+
+    def _after_flush(self, idx: SearchIndex) -> SearchIndex:
+        """FLUSHALL took every document and the bank records with them: the
+        definition stays, over an empty index with banks of its own, which
+        is returned.  The dirty set goes along: it holds the keys written
+        since the flush."""
+        fresh = SearchIndex(
+            idx.name, idx.schema, idx.prefixes, idx.doc_mode,
+            engine=self._engine, vector_specs=idx.vector_specs,
+        )
+        fresh.synonyms, fresh._syn_of = idx.synonyms, idx._syn_of
+        fresh._dirty = idx._dirty
+        fresh._flushes = idx._flushes = self._flushes
+        with self._lock:
+            if self._indexes.get(idx.name) is not idx:
+                return self._indexes.get(idx.name, idx)  # replaced meanwhile
+            self._indexes[idx.name] = fresh
+            self._rehook_locked()
+        return fresh
+
+    def _read_hash(self, idx: SearchIndex, key: str, rec) -> Dict[str, Any]:
+        # wire hashes hold RAW bytes (typed HSET surface): a plain map's
+        # host dict holds them as BytesCodec would hand them back; any other
+        # kind is read through its handle.  Decoded to str below
+        if rec.kind == "map":
+            entries = list(rec.host.items())
+        else:
+            from redisson_tpu.client.codec import BytesCodec
+            from redisson_tpu.client.objects.map import Map
+
+            entries = Map(self._engine, key, codec=BytesCodec()).read_all_entry_set()
+        fields = {}
+        for k, v in entries:
+            ks = k.decode() if isinstance(k, (bytes, bytearray)) else str(k)
+            if idx.schema.get(ks) == FieldType.VECTOR:
+                # raw float32 blob (the RediSearch HSET wire shape):
+                # utf-8 decoding arbitrary vector bytes would throw
+                fields[ks] = bytes(v) if isinstance(v, (bytes, bytearray)) else v
+                continue
+            vs = v.decode() if isinstance(v, (bytes, bytearray)) else v
+            if idx.schema.get(ks) == FieldType.NUMERIC:
+                try:
+                    vs = float(vs)
+                except (TypeError, ValueError):
+                    pass
+            fields[ks] = vs
+        return fields
+
+    def _ingest_hash(self, idx: SearchIndex, key: str) -> int:
+        """One key of a hash-mode index against the store: 1 if the index
+        changed.  A key that is gone, expired or no hash any more leaves the
+        index; one with a TTL is looked at again when it has passed."""
+        rec = self._engine.store.get_unguarded(key)
+        if rec is None or rec.kind not in ("map", "map_cache"):
+            idx._expiring.pop(key, None)
+            idx._synced_versions.pop(key, None)
+            return int(idx.remove(key))
+        if rec.expire_at is not None:
+            idx._expiring[key] = rec.expire_at
+            idx._next_expiry = min(idx._next_expiry, rec.expire_at)
+        else:
+            idx._expiring.pop(key, None)
+        stamp = (rec.nonce, rec.version)  # versions restart at a recreate
+        if idx._synced_versions.get(key) == stamp:
+            return 0
+        idx.add(key, self._read_hash(idx, key, rec))
+        idx._synced_versions[key] = stamp
+        return 1
+
+    def _drain(self, idx: SearchIndex) -> int:
+        """Apply the writes the hooks reported since the last drain: O(dirty
+        keys), whatever the keyspace holds."""
+        import time as _time
+
+        with idx._drain_lock:
+            if idx._flushes == self._flushes:
+                now = _time.time()
+                if idx._next_expiry <= now:
+                    idx._dirty.extend(
+                        k for k, at in idx._expiring.items() if at <= now
+                    )
+                    idx._next_expiry = min(
+                        (at for at in idx._expiring.values() if at > now),
+                        default=float("inf"),
+                    )
+                dirty = set()
+                while idx._dirty:
+                    dirty.add(idx._dirty.popleft())
+                n = sum(self._ingest_hash(idx, key) for key in dirty)
+                _count(docs=n)
+                return n
+            fresh = self._after_flush(idx)
+        return self._drain(fresh)
+
+    def sync(self, name: str) -> int:
+        """Bring an index up to date and say how many documents changed.  A
+        hash-mode index drains what its write hooks reported; an entry-mode
+        one pulls from every map whose name matches a prefix, as a
+        version-diffed scan (maps whose version is unchanged are skipped)."""
+        idx = self._idx(name)
+        if idx.doc_mode == "hash":
+            return self._drain(idx)
+        return self._scan(idx)
+
+    def _scan(self, idx: SearchIndex) -> int:
+        """Walk the keyspace for the keys under the index's prefixes: what
+        FT.CREATE / FT.ALTER do once over the keys that were there before
+        the index, and what an entry-mode sync() is."""
         from redisson_tpu.client.objects.map import Map
 
         n = 0
-        seen = set()
-        for key in self._engine.store.keys():
-            if not any(key.startswith(p) for p in idx.prefixes):
-                continue
-            rec = self._engine.store.get(key)
-            if rec is None or rec.kind not in ("map", "map_cache"):
-                continue
-            seen.add(key)
-            if idx._synced_versions.get(key) == rec.version:
-                continue
-            if idx.doc_mode == "hash":
-                # wire hashes hold RAW bytes (typed HSET surface): read
-                # through BytesCodec, decode to str below
-                from redisson_tpu.client.codec import BytesCodec
-
-                m = Map(self._engine, key, codec=BytesCodec())
-                fields = {}
-                for k, v in m.read_all_entry_set():
-                    ks = k.decode() if isinstance(k, (bytes, bytearray)) else str(k)
-                    if idx.schema.get(ks) == FieldType.VECTOR:
-                        # raw float32 blob (the RediSearch HSET wire shape):
-                        # utf-8 decoding arbitrary vector bytes would throw
-                        fields[ks] = bytes(v) if isinstance(
-                            v, (bytes, bytearray)
-                        ) else v
-                        continue
-                    vs = v.decode() if isinstance(v, (bytes, bytearray)) else v
-                    if idx.schema.get(ks) == FieldType.NUMERIC:
-                        try:
-                            vs = float(vs)
-                        except (TypeError, ValueError):
-                            pass
-                    fields[ks] = vs
-                idx.add(key, fields)
-                n += 1
-            else:
+        keys = self._engine.store.keys()
+        _count(keys=len(keys))
+        with idx._drain_lock:
+            idx._flushes = self._flushes
+            for key in keys:
+                if not key.startswith(idx._prefix_tuple):
+                    continue
+                if idx.doc_mode == "hash":
+                    n += self._ingest_hash(idx, key)
+                    continue
+                rec = self._engine.store.get(key)
+                if rec is None or rec.kind not in ("map", "map_cache"):
+                    continue
+                if idx._synced_versions.get(key) == rec.version:
+                    continue
                 for k, v in Map(self._engine, key).read_all_entry_set():
                     if isinstance(v, dict):
                         idx.add(f"{key}:{k}", v)
                         n += 1
-            idx._synced_versions[key] = rec.version
-        if idx.doc_mode == "hash":
-            # deleted hashes leave the store silently; prune their docs or
-            # searches keep serving stale fields forever
-            for gone in [d for d in list(idx.docs) if d not in seen]:
-                idx.remove(gone)
-                idx._synced_versions.pop(gone, None)
-                n += 1
+                idx._synced_versions[key] = rec.version
         return n
 
     # -- FT.SEARCH -----------------------------------------------------------

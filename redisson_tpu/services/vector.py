@@ -331,12 +331,44 @@ def pick_shard_record_names(engine, index: str, field: str,
     return names
 
 
-def _query_bucket(n: int) -> int:
-    """Small pow2 bucket for stacked query counts (compile-cache bound)."""
-    b = 1
+# The query counts a stacked KNN dispatch pads to.  A bounded set, compiled
+# together the first time a frame's run reaches a bank (_warm_query_buckets),
+# so what a frame holds never meets a cold program; a run longer than the
+# largest is cut into several dispatches (core/coalesce.py KNN_STACK_MAX).
+KNN_QUERY_BUCKETS = (1, 4, 16, 64)
+
+
+def knn_query_bucket(n: int) -> int:
+    """The query bucket `n` stacked queries pad to: the smallest of
+    KNN_QUERY_BUCKETS that holds them, a power of two above the largest (one
+    FT.MSEARCH blob of more vectors than a frame's run may stack)."""
+    b = next((b for b in KNN_QUERY_BUCKETS if n <= b), KNN_QUERY_BUCKETS[-1])
     while b < n:
         b <<= 1
     return b
+
+
+_KNN_LOCK = threading.Lock()
+_knn_queries = 0
+_knn_slots = 0
+_knn_rows_scored = 0
+
+
+def _count_knn(queries: int, slots: int, rows: int) -> None:
+    global _knn_queries, _knn_slots, _knn_rows_scored
+    with _KNN_LOCK:  # server worker threads dispatch side by side
+        _knn_queries += queries
+        _knn_slots += slots
+        _knn_rows_scored += queries * rows
+
+
+def knn_counted() -> tuple:
+    """(queries, query slots, rows scored) of this process's device KNN
+    dispatches: query vectors asked, the bucket slots their dispatches
+    padded them to, and live rows x queries.  METRICS exports the three
+    (knn_queries_total, knn_query_slots_total, knn_rows_scored_total),
+    always on, as kernels.count_rows does for the sketch banks."""
+    return _knn_queries, _knn_slots, _knn_rows_scored
 
 
 # -- bank compression (FP16 / INT8 with symmetric per-row scale) --------------
@@ -377,6 +409,8 @@ def quantize_row(row: np.ndarray, dtype: str, pwidth: int):
             q = np.clip(np.rint(row / scale), -127, 127)
         stored[:dim] = np.nan_to_num(q).astype(np.int8)
         return stored, scale, stored[:dim].astype(np.float32) * scale
+    if pwidth == dim:  # float32 packs whole: nothing to pad, nothing to widen
+        return row, np.float32(1.0), row
     stored = np.zeros(pwidth, np.float32)
     stored[:dim] = row
     return stored, np.float32(1.0), stored[:dim].copy()
@@ -432,6 +466,7 @@ class DeviceRowBank:
         self.pwidth = phys_width(self.width, self.dtype)
         self.block = max(1, int(block))
         self.rows = 0            # logical row count (max rowid + 1)
+        self.dead = 0            # rows of those whose bias is +inf (killed)
         self._cap = 0            # device capacity (rows)
         # rowid -> (bias, stored row at pwidth | None, scale)
         self._pending: Dict[int, Tuple[float, Optional[np.ndarray],
@@ -466,6 +501,17 @@ class DeviceRowBank:
 
     def _set_planes(self, bank, bias, scale) -> None:
         self._bank, self._bias, self._scale = bank, bias, scale
+
+    # A bank that scores distances (EmbeddingBank) keeps its rows' squared
+    # norms as one more (capacity,) plane, written with the rows and grown
+    # with the bank, so no query sums the bank again.
+    NORMS = False
+
+    def _get_norms(self):
+        return getattr(self, "_norms", None)
+
+    def _set_norms(self, norms) -> None:
+        self._norms = norms
 
     def _target_device(self):
         return None
@@ -513,6 +559,8 @@ class DeviceRowBank:
                 np.asarray(row, np.float32), self.dtype, self.pwidth
             )
         with self._lock:
+            was_dead = rowid < self.rows and np.isinf(self._host_bias[rowid])
+            self.dead += int(row is None) - int(was_dead)
             self._mirror(rowid, float(bias), deq)
             self.rows = max(self.rows, rowid + 1)
             self._pending[rowid] = (float(bias), stored, scale)
@@ -595,13 +643,22 @@ class DeviceRowBank:
                     jnp.ones((new_cap,), jnp.float32)
                     if self.dtype == "INT8" else None
                 )
+                gnorms = (
+                    jnp.zeros((new_cap,), jnp.float32) if self.NORMS else None
+                )
             if device is not None:
                 grown = jax.device_put(grown, device)
                 gbias = jax.device_put(gbias, device)
                 if gscale is not None:
                     gscale = jax.device_put(gscale, device)
+                if gnorms is not None:
+                    gnorms = jax.device_put(gnorms, device)
             bank, bias, scale = self._get_planes()
             if bank is not None and self._cap > 0:
+                if gnorms is not None:
+                    gnorms = K.rowbank_grow_plane(
+                        self._norms_locked(bank, scale), gnorms
+                    )
                 grown, gbias = K.rowbank_grow(bank, bias, grown, gbias)
                 if gscale is not None and scale is not None:
                     gscale = K.rowbank_grow_plane(scale, gscale)
@@ -611,7 +668,20 @@ class DeviceRowBank:
                 self._oom(dev_id, e)
             raise
         self._set_planes(grown, gbias, gscale)
+        if gnorms is not None:
+            self._set_norms(gnorms)
         self._cap = new_cap
+
+    def _norms_locked(self, bank, scale):
+        """The norms plane of `bank`; made in one pass where the record came
+        without one (restored or shipped from before the plane existed)."""
+        from redisson_tpu.core import kernels as K
+
+        norms = self._get_norms()
+        if norms is None or norms.shape[0] != bank.shape[0]:
+            norms = K.rowbank_norms(bank, scale)
+            self._set_norms(norms)
+        return norms
 
     def _oom(self, dev_id: int, cause: BaseException) -> None:
         """HBM exhausted growing this bank: count the fault on the lane's
@@ -695,6 +765,10 @@ class DeviceRowBank:
                         bank, bias, staged, nv
                     )
                 self._set_planes(bank, bias, scale)
+                if self.NORMS:
+                    self._set_norms(K.rowbank_write_norms(
+                        self._norms_locked(bank, scale), bank, scale, staged, nv
+                    ))
                 self.h2d_flushes += 1
                 self.dispatches += 1
             return n
@@ -814,6 +888,12 @@ class RecordRowBank(DeviceRowBank):
         rec.meta["rows"] = self.rows
         rec.version += 1
 
+    def _get_norms(self):
+        return self._rec().arrays.get("norms")
+
+    def _set_norms(self, norms) -> None:
+        self._rec().arrays["norms"] = norms
+
     def _target_device(self):
         from redisson_tpu.core.ioplane import device_of
 
@@ -853,6 +933,7 @@ class RecordRowBank(DeviceRowBank):
             self.rows = rows
             self._cap = 0 if bank is None else int(bank.shape[0])
             if bank is None or rows <= 0:
+                self.dead = 0
                 self._host = np.zeros((0, self.width), np.float32)
                 self._host_bias = np.zeros((0,), np.float32)
             else:
@@ -867,6 +948,11 @@ class RecordRowBank(DeviceRowBank):
                     np.asarray(bias)[:rows].astype(np.float32)
                     if bias is not None else np.zeros((rows,), np.float32)
                 )
+                self.dead = int(np.isinf(self._host_bias).sum())
+                if self.NORMS:  # whatever plane came along is of other rows
+                    from redisson_tpu.core import kernels as K
+
+                    self._set_norms(K.rowbank_norms(bank, scale))
             ivf = getattr(self, "_ivf", None)
             if ivf is not None:
                 self._ivf = type(ivf)(self.spec)
@@ -930,11 +1016,15 @@ class EmbeddingBank(RecordRowBank):
     own device and every per-shard axis (IVF plane, compressed storage,
     lane accounting) is exactly this class, unchanged."""
 
+    NORMS = True
+
     def __init__(self, engine, index: str, spec: VectorFieldSpec,
                  block: int = DEFAULT_BLOCK, reset: bool = True,
                  record_name: Optional[str] = None):
         self.spec = spec
         self._ivf = _IvfPlane(spec) if spec.algo == "IVF" else None
+        self._warm: set = set()  # (capacity, k, masked) with every bucket built
+        self.counts = True       # False on a shard: its facade counts once
         super().__init__(
             engine, record_name or bank_record_name(index, spec.field),
             spec.dim, block=block, dtype=spec.dtype,
@@ -1294,7 +1384,7 @@ class EmbeddingBank(RecordRowBank):
 
     def knn_async(self, queries: np.ndarray, k: int,
                   allowed_rows: Optional[np.ndarray] = None,
-                  nprobe: Optional[int] = None):
+                  nprobe: Optional[int] = None, warm: bool = False):
         """Dispatch one stacked KNN: queries (Q, dim) float32 against every
         live row (FLAT) or the routed top-nprobe cells (IVF).  Returns
         (device_dist, device_idx, q_count, k_eff) WITHOUT forcing the
@@ -1302,7 +1392,11 @@ class EmbeddingBank(RecordRowBank):
         transfer drains it; embedded callers np.asarray().
 
         ``allowed_rows`` (hybrid prefilter): int row ids that may score —
-        everything else gets +inf distance via an additive bias operand.
+        everything else gets +inf distance via an additive (capacity,) plane.
+
+        ``warm``: the caller stacks a frame's run, whose length the next
+        frame changes — build the FLAT program at every query bucket now
+        (once a capacity and k), so no later run meets a cold one.
 
         Falls back to the host path (knn_host) when the device plane is
         disarmed (RTPU_NO_VECTOR) — callers branch on vector_enabled()."""
@@ -1317,21 +1411,22 @@ class EmbeddingBank(RecordRowBank):
                 return None
             if self._ivf is not None:
                 self._ivf_sync()
-            qb = _query_bucket(nq)
+            qb = knn_query_bucket(nq)
             staged = K.stage(self._pad_queries(q, qb))
             metric = self.spec.metric
+            mask = None
+            if allowed_rows is not None:
+                m = np.full(self._cap, np.inf, np.float32)
+                m[np.asarray(allowed_rows, np.int64)] = 0.0
+                mask = K.stage(m)
+            live = rows - self.dead
+            nv = K.valid_n(rows)
             if self.ivf_ready():
                 np_eff = self._resolve_nprobe(nprobe)
                 dc, dl = self._ensure_index_device()
                 cand = np_eff * self._ivf.cell_cap
                 k_eff = max(1, min(int(k), cand))
-                mask = None
-                if allowed_rows is not None:
-                    m = np.full(self._cap, np.inf, np.float32)
-                    m[np.asarray(allowed_rows, np.int64)] = 0.0
-                    mask = K.stage(m)
                 with self._lane_gate(nq * max(1, min(rows, cand))):
-                    nv = K.valid_n(rows)
                     if scale is not None and mask is not None:
                         dist, idx = K.knn_ivf_topk_masked_q(
                             bank, scale, bias, mask, dc, dl, staged, nv,
@@ -1352,35 +1447,44 @@ class EmbeddingBank(RecordRowBank):
                             bank, bias, dc, dl, staged, nv,
                             k_eff, np_eff, metric,
                         )
+                if self.counts:
+                    _count_knn(nq, qb, min(live, cand))
                 return dist, idx, nq, k_eff
             if nprobe and self._ivf is None:
                 raise ValueError("NPROBE applies to an IVF field")
             k_eff = max(1, min(int(k), self._cap))
+            norms = self._norms_locked(bank, scale)
             with self._lane_gate(nq * max(1, rows)):
-                nv = K.valid_n(rows)
-                if allowed_rows is None:
-                    if scale is not None:
-                        dist, idx = K.knn_topk_q(
-                            bank, scale, bias, staged, nv, k_eff, metric
-                        )
-                    else:
-                        dist, idx = K.knn_topk(
-                            bank, bias, staged, nv, k_eff, metric
-                        )
-                else:
-                    qbias = np.full((qb, self._cap), np.inf, np.float32)
-                    qbias[:, np.asarray(allowed_rows, np.int64)] = 0.0
-                    if scale is not None:
-                        dist, idx = K.knn_topk_masked_q(
-                            bank, scale, bias, K.stage(qbias), staged,
-                            nv, k_eff, metric,
-                        )
-                    else:
-                        dist, idx = K.knn_topk_masked(
-                            bank, bias, K.stage(qbias), staged,
-                            nv, k_eff, metric,
-                        )
+                if warm:
+                    self._warm_query_buckets(
+                        bank, scale, bias, norms, mask, nv, k_eff
+                    )
+                dist, idx = K.knn_flat_topk(
+                    bank, scale, bias, norms, mask, staged, nv, k_eff, metric
+                )
+            if self.counts:
+                _count_knn(nq, qb, live)
         return dist, idx, nq, k_eff
+
+    def _warm_query_buckets(self, bank, scale, bias, norms, mask, nv,
+                            k_eff: int) -> None:
+        """Build knn_flat_topk at every KNN_QUERY_BUCKETS size for this
+        capacity, k and kind of query, on the planes themselves (it writes
+        nothing).  jit keeps the programs; the set only spares the calls."""
+        import jax
+
+        from redisson_tpu.core import kernels as K
+
+        key = (self._cap, k_eff, mask is not None)
+        if key in self._warm:
+            return
+        for qb in KNN_QUERY_BUCKETS:
+            zeros = K.stage(np.zeros((qb, self.pwidth), np.float32))
+            jax.block_until_ready(K.knn_flat_topk(
+                bank, scale, bias, norms, mask, zeros, nv, k_eff,
+                self.spec.metric,
+            ))
+        self._warm.add(key)
 
     def _host_flat_dists(self, q: np.ndarray, host: np.ndarray) -> np.ndarray:
         dots = q @ host.T  # (Q, rows) f32
@@ -1590,6 +1694,8 @@ class ShardedEmbeddingBank:
                           record_name=nm)
             for nm in names
         ]
+        for sh in self.shards:
+            sh.counts = False  # knn_async below counts a query once
         # global rowid -> (shard, shard-local rowid); -1 = never assigned
         self._route = np.full(0, -1, np.int32)
         self._local = np.full(0, -1, np.int32)
@@ -1771,7 +1877,7 @@ class ShardedEmbeddingBank:
 
     def knn_async(self, queries: np.ndarray, k: int,
                   allowed_rows: Optional[np.ndarray] = None,
-                  nprobe: Optional[int] = None):
+                  nprobe: Optional[int] = None, warm: bool = False):
         """Row-parallel KNN: fan the stacked queries out as one
         ``knn_async`` leg per live shard (concurrent, each under its own
         device lane), d2d-colocate the per-shard (Q, k) tops onto one
@@ -1790,7 +1896,7 @@ class ShardedEmbeddingBank:
             return None
         pool = _fanout_pool()
         futs = [
-            pool.submit(self.shards[s].knn_async, q, k, al, nprobe)
+            pool.submit(self.shards[s].knn_async, q, k, al, nprobe, warm)
             for s, al in legs
         ]
         outs = []
@@ -1840,6 +1946,8 @@ class ShardedEmbeddingBank:
         with self._merge_lane_gate(dest, nq * total):
             dist, sid, lidx = merge(tuple(dists), tuple(idxs), sop, k_out)
         ioplane.STATS.count_sharded_merge()
+        _count_knn(nq, knn_query_bucket(nq),
+                   sum(self.shards[s].rows - self.shards[s].dead for s, _o in outs))
         return dist, sid, lidx, nq, k_out
 
     def resolve_hits(self, vals) -> Tuple[np.ndarray, np.ndarray]:

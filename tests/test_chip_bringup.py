@@ -63,6 +63,9 @@ def test_platform_flag_wins_and_sigterm_releases_with_client_connected():
         assert fields["replica_occupancy"] == "none"
         assert fields["host_colocations"] == "0"
         assert fields["wire_plane"] in ("native", "python")
+        # a server process sets the collector for a heap of records
+        # (server.GC_THRESHOLDS); an embedded ServerThread does not
+        assert fields["gc_thresholds"] == "50000,20,100"
         proc.send_signal(signal.SIGTERM)  # `sock` stays open and idle
         assert proc.wait(timeout=15) == 0
         sock.close()
