@@ -397,6 +397,7 @@ _planes_asked = 0
 _planes_stacked = 0
 _cmds_offered = 0
 _knn_fused = 0
+_knn_shared = 0
 
 
 def planes_counted() -> tuple:
@@ -423,12 +424,24 @@ def count_offered(n: int) -> None:
         _cmds_offered += n
 
 
-def count_knn_fused(n: int) -> None:
+def count_knn_fused(n: int, shared: int) -> None:
     """`n` search commands rode one stacked KNN dispatch (no plane is
-    stacked for them: they share the index's bank)."""
-    global _knn_fused
+    stacked for them: they share the index's bank), `shared` of them
+    answered from the one plan they share, as bytes."""
+    global _knn_fused, _knn_shared
     with _PLANES_LOCK:
         _knn_fused += n
+        _knn_shared += shared
+
+
+def knn_wave_counted() -> tuple:
+    """(members, shared members) of this process's stacked KNN dispatches:
+    search commands that rode one, and those of them that differed from
+    their wave's first in the query blob alone, shared its plan and were
+    answered by the wave's encoder (verbs/modules.py coalesce_knn_run).
+    METRICS exports both (knn_wave_cmds_total, knn_wave_shared_cmds_total),
+    always on."""
+    return _knn_fused, _knn_shared
 
 
 def cmds_counted() -> tuple:

@@ -155,10 +155,11 @@ class FrameTrace:
     def stage_totals(self) -> Dict[str, int]:
         """{stage: summed µs} — the SLOWLOG breakdown projection (child
         spans excluded: ``kernel.member`` duplicates its kernel span's time,
-        the ``reply.*`` children their ``reply`` span's)."""
+        the ``reply.*`` children their ``reply`` span's, ``wave.plan`` its
+        kernel span's and ``wave.answer`` its ``reply`` span's)."""
         out: Dict[str, int] = {}
         for s in self.spans:
-            if s.name.endswith(".member") or s.name.startswith("reply."):
+            if s.name.endswith(".member") or s.name.startswith(("reply.", "wave.")):
                 continue
             out[s.name] = out.get(s.name, 0) + s.dur_us
         return out

@@ -69,6 +69,17 @@ class LazyReply:
         return self.finish(tuple(np.asarray(v) for v in self.device))
 
 
+class Encoded:
+    """A reply already in wire bytes: an error encoded where it was caught,
+    or an answer its handler made as bytes (a KNN wave's, verbs/modules.py
+    _ft_wave_encode).  The frame's encoder passes ``data`` through."""
+
+    __slots__ = ("data",)
+
+    def __init__(self, data: bytes):
+        self.data = data
+
+
 def gather_lazy_device_results(lazies: List["LazyReply"]) -> List[tuple]:
     """Fetch every device value of `lazies` with one grouped fetch a device
     — the frame-level gather, THE shared primitive of the overlap plane

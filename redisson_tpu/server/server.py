@@ -50,16 +50,7 @@ from redisson_tpu.net import resp
 from redisson_tpu.net.resp import ProtocolError, RespError
 from redisson_tpu.observe import trace as _obs
 from redisson_tpu.server import scheduler as _sched
-from redisson_tpu.server.registry import LazyReply, REGISTRY, CommandContext
-
-
-class _Encoded:
-    """Pre-encoded wire frame (errors encoded at catch time)."""
-
-    __slots__ = ("data",)
-
-    def __init__(self, data: bytes):
-        self.data = data
+from redisson_tpu.server.registry import CommandContext, Encoded, LazyReply, REGISTRY
 
 
 class _PendingFrame:
@@ -419,6 +410,14 @@ class TpuServer:
         )
         self.metrics.gauge(
             "knn_rows_scored_total", lambda: _vector.knn_counted()[2]
+        )
+        # members of stacked KNN dispatches, and those of them answered from
+        # a plan the wave shares, as bytes (verbs/modules.py coalesce_knn_run)
+        self.metrics.gauge(
+            "knn_wave_cmds_total", lambda: _coalesce.knn_wave_counted()[0]
+        )
+        self.metrics.gauge(
+            "knn_wave_shared_cmds_total", lambda: _coalesce.knn_wave_counted()[1]
         )
         self.metrics.gauge(
             "gather_bytes_owed_total", lambda: ioplane.gather_bytes_counted()[0]
@@ -1267,7 +1266,7 @@ class TpuServer:
             # a permanently wedged worker pool
             self._pause_gate.wait(timeout=60.0)
 
-    def _error_reply(self, e: BaseException, n: int = 1) -> _Encoded:
+    def _error_reply(self, e: BaseException, n: int = 1) -> Encoded:
         """THE translation of a failed dispatch into a reply, for `n`
         commands that share it.  A stopping worker pool drops the
         connection instead (ConnectionResetError): it never replies
@@ -1289,7 +1288,7 @@ class TpuServer:
             text = _DEVICE_FAULT_TRYAGAIN
         else:  # uninitialized object, state errors, handler bugs: sandboxed
             text = f"ERR internal: {type(e).__name__}: {e}"
-        return _Encoded(resp.encode_error(text))
+        return Encoded(resp.encode_error(text))
 
     def _dispatch_one(self, ctx, cmd, qos_class: Optional[str] = None,
                       held: bool = False):
@@ -1307,7 +1306,7 @@ class TpuServer:
         if not isinstance(cmd, list) or not all(
             isinstance(a, (bytes, bytearray)) for a in cmd
         ):
-            return _Encoded(resp.encode_error("ERR bad request frame"))
+            return Encoded(resp.encode_error("ERR bad request frame"))
         try:
             if held:
                 return REGISTRY.dispatch(self, ctx, cmd)
@@ -1486,8 +1485,8 @@ class TpuServer:
                 return [enc for _ in cmds]
         if fused is None:
             return [self._dispatch_one(ctx, cmd, held=True) for cmd in cmds]
-        replies, slots = fused
-        _coalesce.count_knn_fused(len(cmds))
+        replies, slots, shared = fused
+        _coalesce.count_knn_fused(len(cmds), shared)
         if cur is not None:
             self._stacked_kernel_span(cur, k0, "FT.SEARCH", cmds, stacked=slots)
         return replies
@@ -1787,7 +1786,7 @@ class TpuServer:
             # fused window covers ADMITTED ops only, so a partially-applied
             # coalesced add run can never be created by (or re-dispatched
             # after) a shed decision
-            shed = _Encoded(resp.encode_error(_sched.busy_error(adm.tenant)))
+            shed = Encoded(resp.encode_error(_sched.busy_error(adm.tenant)))
             for i, refused in enumerate(shed_mask):
                 if refused:
                     results[i] = shed
@@ -2518,7 +2517,7 @@ def _encode_frame(results: list, proto: int) -> bytes:
     run: list = []
     flush = parts.append
     for r in results:
-        if isinstance(r, _Encoded):
+        if isinstance(r, Encoded):
             if run:
                 flush(resp.encode_replies(run, proto))
                 run = []

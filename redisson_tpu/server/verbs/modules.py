@@ -5,8 +5,10 @@ verb family, shared preludes in verbs/common.py so numkeys/syntax validation
 cannot diverge between families again.
 """
 
+import time
 
 from redisson_tpu.net.resp import RespError
+from redisson_tpu.observe import trace as _obs
 from redisson_tpu.server.registry import register, _s, _int
 from redisson_tpu.server.verbs.common import _fnum
 
@@ -495,8 +497,8 @@ def _ft_parse_search_opts(args, i):
     PARAMS / DIALECT / NPROBE / WITHCURSOR [COUNT n]."""
     opts = {
         "nocontent": False, "sort_by": None, "desc": False,
-        "off": 0, "lim": 10, "params": {}, "withcursor": False,
-        "cursor_count": 10, "nprobe": None,
+        "off": 0, "lim": 10, "params": {}, "param_at": {},
+        "withcursor": False, "cursor_count": 10, "nprobe": None,
     }
     while i < len(args):
         opt = bytes(args[i]).upper()
@@ -518,6 +520,7 @@ def _ft_parse_search_opts(args, i):
                 raise RespError("ERR PARAMS count must be even")
             for j in range(i + 2, i + 2 + n, 2):
                 opts["params"][_s(args[j])] = bytes(args[j + 1])
+                opts["param_at"][_s(args[j])] = j + 1  # where the value stands
             i += 2 + n
         elif opt == b"DIALECT":
             i += 2  # accepted for driver compatibility; grammar is fixed
@@ -599,7 +602,7 @@ def _ft_knn_plan(server, ctx, args, multi: bool):
     opts = _ft_parse_search_opts(args, 2)
     cond = _ft_parse_query(qstr, idx.schema)
     plan = {"name": name, "idx": idx, "qstr": qstr, "cond": cond, "knn": knn,
-            "opts": opts, "q": None, "encode": None}
+            "opts": opts, "q": None, "encode": None, "multi": multi}
     if multi:
         if knn is None:
             raise RespError("ERR FT.MSEARCH requires a KNN query")
@@ -713,54 +716,131 @@ def cmd_ft_msearch(server, ctx, args):
     return _ft_knn_answer(server, _ft_knn_plan(server, ctx, args, multi=True))
 
 
+def _ft_wave_plans(server, ctx, cmds):
+    """(plan, $param blob) a command of a KNN wave.  The first is planned as
+    the command it is (_ft_knn_plan: the index brought up to date, the
+    connection's tracked read noted — both once a wave, which is one
+    connection's consecutive searches with no write between them).  A
+    further command that is the first's byte for byte but for that blob, of
+    one vector, asks the same of another query and shares the first's plan;
+    any other is planned alone.  None where the first has no KNN arm (no
+    wave); raises what planning a command raises."""
+
+    def alone(cmd):
+        verb = bytes(cmd[0]).upper()
+        if server.cluster_view or server.role == "replica":
+            server.check_routing(verb.decode(), cmd[1:], asking=False)
+        plan = _ft_knn_plan(server, ctx, cmd[1:], multi=verb == b"FT.MSEARCH")
+        knn = plan["knn"]
+        return plan, (plan["opts"]["params"][knn["param"]] if knn else b"")
+
+    first = alone(cmds[0])
+    plan, head = first[0], cmds[0]
+    if plan["knn"] is None:
+        return None
+    at = 1 + plan["opts"]["param_at"][plan["knn"]["param"]]
+    width = 4 * plan["q"].shape[1]
+    before, after = head[:at], head[at + 1:]
+    return [first] + [
+        (plan, cmd[at])
+        if len(cmd) == len(head) and len(cmd[at]) == width
+        and cmd[:at] == before and cmd[at + 1:] == after
+        else alone(cmd)
+        for cmd in cmds[1:]
+    ]
+
+
+def _ft_wave_encode(plan, docs, scores, ends, queries):
+    """The replies of the wave's members that share `plan` — FT.SEARCH,
+    NOCONTENT, no cursor, the query ``queries[j]`` of the wave each — as
+    wire bytes, from the hits as columns (SearchService.knn): byte for byte
+    what ``resp.encode_reply(plan["encode"](hits), proto)`` gives a member
+    under either protocol (integers, bulk strings and arrays read the same
+    in both), in one pass over the wave's hits."""
+    from redisson_tpu.net import resp
+    from redisson_tpu.server.registry import Encoded
+
+    opts, knn = plan["opts"], plan["knn"]
+    score = b"*2\r\n" + resp.encode_bulk(
+        (knn["alias"] or f"__{knn['field']}_score").encode())
+    rows = [
+        b"$%d\r\n%b\r\n%b$%d\r\n%b\r\n" % (len(d), d, score, len(t), t)
+        for d, t in zip(
+            [d.encode() for d in docs.tolist()],
+            [_ft_score_bytes(x) for x in scores.tolist()],
+        )
+    ]
+    ends = ends.tolist()
+    starts = [0] + ends[:-1]
+    lo, hi = opts["off"], opts["off"] + opts["lim"]
+    out = []
+    for q in queries:
+        hits = rows[starts[q]:ends[q]]
+        shown = (hits[::-1] if opts["desc"] else hits)[lo:hi]
+        out.append(Encoded(b"*%d\r\n:%d\r\n%b" % (
+            1 + 2 * len(shown), len(hits), b"".join(shown))))
+    return out
+
+
 def coalesce_knn_run(server, ctx, cmds):
     """ONE stacked KNN dispatch for a wave of FT.SEARCH / FT.MSEARCH commands
     of one pipelined frame that name the same index and the same query text
     (core/coalesce.py wave_entry: same filter, field, k), so the bank is
     read once for all of them.  Returns (one LazyReply a command, the query
-    slots the dispatch was padded to), each reply its own queries' hits
-    encoded as the command alone would (NOCONTENT, LIMIT, SORTBY,
-    WITHCURSOR as parsed per command), or None where the wave cannot
-    ride — a command that does not parse, commands that differ in NPROBE, the
-    device plane disarmed, an empty index: the per-command path then replies,
-    errors included.  Prechecks as coalesce_bloom_run's."""
+    slots the dispatch was padded to, the members the wave's encoder
+    answers), each reply its own queries' hits encoded as the command alone
+    would (NOCONTENT, LIMIT, SORTBY, WITHCURSOR as parsed per command), or
+    None where the wave cannot ride — a command that does not parse, commands
+    that differ in NPROBE, the device plane disarmed, an empty index: the
+    per-command path then replies, errors included.  Prechecks as
+    coalesce_bloom_run's.
+
+    Host work is a wave's, not a command's: members that differ in their
+    query blob alone share ONE plan (_ft_wave_plans), the queries are one
+    array over the joined blobs, the fetched rows become doc ids and scores
+    once, and the plain members of the shared plan are answered as wire
+    bytes in one pass (_ft_wave_encode)."""
     import numpy as np
 
     from redisson_tpu.server.registry import LazyReply
+    from redisson_tpu.services.search import hit_lists
     from redisson_tpu.services.vector import KNN_QUERY_BUCKETS, knn_query_bucket
     from redisson_tpu.utils.metrics import run_hooks_end, run_hooks_start
 
     if ctx.multi_queue is not None or not ctx.authenticated or ctx.asking:
         return None
-    plans = []
+    cur = _obs.current_trace() if _obs._tracer is not None else None
+    t0 = time.monotonic() if cur is not None else 0.0
     try:
-        for cmd in cmds:
-            verb = bytes(cmd[0]).upper()
-            if server.cluster_view or server.role == "replica":
-                server.check_routing(verb.decode(), cmd[1:], asking=False)
-            plans.append(_ft_knn_plan(server, ctx, cmd[1:],
-                                      multi=verb == b"FT.MSEARCH"))
+        members = _ft_wave_plans(server, ctx, cmds)
     except Exception:  # noqa: BLE001 — nothing was dispatched: per command
         return None
-    first = plans[0]
-    if any(
-        p["knn"] is None or p["idx"] is not first["idx"]
-        or p["qstr"] != first["qstr"]
-        or (p["knn"]["field"], p["knn"]["k"]) != (
-            first["knn"]["field"], first["knn"]["k"])
-        or p["opts"]["nprobe"] != first["opts"]["nprobe"] for p in plans
+    first = members[0][0] if members else None
+    if first is None or any(
+        p is not first and (
+            p["knn"] is None or p["idx"] is not first["idx"]
+            or p["qstr"] != first["qstr"]
+            or (p["knn"]["field"], p["knn"]["k"]) != (
+                first["knn"]["field"], first["knn"]["k"])
+            or p["opts"]["nprobe"] != first["opts"]["nprobe"]
+        ) for p, _blob in members
     ):
         return None
-    sizes = [p["q"].shape[0] for p in plans]
-    if sum(sizes) > KNN_QUERY_BUCKETS[-1]:
+    dim = first["q"].shape[1]
+    ends = np.cumsum([len(blob) // (4 * dim) for _p, blob in members]).tolist()
+    if ends[-1] > KNN_QUERY_BUCKETS[-1]:
         return None  # more vectors than a warmed bucket holds
+    queries = np.frombuffer(
+        b"".join([blob for _p, blob in members]), "<f4").reshape(-1, dim)
+    if cur is not None:
+        cur.add_span("wave.plan", t0, time.monotonic(), members=len(cmds))
     hooks = getattr(server, "hooks", None) or ()
     tokens = run_hooks_start(hooks, "FT.SEARCH.COALESCED", (len(cmds),))
     try:
         device, finish = _ft(server).knn(
-            first["name"], first["knn"]["field"],
-            np.concatenate([p["q"] for p in plans]), first["knn"]["k"],
+            first["name"], first["knn"]["field"], queries, first["knn"]["k"],
             condition=first["cond"], nprobe=first["opts"]["nprobe"], warm=True,
+            columns=True,
         )
     except BaseException as e:
         run_hooks_end(tokens, "FT.SEARCH.COALESCED", e)
@@ -770,23 +850,37 @@ def coalesce_knn_run(server, ctx, cmds):
     run_hooks_end(tokens, "FT.SEARCH.COALESCED", None)
     if device is None:
         return None
+    opts, starts = first["opts"], [0] + ends[:-1]
+    shared = [i for i, (p, _blob) in enumerate(members) if p is first]
+    if len(shared) < 2 or first["multi"] or opts["withcursor"] or not opts["nocontent"]:
+        shared = []  # nobody shares the first's plan, or it is no plain one
+    place = {i: j for j, i in enumerate(shared)}
     done: list = []
 
-    def per_query(vals):  # rows -> doc ids -> scores once a run
+    def answer(vals):  # once a wave: rows -> doc ids -> scores -> the answers
         if not done:
-            done.append(finish(vals))
+            cur = _obs.current_trace() if _obs._tracer is not None else None
+            t0 = time.monotonic() if cur is not None else 0.0
+            cols = finish(vals)
+            done.append((
+                _ft_wave_encode(first, *cols, [starts[i] for i in shared])
+                if shared else [],
+                hit_lists(*cols) if len(shared) < len(members) else None,
+            ))
+            if cur is not None:
+                cur.add_span("wave.answer", t0, time.monotonic(),
+                             members=len(members), shared=len(shared))
         return done[0]
 
-    out, off = [], 0
-    for p, n in zip(plans, sizes):
-        out.append(LazyReply(
-            device=device,
-            finish=lambda vals, p=p, a=off, b=off + n: p["encode"](
-                per_query(vals)[a:b]
-            ),
-        ))
-        off += n
-    return out, knn_query_bucket(off)
+    def reply(i, plan):
+        if i in place:
+            return lambda vals, j=place[i]: answer(vals)[0][j]
+        return lambda vals, a=starts[i], b=ends[i]: (
+            plan["encode"](answer(vals)[1][a:b]))
+
+    out = [LazyReply(device=device, finish=reply(i, p))
+           for i, (p, _blob) in enumerate(members)]
+    return out, knn_query_bucket(ends[-1]), len(shared)
 
 
 @register("FT.AGGREGATE")

@@ -54,6 +54,15 @@ def search_counted() -> tuple:
     return _docs_indexed, _scan_keys
 
 
+def hit_lists(docs, scores, ends) -> list:
+    """A KNN's hits as columns (SearchService.knn: doc ids, distances and
+    where each query's end) -> one ``[(doc_id, distance), ...]`` list a
+    query."""
+    flat = list(zip(docs.tolist(), scores.tolist()))
+    ends = ends.tolist()
+    return [flat[a:b] for a, b in zip([0] + ends[:-1], ends)]
+
+
 class _RowDocs:
     """row -> doc id, as an object array that grows by doubling: a KNN
     reply's (Q, k) rows become doc ids by ONE lookup (take), where a list
@@ -828,7 +837,8 @@ class SearchService:
 
     def knn(self, index: str, field: str, queries, k: int,
             condition: Optional[Condition] = None,
-            nprobe: Optional[int] = None, warm: bool = False):
+            nprobe: Optional[int] = None, warm: bool = False,
+            columns: bool = False):
         """One stacked KNN over the index's embedding bank (FLAT exact, or
         routed IVF once the field's coarse quantizer trained; ``nprobe``
         overrides the IVF field's probe width for this query; ``warm``: the
@@ -841,7 +851,9 @@ class SearchService:
         None and ``finish(None)`` scores on the NumPy path.  Either way
         ``finish`` maps rows back to doc ids and returns one
         ``[(doc_id, distance), ...]`` list per query (distance ascending,
-        ties toward the lower rowid)."""
+        ties toward the lower rowid) — or, with ``columns``, the same hits
+        as ``(doc ids (H,), distances (H,), ends (nq,))``, query after query,
+        ``ends`` where each query's hits end (hit_lists makes the lists)."""
         from redisson_tpu.services import vector as V
 
         idx = self._idx(index)
@@ -856,6 +868,8 @@ class SearchService:
             raise ValueError("NPROBE applies to an IVF field")
         q = np.ascontiguousarray(queries, np.float32).reshape(-1, bank.spec.dim)
         nq = q.shape[0]
+        shape = (lambda *cols: cols) if columns else hit_lists
+        none = (np.empty(0, object), np.empty(0), np.zeros(nq, np.int64))
         allowed = None
         if condition is not None:
             ids = idx._eval(condition)
@@ -865,7 +879,7 @@ class SearchService:
                     np.int64,
                 )
             if allowed.size == 0:
-                return None, lambda _vals: [[] for _ in range(nq)]
+                return None, lambda _vals: shape(*none)
         armed = V.vector_enabled()
         out = (
             bank.knn_async(q, k, allowed_rows=allowed, nprobe=nprobe,
@@ -873,14 +887,14 @@ class SearchService:
             if armed else None
         )
         if armed and out is None:
-            return None, lambda _vals: [[] for _ in range(nq)]
+            return None, lambda _vals: shape(*none)
 
         def finish(vals):
             if vals is None:  # disarmed: score now, on host
                 host = bank.knn_host(q, k, allowed_rows=allowed,
                                      nprobe=nprobe)
                 if host is None:
-                    return [[] for _ in range(nq)]
+                    return shape(*none)
                 dist_h, idx_h = host[0], host[1]
             else:
                 # the bank decodes its own device outputs to GLOBAL rowids:
@@ -895,14 +909,12 @@ class SearchService:
             ok = np.isfinite(dist_h) & (docs != None)  # noqa: E711 — elementwise
             qis, _j = np.nonzero(ok)  # row-major: reply order
             if not len(qis):
-                return [[] for _ in range(nq)]
+                return shape(*none)
             # the kernel/NumPy paths choose WHICH rows win; the scores on
             # the wire come from ONE canonical per-pair routine so armed
             # and disarmed replies are byte-identical (vector.pair_scores)
             scores = bank.pair_scores(q, qis, idx_h[ok])
-            flat = list(zip(docs[ok].tolist(), scores.tolist()))
-            ends = np.cumsum(ok.sum(axis=1)).tolist()
-            return [flat[a:b] for a, b in zip([0] + ends[:-1], ends)]
+            return shape(docs[ok], scores, np.cumsum(ok.sum(axis=1)))
 
         if not armed:
             return None, finish

@@ -17,7 +17,15 @@ Contracts pinned here:
     that is no multiple of the block, a hybrid filter, INT8 / FLOAT16,
     sharded — on integer and Gaussian data;
   * the norms plane follows set_row / overwrite / delete / growth.
+
+ISSUE 33: inside a stacked KNN wave host work is per wave.  Members that
+differ from the wave's first in the query blob alone share its plan and are
+answered as wire bytes by the wave's encoder — byte for byte what each
+answers alone, under RESP2 and RESP3; any other member is planned alone and
+rides the same dispatch; ``rtpu_knn_wave_cmds_total`` /
+``rtpu_knn_wave_shared_cmds_total`` count both kinds.
 """
+import socket
 import time
 
 import numpy as np
@@ -25,6 +33,7 @@ import pytest
 
 from redisson_tpu.core import kernels as K
 from redisson_tpu.core.engine import Engine
+from redisson_tpu.net import resp
 from redisson_tpu.net.client import Connection
 from redisson_tpu.server.server import ServerThread
 from redisson_tpu.services import search as S
@@ -360,6 +369,214 @@ def test_knn_counters_count_queries_slots_and_rows(loaded):
     c.execute("DEL", "doc:0")
     c.execute(*_search_cmd(vecs[1]))
     assert V.knn_counted() == (q1 + 1, s1 + 1, r1 + 79)
+
+
+# -- host work is a wave's: one plan, one answer (ISSUE 33) ----------------------
+
+
+def _raw(sock, cmds):
+    """The bytes that answer `cmds`, sent as one frame."""
+    sock.sendall(b"".join(resp.encode_command(*c) for c in cmds))
+    parser, buf, got = resp.RespParser(), b"", 0
+    while got < len(cmds):
+        chunk = sock.recv(1 << 20)
+        assert chunk, "the server hung up"
+        buf += chunk
+        got += len(parser.feed(chunk))
+    return buf
+
+
+def _wave_counters(c):
+    rows = dict(line.rsplit(" ", 1) for line in
+                bytes(c.execute("METRICS")).decode().splitlines() if line)
+    return (int(float(rows["rtpu_knn_wave_cmds_total"])),
+            int(float(rows["rtpu_knn_wave_shared_cmds_total"])))
+
+
+def _wave_spans(c):
+    """(name, attrs) of the kernel and wave.* spans of the traced frames, in
+    frame order."""
+    out = []
+    for *_head, spans in c.execute("TRACE", "GET", 100, "BY", "nothing"):
+        for name, _off, _dur, attrs in spans:
+            if bytes(name) in (b"kernel", b"wave.plan", b"wave.answer"):
+                out.append((bytes(name).decode(), {
+                    bytes(attrs[i]).decode(): attrs[i + 1]
+                    for i in range(0, len(attrs), 2)}))
+    return out
+
+
+@pytest.fixture()
+def gone_meanwhile(server, monkeypatch):
+    """hide(docs): those documents of "ix" are deleted between every
+    dispatch and its fetch from then on (the rows score, the ids are gone
+    when the reply is made) — for a command alone as for a wave."""
+    from redisson_tpu.server import server as server_mod
+
+    hidden = []
+    force = server_mod._force_lazies
+
+    def force_with_rows_gone(results, srv):
+        idx = srv.engine._services["search"]._idx("ix")
+        rows = [idx._rowid[d] for d in hidden]
+        for row in rows:
+            idx._rowdoc[row] = None
+        try:
+            return force(results, srv)
+        finally:
+            for row, d in zip(rows, hidden):
+                idx._rowdoc[row] = d
+
+    monkeypatch.setattr(server_mod, "_force_lazies", force_with_rows_gone)
+    return hidden.extend
+
+
+_SCORE = ("SORTBY", "__vector_score")
+_WAVE_CASES = {
+    # the wording of the ann-batch cell
+    "limit_0_10": (10, _SCORE + ("LIMIT", "0", "10", "DIALECT", "2")),
+    "limit_2_5": (10, ("LIMIT", "2", "5")),
+    "sortby_desc": (6, _SCORE + ("DESC", "LIMIT", "1", "5")),
+    "k_above_live": (100, ("LIMIT", "0", "100")),
+    "empty": (5, ()),
+    "deleted_meanwhile": (5, ()),
+    "sharded": (7, _SCORE + ("LIMIT", "0", "7")),
+}
+
+
+@pytest.mark.parametrize("proto", [2, 3], ids=["hello2", "hello3"])
+@pytest.mark.parametrize("case", list(_WAVE_CASES))
+def test_a_shared_plan_frame_answers_the_single_commands_bytes(
+        server, gone_meanwhile, case, proto):
+    n = 12
+    k, opts = _WAVE_CASES[case]
+    c = _conn(server)
+    vecs = _vecs(80, seed=9)
+    if case == "sharded":
+        assert c.execute(
+            "FT.CREATE", "ix", "ON", "HASH", "PREFIX", "1", "doc:", "SCHEMA",
+            "vector", "VECTOR", "FLAT", "8", "TYPE", "FLOAT32", "DIM", str(DIM),
+            "DISTANCE_METRIC", "L2", "SHARDS", "2") == b"OK"
+    else:
+        _create(c)
+    _load(c, vecs)
+    if case == "empty":
+        gone_meanwhile(f"doc:{i}" for i in range(len(vecs)))
+    if case == "deleted_meanwhile":
+        gone_meanwhile(["doc:3", "doc:4"])
+    queries = np.concatenate([vecs[3:5] + 1, _vecs(n - 2, seed=200)])
+    cmds = [_search_cmd(q, "ix", k, *opts) for q in queries]
+    with socket.create_connection((server.server.host, server.server.port),
+                                  timeout=60.0) as sock:
+        _raw(sock, [("HELLO", str(proto))])
+        alone = [_raw(sock, [cmd]) for cmd in cmds]
+        before = _wave_counters(c)
+        together = _raw(sock, cmds)
+        after = _wave_counters(c)
+    assert together == b"".join(alone)
+    assert (after[0] - before[0], after[1] - before[1]) == (n, n)
+    # and the bytes say what the case is about
+    replies = resp.RespParser().feed(together)
+    if case == "empty":
+        assert replies == [[0]] * n
+    elif case == "deleted_meanwhile":
+        assert replies[0][0] == k - 1 and "doc:3" not in _ids(replies[0])
+        assert replies[1][0] == k - 1 and "doc:4" not in _ids(replies[1])
+        assert replies[2][0] == k
+    elif case == "k_above_live":
+        assert all(r[0] == len(vecs) and len(r) == 1 + 2 * len(vecs) for r in replies)
+    elif case == "limit_2_5":
+        assert all(r[0] == k and len(r) == 1 + 2 * 5 for r in replies)
+    else:
+        assert _ids(replies[0])[0 if "DESC" not in opts else -1] in ("doc:3", "doc:4")
+    c.close()
+
+
+def _mixed_wave(q, first_is_plain):
+    """Searches of one index and query text that differ in more than the
+    blob: (the frame's commands, how many of them share the first's plan)."""
+    plain = [_search_cmd(v, "ix", 4) for v in q[:4]]
+    other = [
+        _search_cmd(q[4], "ix", 4, "WITHCURSOR", "COUNT", "2"),
+        _search_cmd(q[5], "ix", 4, "LIMIT", "1", "2"),
+        ("FT.MSEARCH", "ix", "*=>[KNN 4 @vector $BLOB]", "PARAMS", "2", "BLOB",
+         q[6:8].tobytes()),
+        ("FT.SEARCH", "ix", "*=>[KNN 4 @vector $BLOB]", "PARAMS", "2", "BLOB",
+         q[8].tobytes()),  # returns content
+    ]
+    if first_is_plain:
+        return plain[:2] + other[:2] + plain[2:3] + other[2:] + plain[3:], 4
+    return other + plain, 0  # nobody is the cursor search's byte for byte
+
+
+@pytest.mark.parametrize("first_is_plain", [True, False], ids=["plain_first", "other_first"])
+def test_a_mixed_wave_rides_one_dispatch_and_answers_each_as_alone(loaded, first_is_plain):
+    c, vecs = loaded
+    cmds, shared = _mixed_wave(_vecs(9, seed=41), first_is_plain)
+    alone = [c.execute(*cmd) for cmd in cmds]
+    before = _wave_counters(c)
+    c.execute("CONFIG", "SET", "trace-enabled", "yes")
+    c.execute("TRACE", "RESET")
+    together = c.execute_many(cmds)
+    spans = _wave_spans(c)
+    c.execute("CONFIG", "SET", "trace-enabled", "no")
+    after = _wave_counters(c)
+
+    def shape(reply):  # a cursor a command: the ids differ by design
+        if reply and isinstance(reply[0], list):
+            return [reply[0], bool(reply[1])]
+        return reply
+
+    assert [shape(r) for r in together] == [shape(r) for r in alone]
+    content = next(i for i, cmd in enumerate(cmds)
+                   if cmd[0] == "FT.SEARCH" and "NOCONTENT" not in cmd)
+    assert b"vector" in together[content][2]
+    assert (after[0] - before[0], after[1] - before[1]) == (len(cmds), shared)
+    assert [name for name, _a in spans] == ["wave.plan", "kernel", "wave.answer"]
+    assert spans[1][1]["members"] == len(cmds) and spans[1][1]["stacked"] == 16
+    assert (spans[2][1]["members"], spans[2][1]["shared"]) == (len(cmds), shared)
+
+
+def test_a_write_between_shared_plan_searches_cuts_the_wave(loaded):
+    c, vecs = loaded
+    probe = (vecs[0] + 1).astype(np.float32)
+    half = [_search_cmd(probe + i, "ix", 3, "LIMIT", "0", "3") for i in range(3)]
+    before = _wave_counters(c)
+    c.execute("CONFIG", "SET", "trace-enabled", "yes")
+    c.execute("TRACE", "RESET")
+    out = c.execute_many(half + [("HSET", "doc:cut", "vector", probe.tobytes())] + half)
+    spans = _wave_spans(c)
+    c.execute("CONFIG", "SET", "trace-enabled", "no")
+    assert all("doc:cut" not in _ids(r) for r in out[:3])
+    assert all(_ids(r)[0] == "doc:cut" for r in out[4:])
+    assert [a["members"] for name, a in spans if name == "kernel"] == [3, 3]
+    assert [a["shared"] for name, a in spans if name == "wave.answer"] == [3, 3]
+    after = _wave_counters(c)
+    assert (after[0] - before[0], after[1] - before[1]) == (6, 6)
+
+
+@pytest.mark.parametrize("blob", [b"", b"12345", b"\x00" * (4 * DIM + 4)],
+                         ids=["empty", "five_bytes", "one_float_more"])
+def test_a_malformed_blob_is_its_members_own_error(loaded, blob):
+    c, vecs = loaded
+    good = [_search_cmd(v + 1, "ix", 3) for v in vecs[:3]]
+    bad = ("FT.SEARCH", "ix", "*=>[KNN 3 @vector $BLOB]", "NOCONTENT",
+           "PARAMS", "2", "BLOB", blob)
+    out = c.execute_many(good[:2] + [bad] + good[2:])
+    assert isinstance(out[2], Exception) and "vector blob" in str(out[2])
+    assert [_ids(r)[0] for r in out[:2] + out[3:]] == ["doc:0", "doc:1", "doc:2"]
+
+
+def test_no_wave_of_either_kind_meets_a_cold_program(loaded):
+    c, vecs = loaded
+    q = _vecs(64, seed=56)
+    c.execute_many([_search_cmd(v, "ix", 4) for v in q[:2]])  # warms every bucket
+    built = K.knn_flat_topk._cache_size()
+    for n in (2, 7, 33, 64):
+        c.execute_many([_search_cmd(v, "ix", 4, "LIMIT", "0", "4") for v in q[:n]])
+    for first_is_plain in (True, False):
+        c.execute_many(_mixed_wave(q[:9], first_is_plain)[0])
+    assert K.knn_flat_topk._cache_size() == built
 
 
 # -- the blocked top-k against the NumPy path ------------------------------------
