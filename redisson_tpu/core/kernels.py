@@ -162,6 +162,45 @@ def rows_counted() -> tuple:
     return _rows_valid, _rows_issued
 
 
+# Point commands: a single-item BF.ADD / BF.EXISTS (server/verbs/sketch.py
+# _point).  Counted by what is done, whatever does it: commands answered by
+# verb, device dispatches issued for them, and count_rows's rule at those
+# dispatches — rows the bytes kernel was handed against rows somebody asked
+# for.  One command a dispatch and one row in a bucket of MIN_BUCKET today;
+# a window of commands formed across connections would count its members
+# here and its one dispatch once.  Always on; METRICS exports the sums
+# (point_cmds_total with point_cmds_bf_add_total / point_cmds_bf_exists_total,
+# point_dispatches_total, point_rows_valid_total, point_rows_issued_total).
+POINT_VERBS = ("BF.ADD", "BF.EXISTS")
+_point_cmds = dict.fromkeys(POINT_VERBS, 0)
+_point_dispatches = 0
+_point_rows_valid = 0
+_point_rows_issued = 0
+
+
+def count_point_dispatch(n: int, issued: int) -> None:
+    """One device dispatch issued for `n` point commands, handed `issued`
+    rows."""
+    global _point_dispatches, _point_rows_valid, _point_rows_issued
+    with _ROWS_LOCK:
+        _point_dispatches += 1
+        _point_rows_valid += n
+        _point_rows_issued += issued
+
+
+def count_point_cmds(verb: str, n: int = 1) -> None:
+    """`n` point commands of `verb` answered."""
+    with _ROWS_LOCK:
+        _point_cmds[verb] += n
+
+
+def point_counted() -> dict:
+    """This process's point-command totals: {"cmds": {verb: n},
+    "dispatches", "rows_valid", "rows_issued"}."""
+    return {"cmds": dict(_point_cmds), "dispatches": _point_dispatches,
+            "rows_valid": _point_rows_valid, "rows_issued": _point_rows_issued}
+
+
 def _map_valid_chunks(rows, n_valid, body):
     """THE one expression of the policy: found[c] = body(*rows[c], n_valid -
     start of c) for the CHUNK-row slices c of `rows` (parallel 1-D arrays of
@@ -244,19 +283,21 @@ bloom_contains_u64_masked = jax.jit(_bloom_contains_body, static_argnums=(4, 5))
 
 @functools.partial(jax.jit, static_argnums=(4, 5), donate_argnums=(0,))
 def bloom_add_bytes_masked(bits, words, nbytes, n_valid, k: int, m: int):
-    h1, h2 = H.hash_packed_bytes(words, nbytes, jnp)
-    idx = H.bloom_indexes(h1, h2, k, m, jnp)
-    mask = _valid_mask(h1.shape[0], n_valid)
-    idx = jnp.where(mask[:, None], idx, bits.shape[0])
-    new_bits, newly = bt.set_and_report(bits, idx)
-    return new_bits, newly & mask
+    with jax.named_scope("bloom_add_bytes_masked"):
+        h1, h2 = H.hash_packed_bytes(words, nbytes, jnp)
+        idx = H.bloom_indexes(h1, h2, k, m, jnp)
+        mask = _valid_mask(h1.shape[0], n_valid)
+        idx = jnp.where(mask[:, None], idx, bits.shape[0])
+        new_bits, newly = bt.set_and_report(bits, idx)
+        return new_bits, newly & mask
 
 
 @functools.partial(jax.jit, static_argnums=(4, 5))
 def bloom_contains_bytes_masked(bits, words, nbytes, n_valid, k: int, m: int):
-    h1, h2 = H.hash_packed_bytes(words, nbytes, jnp)
-    idx = H.bloom_indexes(h1, h2, k, m, jnp)
-    return bt.contains(bits, idx) & _valid_mask(h1.shape[0], n_valid)
+    with jax.named_scope("bloom_contains_bytes_masked"):
+        h1, h2 = H.hash_packed_bytes(words, nbytes, jnp)
+        idx = H.bloom_indexes(h1, h2, k, m, jnp)
+        return bt.contains(bits, idx) & _valid_mask(h1.shape[0], n_valid)
 
 
 # --- multi-tenant bloom bank: (T, m) bit plane, ops carry a tenant row ------

@@ -22,7 +22,12 @@ and each chokepoint it crosses appends a **stage span**:
   ``dispatch``  — handler execution window for the whole frame;
   ``stage``     — device-lane gate wait (queueing ahead of the chip);
   ``kernel``    — ONE span per coalesced same-verb run, its member commands
-                  recorded as ``kernel.member`` child spans;
+                  recorded as ``kernel.member`` child spans; one per
+                  single-item BF.ADD / BF.EXISTS too (``members`` 1);
+  ``point.wait`` — such a point command's plan (its worker job's submit) ->
+                  its device dispatch issued (``verb``): the queue for a
+                  worker and the record's lock.  It lies over ``hop`` and
+                  the head of ``dispatch``, so stage totals leave it out;
   ``readback``  — D2H force, annotated whether the frame PAID the blocking
                   sync (``blocking``) or rode a grouped fetch (``grouped``);
   ``reply``     — dispatch-done -> bytes written: the tail that makes the
@@ -156,10 +161,12 @@ class FrameTrace:
         """{stage: summed µs} — the SLOWLOG breakdown projection (child
         spans excluded: ``kernel.member`` duplicates its kernel span's time,
         the ``reply.*`` children their ``reply`` span's, ``wave.plan`` its
-        kernel span's and ``wave.answer`` its ``reply`` span's)."""
+        kernel span's, ``wave.answer`` its ``reply`` span's, and
+        ``point.wait`` lies over the ``hop`` and the head of ``dispatch``)."""
         out: Dict[str, int] = {}
         for s in self.spans:
-            if s.name.endswith(".member") or s.name.startswith(("reply.", "wave.")):
+            if s.name.endswith(".member") or s.name.startswith(
+                    ("reply.", "wave.", "point.")):
                 continue
             out[s.name] = out.get(s.name, 0) + s.dur_us
         return out

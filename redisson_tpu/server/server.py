@@ -419,6 +419,22 @@ class TpuServer:
         self.metrics.gauge(
             "knn_wave_shared_cmds_total", lambda: _coalesce.knn_wave_counted()[1]
         )
+        # point commands (single-item BF.ADD / BF.EXISTS; always on): answered
+        # by verb, device dispatches issued for them, rows those were handed
+        # against rows asked (core/kernels.py count_point_*)
+        self.metrics.gauge(
+            "point_cmds_total", lambda: sum(_K.point_counted()["cmds"].values())
+        )
+        for verb in _K.POINT_VERBS:
+            self.metrics.gauge(
+                f"point_cmds_{verb.lower()}_total",
+                lambda verb=verb: _K.point_counted()["cmds"][verb],
+            )
+        for series in ("dispatches", "rows_valid", "rows_issued"):
+            self.metrics.gauge(
+                f"point_{series}_total",
+                lambda series=series: _K.point_counted()[series],
+            )
         self.metrics.gauge(
             "gather_bytes_owed_total", lambda: ioplane.gather_bytes_counted()[0]
         )

@@ -5,9 +5,11 @@ verb family, shared preludes in verbs/common.py so numkeys/syntax validation
 cannot diverge between families again.
 """
 
+import time
 from typing import Any, List
 
 from redisson_tpu.net.resp import RespError
+from redisson_tpu.observe import trace as _obs
 from redisson_tpu.server.registry import (
     LazyReply,
     register,
@@ -263,10 +265,37 @@ def cmd_bf_reserve(server, ctx, args):
     return "+OK"
 
 
+def _point(verb: str, dispatch, item: bytes) -> int:
+    """A POINT command — a single-item BF.ADD / BF.EXISTS: `dispatch`
+    (add_each_async / contains_each_async: the bytes kernel issued under the
+    record's lock, nothing fetched) handed one row, then the blocking fetch
+    of its flag.  Counted by what is done, whatever does it
+    (core/kernels.py count_point_*): one command, one dispatch, one row
+    asked of the bucket the kernel walked.  Tracing armed: a `kernel` span
+    (`verb`, `members`: commands this dispatch answers) and `point.wait`,
+    the command's plan (its worker job's submit) -> its dispatch issued —
+    the queue for a worker and the record's lock."""
+    import numpy as np
+
+    from redisson_tpu.core import kernels as K
+
+    cur = _obs.current_trace() if _obs._tracer is not None else None
+    k0 = time.monotonic() if cur is not None else 0.0
+    flags, _n = dispatch([item])
+    K.count_point_dispatch(1, flags.shape[0])
+    if cur is not None:
+        k1 = time.monotonic()
+        cur.add_span("kernel", k0, k1, verb=verb, members=1)
+        cur.add_span("point.wait", cur.hop_at, k1, verb=verb)
+    flag = 1 if np.asarray(flags)[0] else 0
+    K.count_point_cmds(verb)
+    return flag
+
+
 @register("BF.ADD")
 def cmd_bf_add(server, ctx, args):
     bf = _bloom(server, _s(args[0]))
-    return 1 if bf.add(bytes(args[1])) else 0
+    return _point("BF.ADD", bf.add_each_async, bytes(args[1]))
 
 
 @register("BF.MADD")
@@ -279,7 +308,7 @@ def cmd_bf_madd(server, ctx, args):
 @register("BF.EXISTS")
 def cmd_bf_exists(server, ctx, args):
     bf = _bloom(server, _s(args[0]))
-    return 1 if bf.contains(bytes(args[1])) else 0
+    return _point("BF.EXISTS", bf.contains_each_async, bytes(args[1]))
 
 
 @register("BF.MEXISTS")
@@ -296,7 +325,7 @@ def cmd_bf_info(server, ctx, args):
     if rec is None:
         raise RespError("ERR not found")
     return [
-        b"Capacity", rec.meta.get("expected_insertions", 0),
+        b"Capacity", rec.meta.get("n", 0),
         b"Size", rec.meta["m"],
         b"Number of hashes", rec.meta["k"],
         b"Number of items inserted", bf.count(),
