@@ -159,6 +159,37 @@ class BloomFilter(RExpirable):
             self._touch_version(rec)
         return newly, n
 
+    def add_in_order_async(self, items) -> list:
+        """Pipelined add of byte items answered as if they were added ONE AT
+        A TIME, in order: [(device newly-added array, n_valid), ...], a
+        dispatch each, nothing fetched.  add_each_async answers a batch from
+        ONE gather taken before its scatter, so an item whose every unset
+        cell an EARLIER item of the batch sets still reports newly added —
+        right for a caller's own batch, not for commands of different
+        clients answered together (server/verbs/sketch.py point_window).
+        The items' cells are computed here, on the host, and the batch is
+        cut before every item that shares a cell with an earlier one of its
+        run: inside a run no item's answer depends on another's, and the
+        runs are dispatched in order under the record's lock, so the device
+        applies them one after another.  Distinct items hardly ever share a
+        cell: one run, one dispatch."""
+        items = [o if isinstance(o, bytes) else self._codec.encode(o) for o in items]
+        with self._engine.locked(self._name):
+            cuts = [0]
+            if len(items) > 1:
+                rec = self._rec()
+                h1, h2 = H.hash_packed_bytes(*H.pack_keys(items), np)
+                rows = H.bloom_indexes(h1, h2, rec.meta["k"], rec.meta["m"], np)
+                seen: set = set()
+                for at, row in enumerate(rows.tolist()):
+                    if not seen.isdisjoint(row):
+                        cuts.append(at)
+                        seen = set()
+                    seen.update(row)
+            cuts.append(len(items))
+            return [self.add_each_async(items[lo:hi])
+                    for lo, hi in zip(cuts, cuts[1:]) if hi > lo]
+
     def contains(self, obj) -> bool:
         if isinstance(obj, np.ndarray):
             raise TypeError("use contains_each / count_contains for batches")

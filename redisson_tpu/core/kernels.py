@@ -163,12 +163,13 @@ def rows_counted() -> tuple:
 
 
 # Point commands: a single-item BF.ADD / BF.EXISTS (server/verbs/sketch.py
-# _point).  Counted by what is done, whatever does it: commands answered by
-# verb, device dispatches issued for them, and count_rows's rule at those
-# dispatches — rows the bytes kernel was handed against rows somebody asked
-# for.  One command a dispatch and one row in a bucket of MIN_BUCKET today;
-# a window of commands formed across connections would count its members
-# here and its one dispatch once.  Always on; METRICS exports the sums
+# point_window).  Counted by what is done, whatever does it: commands
+# answered by verb, device dispatches issued for them, and count_rows's rule
+# at those dispatches — rows the bytes kernel was handed against rows
+# somebody asked for.  A window of commands formed across connections
+# (server/server.py _join_point_window) counts its members here and each of
+# its dispatches — one a verb — once; a lone command is one command, one
+# dispatch, one row of a bucket of MIN_BUCKET.  Always on; METRICS exports the sums
 # (point_cmds_total with point_cmds_bf_add_total / point_cmds_bf_exists_total,
 # point_dispatches_total, point_rows_valid_total, point_rows_issued_total).
 POINT_VERBS = ("BF.ADD", "BF.EXISTS")

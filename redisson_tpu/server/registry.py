@@ -80,17 +80,23 @@ class Encoded:
         self.data = data
 
 
-def gather_lazy_device_results(lazies: List["LazyReply"]) -> List[tuple]:
+def gather_lazy_device_results(lazies: List["LazyReply"],
+                               traces=None) -> List[tuple]:
     """Fetch every device value of `lazies` with one grouped fetch a device
     — the frame-level gather, THE shared primitive of the overlap plane
     (core/ioplane.gather_device_results): the server's reply path, the
     embedded Batch drain, and bench's A/B harness all force through it, so
-    the fetch discipline cannot diverge between layers."""
+    the fetch discipline cannot diverge between layers.  `traces` (tracing
+    armed): the frames the fetch is made for where they are several — a
+    window of point commands, one frame a member — else the thread's
+    current one."""
     from redisson_tpu.core.ioplane import _is_ready, gather_device_results
 
     if _obs._tracer is not None:
-        cur = _obs.current_trace()
-        if cur is not None:
+        if traces is None:
+            cur = _obs.current_trace()
+            traces = () if cur is None else (cur,)
+        if traces:
             # the frame rode the GROUPED fetch: one span covering the whole
             # gather, annotated whether any member still had to block on
             # device work (vs a pure-transfer ride)
@@ -106,10 +112,12 @@ def gather_lazy_device_results(lazies: List["LazyReply"]) -> List[tuple]:
             )
             # parts: distinct device values the frame owed; fetches: the
             # transfers that brought them; bucket: the widest stack ridden
-            cur.add_span(
-                "readback", t0, _time.monotonic(),
-                grouped=len(lazies), blocking=int(not was_ready), **note,
-            )
+            t1 = _time.monotonic()
+            for tr in traces:
+                tr.add_span(
+                    "readback", t0, t1,
+                    grouped=len(lazies), blocking=int(not was_ready), **note,
+                )
             return out
     return gather_device_results(
         [lz.device for lz in lazies], [lz.owed for lz in lazies]

@@ -46,6 +46,8 @@ from redisson_tpu.core.coalesce import (
     plan_subwindows, plan_waves, serial_plan, stacked_row_bucket, wave_entry,
 )
 from redisson_tpu.core.engine import Engine
+from redisson_tpu.core.kernels import MIN_BUCKET
+from redisson_tpu.net import client as _net
 from redisson_tpu.net import resp
 from redisson_tpu.net.resp import ProtocolError, RespError
 from redisson_tpu.observe import trace as _obs
@@ -151,6 +153,49 @@ def _on_worker(trace, to: str, fn, *args):
         return fn(*args)
     finally:
         _obs.clear_current()
+
+
+# POINT commands — a single-item BF.ADD / BF.EXISTS — by the verb's bytes as
+# clients write them (any other spelling takes the serial path, which ends
+# in the same window function with one member), to the verb as the registry
+# names it and as hooks and counters do.
+_POINT_VERBS = {
+    spelling: (name.encode(), name)
+    for name in ("BF.ADD", "BF.EXISTS")
+    for spelling in (name.encode(), name.lower().encode())
+}
+# the most members one window takes: the smallest bucket pack_keys gives
+# byte items, so a window of any size runs the programs a lone command runs
+_POINT_WINDOW_MAX = MIN_BUCKET
+
+
+class _PointMember:
+    """One point command waiting in its record's open window: the
+    connection, the command, the future its frame awaits on the loop, and
+    its FrameTrace (tracing armed) or None."""
+
+    __slots__ = ("ctx", "cmd", "fut", "trace")
+
+    def __init__(self, ctx, cmd, fut, trace):
+        self.ctx = ctx
+        self.cmd = cmd
+        self.fut = fut
+        self.trace = trace
+
+
+def _resolve_point_window(members, replies, error=None) -> None:
+    """On the loop, ONE call a window: every member's frame goes on with
+    its reply, or dies with `error` (a stopping pool).  A member whose
+    connection went away while it waited (its future cancelled) drops its
+    answer."""
+    if error is not None:
+        for m in members:
+            if not m.fut.done():
+                m.fut.set_exception(error)
+        return
+    for m, reply in zip(members, replies):
+        if not m.fut.done():
+            m.fut.set_result(reply)
 
 
 def _is_slow(cmd) -> bool:
@@ -627,6 +672,11 @@ class TpuServer:
         # commands isBlockingCommand and gives them dedicated connections)
         self._slow_pool = ThreadPoolExecutor(max_workers=64, thread_name_prefix="rtpu-slow")
         self._closing = False
+        # open windows of point commands, by record name as the wire gave
+        # it: the members that joined since the record's ONE job — submitted
+        # or at work — took its window (_join_point_window); no entry, no job
+        self._point_open: Dict[bytes, List[_PointMember]] = {}
+        self._point_lock = threading.Lock()
         # EXEC transactions serialize (see cmd_exec: handlers may take record
         # locks beyond the precomputed key set)
         self._exec_mutex = threading.Lock()
@@ -1309,7 +1359,9 @@ class TpuServer:
     def _dispatch_one(self, ctx, cmd, qos_class: Optional[str] = None,
                       held: bool = False):
         """One command through its handler, whatever it raises a reply
-        (_error_reply).  A serial command is its own worker job: it waits
+        (_error_reply).  A serial command (one of a serial segment's worker
+        job, _dispatch_serial; a frame that is ONE point command does not
+        come here, it joins its record's window: _join_point_window) waits
         at the pause gate and occupies its lane when every key maps to ONE
         device — single-command frames (pipelined blobs bigger than one
         recv chunk arrive one command per parse batch) and transaction
@@ -1336,6 +1388,136 @@ class TpuServer:
         """A serial segment's consecutive commands, each alone and in frame
         order, as one worker job."""
         return [self._dispatch_one(ctx, cmd, qos_class) for cmd in cmds]
+
+    # -- windows of point commands (ISSUE 36) ----------------------------------
+    # A frame that is ONE point command does not go to a worker alone: on
+    # the loop it joins the open window of its record, and ONE worker job a
+    # window answers every member with one dispatch a verb and one fetch.
+    # A record has one job at a time, so no timer and no size: a window is
+    # what arrived while the one before it was served (group commit) — a
+    # lone command on an idle server is taken at once, alone, and 200
+    # waiting connections form windows of a hundred.  The members of a
+    # window are all unanswered, hence concurrent, so any one order of them
+    # is a legal one; it is the probes, then the adds as they arrived
+    # (verbs/sketch.py point_window).  A connection has at most one member
+    # anywhere: its read loop awaits the frame, so its next command is read
+    # after this one's reply.
+
+    def _point_windows_serve(self) -> bool:
+        """Whether this server answers point commands by window: where
+        Registry.dispatch does nothing a command that a window cannot do a
+        member — no placement and no cluster view (routing, lanes), not a
+        replica (READONLY redirects), the fault plane disarmed (its
+        per-command device chokepoint)."""
+        return (
+            self.engine.placement is None and not self.cluster_view
+            and self.role != "replica" and _net._fault_plane is None
+        )
+
+    def _join_point_window(self, ctx, cmd, loop, pool, trace):
+        """On the loop: `cmd`, a point command, joins the open window of
+        its record; where the record has no job, it opens one and submits
+        the job that will take it.  Returns the future its frame awaits."""
+        member = _PointMember(ctx, cmd, loop.create_future(), trace)
+        key = cmd[1]
+        with self._point_lock:
+            waiting = self._point_open.get(key)
+            if waiting is not None:
+                waiting.append(member)
+                return member.fut
+            self._point_open[key] = [member]
+        self._submit_point_job(loop, pool, key)
+        return member.fut
+
+    def _submit_point_job(self, loop, pool, key: bytes) -> None:
+        """The record's next job; from a stopping pool, the end of whoever
+        waits for it."""
+        try:
+            pool.submit(self._serve_point_window, loop, pool, key)
+        except RuntimeError as e:  # nobody will take them
+            with self._point_lock:
+                stranded = self._point_open.pop(key)
+            loop.call_soon_threadsafe(
+                _resolve_point_window, stranded, None, ConnectionResetError(str(e))
+            )
+
+    def _serve_point_window(self, loop, pool, key: bytes) -> None:
+        """THE worker job of a record's point commands: take what is
+        waiting (at most _POINT_WINDOW_MAX members), answer it, hand every
+        member's reply to the loop in ONE call, and — before returning —
+        submit the record's next job if anybody joined meanwhile.  `hop`
+        closes here, where the job takes the member; `dispatch` is taken ->
+        answered."""
+        self._await_resume()
+        with self._point_lock:
+            waiting = self._point_open[key]
+            members = waiting[:_POINT_WINDOW_MAX]
+            del waiting[:_POINT_WINDOW_MAX]
+        traced = [m.trace for m in members if m.trace is not None]
+        for tr in traced:
+            tr.hopped("dispatch")
+        t_taken = time.monotonic()
+        replies, error = None, ConnectionResetError("the window's job died")
+        try:
+            replies = self._answer_point_window(key, members)
+            error = None
+        except Exception as e:  # noqa: BLE001 — a stopping pool: the frames die, as a serial command's does
+            error = e if isinstance(e, ConnectionResetError) else ConnectionResetError(repr(e))
+        finally:
+            # whatever ended the window, its members are answered and the
+            # record is let go of (or handed to its next job)
+            t_done = time.monotonic()
+            for tr in traced:
+                tr.add_span("dispatch", t_taken, t_done)
+            loop.call_soon_threadsafe(_resolve_point_window, members, replies, error)
+            with self._point_lock:
+                if not waiting:
+                    del self._point_open[key]
+            if waiting:  # only a job takes members away: still there
+                self._submit_point_job(loop, pool, key)
+
+    def _answer_point_window(self, key: bytes, members) -> list:
+        """One window's replies, a member each: what Registry.dispatch does
+        a command done a member — tracking (reads registered before the
+        dispatch, writes invalidated after it, also where it failed:
+        possibly applied), the command hooks (INFO commandstats counts
+        every member) — around ONE call of verbs/sketch.py point_window.  A
+        window that raises answers every member what a refused serial
+        command answers (_error_reply) and dispatches nothing again."""
+        from redisson_tpu.server.verbs.sketch import point_window
+        from redisson_tpu.utils.metrics import run_hooks_end, run_hooks_start
+
+        if not self._point_windows_serve():
+            # the server changed while they waited (a cluster view, the
+            # fault plane armed): each goes the way a command goes
+            return [self._dispatch_one(m.ctx, m.cmd) for m in members]
+        verbs = [_POINT_VERBS[m.cmd[0]] for m in members]
+        track = self.tracking if self.tracking.active else None
+        hooks = self.hooks
+        tokens, error = [], None
+        try:
+            for m, (verb, name) in zip(members, verbs):
+                if track is not None:
+                    track.pre_dispatch(m.ctx, verb, m.cmd[1:])
+                if hooks:
+                    tokens.append(run_hooks_start(hooks, name, m.cmd[1:]))
+            replies = point_window(
+                self, key.decode(), [name for _v, name in verbs],
+                [m.cmd[2] for m in members], [m.trace for m in members],
+            )
+        except Exception as e:  # noqa: BLE001 — the window's, so every member's
+            error = e
+        for tok, (_verb, name) in zip(tokens, verbs):
+            run_hooks_end(tok, name, error)
+        if track is not None:
+            for m, (verb, _name) in zip(members, verbs):
+                try:
+                    track.post_dispatch(m.ctx, verb, m.cmd[1:])
+                except Exception as e:  # noqa: BLE001 — never mask the primary error
+                    error = error or e
+        if error is not None:
+            replies = [self._error_reply(error, len(members))] * len(members)
+        return replies
 
     def _fused_add_error_invalidate(self, track, run_names) -> None:
         """A failed fused BF.MADD64 run may have PARTIALLY applied (that is
@@ -1747,8 +1929,10 @@ class TpuServer:
         says what a plan is).  A connection in a state the grouped
         dispatchers do not serve — inside MULTI, unauthenticated, ASKING —
         gets the serial plan, as does a frame of one command with no
-        placement (nothing to group: every frame of a bulk flush) and a
-        frame whose planning failed: planning must never break a frame."""
+        placement (nothing to group WITHIN the frame: every frame of a
+        bulk flush; a point command has left for its record's window
+        before any plan, _run_frame) and a frame whose planning failed:
+        planning must never break a frame."""
         placement = self.engine.placement
         if (
             (len(commands) > 1 or placement is not None)
@@ -1784,9 +1968,13 @@ class TpuServer:
         return LazyReply — device work enqueued, NOT
         forced: the frame's lazies are forced together afterwards
         (_finish_frame), one device->host sync a frame and lane instead of
-        one a command.  A 'serial' segment runs its commands in frame
-        order as barriers, consecutive fast ones as one worker job
-        (_dispatch_serial); a 'buckets' segment fans its
+        one a command.  A frame that is ONE point command (a single-item
+        BF.ADD / BF.EXISTS) on a connection and a server the grouped paths
+        serve is not planned: it joins the open window of its record and
+        is answered with the other connections' commands waiting there, by
+        one worker job (_join_point_window).  A 'serial' segment runs its
+        commands in frame order as barriers, consecutive fast ones as one
+        worker job (_dispatch_serial); a 'buckets' segment fans its
         per-lane buckets out on the worker pool CONCURRENTLY (each bucket
         FIFO on its device lane — per-key order is preserved because a key
         maps to exactly one device)."""
@@ -1807,7 +1995,31 @@ class TpuServer:
                 if refused:
                     results[i] = shed
         pool = self._pool_for(adm)
-        for seg_kind, seg in self._plan_frame(ctx, commands, shed_mask):
+        plan = None
+        if len(commands) == 1:
+            # a frame of ONE command (every frame of a bulk flush is one):
+            # a point command joins its record's window, anything else is
+            # planned as it always was
+            cmd = commands[0]
+            if (
+                type(cmd) is list and len(cmd) == 3
+                and type(cmd[0]) is bytes and cmd[0] in _POINT_VERBS
+                and results[0] is None  # not shed
+                and ctx.authenticated and ctx.multi_queue is None
+                and not ctx.asking
+                and type(cmd[1]) is bytes and type(cmd[2]) is bytes
+                and self._point_windows_serve()
+            ):
+                self.stats["commands"] += 1
+                if trace is not None:
+                    trace.hop_at = time.monotonic()
+                results[0] = await self._join_point_window(
+                    ctx, cmd, loop, pool, trace
+                )
+                plan = ()
+        if plan is None:
+            plan = self._plan_frame(ctx, commands, shed_mask)
+        for seg_kind, seg in plan:
             if seg_kind == "serial":
                 self.stats["commands"] += len(seg)
                 # consecutive fast commands are ONE worker job, run in frame

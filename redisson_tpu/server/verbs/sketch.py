@@ -265,37 +265,85 @@ def cmd_bf_reserve(server, ctx, args):
     return "+OK"
 
 
-def _point(verb: str, dispatch, item: bytes) -> int:
-    """A POINT command — a single-item BF.ADD / BF.EXISTS: `dispatch`
-    (add_each_async / contains_each_async: the bytes kernel issued under the
-    record's lock, nothing fetched) handed one row, then the blocking fetch
-    of its flag.  Counted by what is done, whatever does it
-    (core/kernels.py count_point_*): one command, one dispatch, one row
-    asked of the bucket the kernel walked.  Tracing armed: a `kernel` span
-    (`verb`, `members`: commands this dispatch answers) and `point.wait`,
-    the command's plan (its worker job's submit) -> its dispatch issued —
-    the queue for a worker and the record's lock."""
-    import numpy as np
+def point_window(server, name: str, verbs, items, traces=None) -> List[int]:
+    """A WINDOW of point commands — single-item BF.ADD / BF.EXISTS of one
+    record `name`: member i is `verbs[i]` ("BF.ADD" / "BF.EXISTS") of
+    `items[i]` — answered together.  The members are the commands of
+    different connections that were waiting when a worker took the window
+    (server/server.py _serve_point_window), or the ONE command
+    Registry.dispatch handed cmd_bf_add / cmd_bf_exists (a multi-command
+    frame, MULTI/EXEC, cluster mode): the same code, a window of one.
 
+    The answers are those of ONE one-at-a-time execution of the members:
+    the probes, then the adds in the order given.  One BloomFilter a
+    window; ONE pack and ONE dispatch of the probes' items
+    (contains_each_async: bloom_contains_bytes_masked under the record's
+    lock), ONE of the adds' (add_in_order_async: bloom_add_bytes_masked,
+    cut only where an add shares a cell with an earlier one, so that
+    `newly` is what the serial order gives); every flags array fetched by
+    ONE grouped fetch (registry.gather_lazy_device_results).  Nothing is
+    answered before the adds are applied under the record's lock and the
+    flags are on the host, and a dispatch is never issued twice: a window
+    that raises has answered nobody.
+
+    Counted by what is done (core/kernels.py count_point_*): a dispatch
+    and the rows it was handed when it is issued, the commands by verb once
+    they are answered.  Tracing armed (`traces`: a FrameTrace or None a
+    member; None = the thread's current one for each): a `kernel` span a
+    member (`verb`, `members`: the commands its verb's dispatch answered),
+    `point.wait` from the member's submit (`hop_at`) to that dispatch
+    issued, and the fetch's `readback`."""
+    from redisson_tpu.core import ioplane
     from redisson_tpu.core import kernels as K
+    from redisson_tpu.server.registry import gather_lazy_device_results
 
-    cur = _obs.current_trace() if _obs._tracer is not None else None
-    k0 = time.monotonic() if cur is not None else 0.0
-    flags, _n = dispatch([item])
-    K.count_point_dispatch(1, flags.shape[0])
-    if cur is not None:
-        k1 = time.monotonic()
-        cur.add_span("kernel", k0, k1, verb=verb, members=1)
-        cur.add_span("point.wait", cur.hop_at, k1, verb=verb)
-    flag = 1 if np.asarray(flags)[0] else 0
-    K.count_point_cmds(verb)
-    return flag
+    if traces is None and _obs._tracer is not None:
+        traces = [_obs.current_trace()] * len(items)
+    traced = [t for t in traces if t is not None] if traces else ()
+    bf = _bloom(server, name)
+    issued = []  # (device flags, the members they answer, row for row)
+    asked = []   # (verb, how many members)
+    for verb, dispatch in (
+        ("BF.EXISTS", lambda its: [bf.contains_each_async(its)]),
+        ("BF.ADD", bf.add_in_order_async),
+    ):
+        members = [i for i, v in enumerate(verbs) if v == verb]
+        if not members:
+            continue
+        asked.append((verb, len(members)))
+        k0 = time.monotonic() if traced else 0.0
+        lo = 0
+        for flags, n in dispatch([items[i] for i in members]):
+            K.count_point_dispatch(n, flags.shape[0])
+            issued.append((flags, members[lo:lo + n]))
+            lo += n
+        if traced:
+            k1 = time.monotonic()
+            for i in members:
+                tr = traces[i]
+                if tr is not None:
+                    tr.add_span("kernel", k0, k1, verb=verb, members=len(members))
+                    tr.add_span("point.wait", tr.hop_at, k1, verb=verb)
+    # which windows are mixed is the traffic's composition: the programs
+    # that stack two flags arrays for the fetch are compiled with the first
+    # window served, not with the first mixed one
+    ioplane.warm_stack_class(issued[0][0])
+    fetched = gather_lazy_device_results(
+        [LazyReply(device=(flags,), owed=len(members)) for flags, members in issued],
+        traced,
+    )
+    answers = [0] * len(items)
+    for (_flags, members), (host,) in zip(issued, fetched):
+        for i, flag in zip(members, host[:len(members)].tolist()):
+            answers[i] = 1 if flag else 0
+    for verb, n in asked:
+        K.count_point_cmds(verb, n)
+    return answers
 
 
 @register("BF.ADD")
 def cmd_bf_add(server, ctx, args):
-    bf = _bloom(server, _s(args[0]))
-    return _point("BF.ADD", bf.add_each_async, bytes(args[1]))
+    return point_window(server, _s(args[0]), ("BF.ADD",), (bytes(args[1]),))[0]
 
 
 @register("BF.MADD")
@@ -307,8 +355,7 @@ def cmd_bf_madd(server, ctx, args):
 
 @register("BF.EXISTS")
 def cmd_bf_exists(server, ctx, args):
-    bf = _bloom(server, _s(args[0]))
-    return _point("BF.EXISTS", bf.contains_each_async, bytes(args[1]))
+    return point_window(server, _s(args[0]), ("BF.EXISTS",), (bytes(args[1]),))[0]
 
 
 @register("BF.MEXISTS")

@@ -12,15 +12,34 @@ Contracts pinned here:
     server;
   * the always-on counters count what they say: ``point_cmds`` the commands
     answered (by verb), ``point_dispatches`` the device dispatches issued for
-    them (one a command on the path that stands), rows valid <= rows issued
-    (one row asked of a bucket of ``MIN_BUCKET``);
+    them, rows valid <= rows issued (the rows asked of a bucket of
+    ``MIN_BUCKET``);
   * with tracing armed a point command's frame carries a ``kernel`` span
     with ``verb`` and ``members`` and a ``point.wait`` span that ends where
     the ``kernel`` span ends; stage totals leave ``point.wait`` out;
   * the two bytes kernels carry their ``jax.named_scope``;
   * ``BF.INFO`` reports the capacity the filter was reserved with.
+
+ISSUE 36: point commands of different connections are answered as one
+WINDOW — what was waiting for its record when a worker took the job — with
+one dispatch a verb and one fetch.  Pinned here (``_window`` makes a window
+on purpose: the server paused, the commands sent and joined, then resumed):
+  * a window's answers are those of one one-at-a-time execution, the probes
+    then the adds: a probe of an item the same window adds sees the plane
+    before it; of two adds that share every unset cell exactly one reports
+    newly added; an acknowledged add is seen by every later probe;
+  * a lone command on an idle server is a window of one: one dispatch of
+    one valid row; a connection never has two members in a window, and
+    MULTI/EXEC and multi-command frames answer as before;
+  * every member counts in ``INFO commandstats`` and ``stats["commands"]``,
+    a tracking client is invalidated by a windowed ``BF.ADD``;
+  * a window that raises answers every member an error and dispatches
+    nothing twice; armed, every member's frame carries ``hop``,
+    ``dispatch``, ``kernel`` (``members`` = its verb's share of the window),
+    ``point.wait`` and ``readback``.
 """
 import threading
+import time
 
 import jax.numpy as jnp
 import numpy as np
@@ -30,7 +49,9 @@ from benchmark import loadgen
 from benchmark import reference_bf as R
 from benchmark.generators import memtier_bf as G
 from redisson_tpu.core import kernels as K
+from redisson_tpu.client.objects.bloom import BloomFilter
 from redisson_tpu.net.client import Connection
+from redisson_tpu.net.resp import RespError
 from redisson_tpu.observe import trace as obs
 from redisson_tpu.server.server import ServerThread
 
@@ -154,9 +175,10 @@ def test_counters_count_what_they_say(fleet):
     adds = PARAMS["connections"] * (PER_CONN // 11 + 1)
     assert c["cmds"] == sent and c["cmds_bf_add"] == adds
     assert c["cmds_bf_exists"] == sent - adds
-    assert c["dispatches"] == c["cmds"]  # one a command, on the path that stands
-    assert c["rows_valid"] == sent and c["rows_issued"] == sent * K.MIN_BUCKET
-    assert c["rows_valid"] <= c["rows_issued"]
+    # 32 connections against four workers: windows form (a window of both
+    # verbs is two dispatches), and each hands its kernel one bucket
+    assert c["dispatches"] < c["cmds"]
+    assert c["rows_valid"] == sent and c["rows_issued"] == c["dispatches"] * K.MIN_BUCKET
 
 
 @pytest.mark.parametrize("verb", ["BF.ADD", "BF.EXISTS"])
@@ -176,6 +198,323 @@ def test_the_batch_forms_count_nothing(conn):
     assert conn.execute("BF.MADD", "cnt:batch", b"a", b"b") == [1, 1]
     assert conn.execute("BF.MEXISTS", "cnt:batch", b"a", b"c") == [1, 0]
     assert _delta(_metrics(conn), before) == dict.fromkeys(before, 0)
+
+
+def _window(server, sends):
+    """``sends`` — [(connection, command)], a connection once — answered as
+    ONE window: while the server is paused its jobs park before they take
+    anything, so every command sent joins the window the first one opened;
+    then it resumes.  The replies, in ``sends``' order."""
+    srv = server.server
+    base = srv.stats["commands"]
+    srv.pause()
+    try:
+        for c, cmd in sends:
+            c.send(*cmd)
+        deadline = time.monotonic() + 60.0
+        while srv.stats["commands"] - base < len(sends):  # counted as it joins
+            assert time.monotonic() < deadline, "the commands never joined"
+            time.sleep(0.001)
+    finally:
+        srv.resume()
+    return [c.read_reply() for c, _cmd in sends]
+
+
+@pytest.fixture()
+def conns(server):
+    cs = [Connection(server.server.host, server.server.port, timeout=60.0)
+          for _ in range(8)]
+    yield cs
+    for c in cs:
+        c.close()
+
+
+def test_a_window_is_the_probes_then_the_adds(server, conns):
+    """Rounds of one window each, eight connections: adds of new items,
+    probes of items earlier rounds added, of items THIS window adds (they
+    see the plane before it) and of strangers — each reply what the
+    reference says, one at a time, in that order."""
+    m, k = R.optimal_m(20000, 0.01), 7
+    conns[0].execute("BF.RESERVE", "win:order", "0.01", 20000)
+    ref = R.RefFilter(m, k)
+    before = _metrics(conns[0])
+    item = lambda n: b"win-%d" % n  # noqa: E731
+    sent = 0
+    for r in range(12):
+        new = [item(100 * r + j) for j in range(3)]
+        old = [item(100 * (r - 1) + j) for j in range(2)] if r else [item(7_000_000)]
+        cmds = ([("BF.ADD", "win:order", it) for it in new]
+                + [("BF.EXISTS", "win:order", it) for it in old]
+                + [("BF.EXISTS", "win:order", new[0]), ("BF.EXISTS", "win:order", new[2])])
+        cmds = cmds[r % len(cmds):] + cmds[:r % len(cmds)]  # who arrives first differs
+        cmds = cmds[:len(conns)]
+        got = _window(server, list(zip(conns, cmds)))
+        probes = [c[2] for c in cmds if c[0] == "BF.EXISTS"]
+        want = dict(zip(probes, ref.contains(*R.pack(probes)).tolist()))
+        rows, nbytes = R.pack(new)
+        cells = ref.indexes(rows, nbytes)
+        assert len(np.unique(cells)) == cells.size  # no two of the round's adds meet
+        newly = {it: ref.add(rows[j], int(nbytes[j])) for j, it in enumerate(new)}
+        for (verb, _name, it), answer in zip(cmds, got):
+            expected = want[it] if verb == "BF.EXISTS" else newly[it]
+            assert answer == int(expected), (r, verb, it)
+        sent += len(cmds)
+    d = _delta(_metrics(conns[0]), before)
+    assert d["cmds"] == sent == d["rows_valid"]
+    assert d["dispatches"] == 2 * 12  # a dispatch a verb a window, whatever its size
+
+
+def _meeting_pair(m: int, k: int):
+    """Two different items with a cell in common, and the cells only one of
+    them has."""
+    ref = R.RefFilter(m, k)
+    items = [b"pair-%d" % n for n in range(4000)]
+    cells = ref.indexes(*R.pack(items))
+    owner = {}
+    for i, row in enumerate(cells.tolist()):
+        for cell in row:
+            j = owner.setdefault(cell, i)
+            if j != i:
+                a, b = set(cells[j].tolist()), set(row)
+                return items[j], items[i], sorted(a ^ b)
+    raise AssertionError("no two items share a cell")
+
+
+@pytest.mark.parametrize("pair", ["the same item", "two items that share every unset cell"])
+def test_of_two_adds_that_meet_exactly_one_is_new(server, conns, pair):
+    """Two connections' adds answered together: the second of them, in
+    whichever order the window took them, finds every cell set.  The
+    batched kernel alone would answer 1 to both."""
+    m, k = R.optimal_m(2000, 0.01), 7
+    eng = server.server.engine
+    a, b, only_one = _meeting_pair(m, k)
+    ones = 0
+    for r in range(24):
+        name = f"win:meet:{pair[:8]}:{r}"
+        conns[0].execute("BF.RESERVE", name, "0.01", 2000)
+        if pair == "the same item":
+            first = second = b"same-%d" % r
+        else:
+            first, second = (a, b) if r % 2 else (b, a)
+            with eng.locked(name):  # what is left unset of either is what both have
+                rec = eng.store.get(name)
+                rec.arrays["bits"] = rec.arrays["bits"].at[jnp.asarray(only_one)].set(1)
+        sends = [(conns[0], ("BF.ADD", name, first)), (conns[1], ("BF.ADD", name, second))]
+        if r < 12:
+            got = _window(server, sends)
+        else:  # and free-running: met in a window or not, the answer stands
+            out = [None, None]
+            threads = [threading.Thread(
+                target=lambda i=i, c=c, cmd=cmd: out.__setitem__(i, c.execute(*cmd)))
+                for i, (c, cmd) in enumerate(sends)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join()
+            got = out
+        assert sorted(got) == [0, 1], (r, got)
+        assert conns[2].execute("BF.MEXISTS", name, first, second) == [1, 1]
+        ones += sum(got)
+    assert ones == 24
+
+
+def test_an_acknowledged_add_is_seen_by_every_later_probe(server, conns):
+    conns[0].execute("BF.RESERVE", "win:seen", "0.01", 20000)
+    stop = threading.Event()
+
+    def company(c, base):  # other connections keep windows forming
+        n = 0
+        while not stop.is_set():
+            c.execute("BF.EXISTS" if n % 4 else "BF.ADD", "win:seen", b"other-%d" % (base + n))
+            n += 1
+
+    threads = [threading.Thread(target=company, args=(c, 10_000 * i))
+               for i, c in enumerate(conns[2:])]
+    for t in threads:
+        t.start()
+    try:
+        for r in range(100):
+            assert conns[0].execute("BF.ADD", "win:seen", b"seen-%d" % r) in (0, 1)
+            assert conns[1].execute("BF.EXISTS", "win:seen", b"seen-%d" % r) == 1, r
+    finally:
+        stop.set()
+        for t in threads:
+            t.join()
+
+
+def test_a_lone_command_is_a_window_of_one_and_waits_for_nobody(server, conn):
+    """Nothing holds a command for company: with nobody else connected it is
+    answered — one dispatch of one valid row — and no window stays open."""
+    conn.execute("BF.RESERVE", "win:lone", "0.01", 1000)
+    before = _metrics(conn)
+    assert [conn.execute("BF.ADD", "win:lone", b"a"), conn.execute("BF.EXISTS", "win:lone", b"a"),
+            conn.execute("bf.exists", "win:lone", b"b"), conn.execute("Bf.Add", "win:lone", b"a")] \
+        == [1, 1, 0, 0]
+    d = _delta(_metrics(conn), before)
+    assert (d["cmds"], d["dispatches"], d["rows_valid"]) == (4, 4, 4)
+    assert d["rows_issued"] == 4 * K.MIN_BUCKET
+    deadline = time.monotonic() + 30.0  # the job lets go of the record after it answered
+    while server.server._point_open and time.monotonic() < deadline:
+        time.sleep(0.001)
+    assert server.server._point_open == {}
+
+
+def test_a_connection_never_has_two_members_in_a_window(server, conn):
+    """One connection writing single commands ahead of its replies: they are
+    answered in the order written, each by a dispatch of its own — the next
+    is taken only when the one before it is answered."""
+    conn.execute("BF.RESERVE", "win:pipe", "0.01", 1000)
+    cmds = [("BF.ADD" if i % 3 == 0 else "BF.EXISTS", "win:pipe", b"p-%d" % (i // 3 * 3))
+            for i in range(30)]
+    want = [1 if c[0] == "BF.ADD" else 1 for c in cmds]  # an add, then two probes of it
+    before = _metrics(conn)
+    for c in cmds:  # a send a command: frames of one, or of what had landed
+        conn.send(*c)
+    assert [conn.read_reply() for _ in cmds] == want
+    assert conn.execute_many(cmds) == [0 if c[0] == "BF.ADD" else 1 for c in cmds]  # one frame
+    d = _delta(_metrics(conn), before)
+    assert d["cmds"] == d["dispatches"] == d["rows_valid"] == 60
+
+
+def test_multi_exec_and_mixed_frames_answer_as_before(server, conn):
+    conn.execute("BF.RESERVE", "win:tx", "0.01", 1000)
+    assert conn.execute_many([("MULTI",), ("BF.ADD", "win:tx", b"t"), ("BF.EXISTS", "win:tx", b"t"),
+                              ("BF.EXISTS", "win:tx", b"u"), ("EXEC",)]) \
+        == [b"OK", b"QUEUED", b"QUEUED", b"QUEUED", [1, 1, 0]]
+    assert conn.execute("MULTI") == b"OK"
+    assert conn.execute("BF.ADD", "win:tx", b"v") == b"QUEUED"  # a frame of one, inside MULTI
+    assert conn.execute("EXEC") == [1]
+    assert conn.execute_many([("PING",), ("BF.EXISTS", "win:tx", b"v"), ("BF.MADD", "win:tx", b"w"),
+                              ("BF.ADD", "win:tx", b"w"), ("BF.EXISTS", "win:nofilter", b"w")])[:4] \
+        == [b"PONG", 1, [1], 0]
+    missing = conn.execute("BF.EXISTS", "win:nofilter", b"w")
+    assert isinstance(missing, RespError) and "not initialized" in str(missing)
+
+
+def test_no_member_is_lost_under_a_short_switch_interval(server):
+    """The open windows are shared by the loop and the workers: 24
+    connections on three records, the interpreter switching threads every
+    10 us — every command is answered, counted once, and no window stays
+    open."""
+    import sys
+
+    admin = Connection(server.server.host, server.server.port, timeout=60.0)
+    for r in range(3):
+        admin.execute("BF.RESERVE", f"win:stress:{r}", "0.01", 20000)
+    before = _metrics(admin)
+    answered, errors = [0] * 24, []
+
+    def run(i):
+        c = Connection(server.server.host, server.server.port, timeout=60.0)
+        try:
+            for n in range(60):
+                verb = "BF.ADD" if n % 5 == 0 else "BF.EXISTS"
+                reply = c.execute(verb, f"win:stress:{i % 3}", b"s-%d-%d" % (i, n // 5 * 5))
+                assert reply == 1, (i, n, reply)  # an add of a new item, then probes of it
+                answered[i] += 1
+        except Exception as e:  # noqa: BLE001 — shown by the test
+            errors.append(repr(e))
+        finally:
+            c.close()
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=run, args=(i,)) for i in range(24)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120.0)
+        assert not any(t.is_alive() for t in threads), "a command was never answered"
+    finally:
+        sys.setswitchinterval(was)
+    assert errors == [] and answered == [60] * 24
+    d = _delta(_metrics(admin), before)
+    assert d["cmds"] == d["rows_valid"] == 24 * 60 and d["dispatches"] <= d["cmds"]
+    deadline = time.monotonic() + 30.0
+    while server.server._point_open and time.monotonic() < deadline:
+        time.sleep(0.001)
+    assert server.server._point_open == {}
+    admin.close()
+
+
+def _calls(c, verb: str) -> int:
+    for line in bytes(c.execute("INFO", "commandstats")).decode().splitlines():
+        if line.startswith(f"cmdstat_{verb.lower()}:"):
+            return int(line.split("calls=")[1].split(",")[0])
+    return 0
+
+
+def test_every_member_is_counted_and_a_tracked_reader_is_invalidated(server, conns):
+    srv = server.server
+    conns[0].execute("BF.RESERVE", "win:stats", "0.01", 1000)
+    pushes = []
+    conns[7].push_handler = pushes.append
+    conns[7].execute("CLIENT", "TRACKING", "ON")
+    assert conns[7].execute("BF.EXISTS", "win:stats", b"s-1") == 0  # a tracked read
+    calls = {v: _calls(conns[0], v) for v in ("BF.ADD", "BF.EXISTS")}
+    commands = srv.stats["commands"]
+    cmds = [("BF.ADD", "win:stats", b"s-%d" % i) for i in range(2)] + \
+           [("BF.EXISTS", "win:stats", b"s-%d" % i) for i in range(4)]
+    assert _window(server, list(zip(conns, cmds))) == [1, 1, 0, 0, 0, 0]
+    assert srv.stats["commands"] - commands == 6
+    assert _calls(conns[0], "BF.ADD") - calls["BF.ADD"] == 2
+    assert _calls(conns[0], "BF.EXISTS") - calls["BF.EXISTS"] == 4
+    conns[7].execute("PING")  # drains the push queued ahead of the reply
+    assert pushes and bytes(pushes[0][0]) == b"invalidate" and pushes[0][1] == [b"win:stats"]
+
+
+def test_a_window_that_raises_answers_every_member_and_dispatches_once(server, conns, monkeypatch):
+    srv = server.server
+    conns[0].execute("BF.RESERVE", "win:fail", "0.01", 1000)
+    issued = []
+
+    def failing(self, items):
+        issued.append(list(items))
+        raise RuntimeError("the adds' dispatch failed")
+
+    monkeypatch.setattr(BloomFilter, "add_in_order_async", failing)
+    errors = srv.stats["errors"]
+    cmds = [("BF.EXISTS", "win:fail", b"f-0"), ("BF.ADD", "win:fail", b"f-1"),
+            ("BF.ADD", "win:fail", b"f-2"), ("BF.EXISTS", "win:fail", b"f-3")]
+    got = _window(server, list(zip(conns, cmds)))
+    assert all(isinstance(r, RespError) and "the adds' dispatch failed" in str(r) for r in got)
+    assert len(issued) == 1 and sorted(issued[0]) == [b"f-1", b"f-2"]  # never issued again
+    assert srv.stats["errors"] - errors == 4
+    monkeypatch.undo()
+    assert conns[0].execute("BF.MEXISTS", "win:fail", b"f-1", b"f-2") == [0, 0]
+    assert conns[1].execute("BF.ADD", "win:fail", b"f-1") == 1  # the connections live on
+
+
+def test_every_member_of_a_window_has_its_spans(server, conns):
+    conns[0].execute("BF.RESERVE", "win:trace", "0.01", 1000)
+    cmds = [("BF.EXISTS", "win:trace", b"t-%d" % i) for i in range(5)] + \
+           [("BF.ADD", "win:trace", b"t-%d" % i) for i in range(2)]
+    admin = conns[7]
+    admin.execute("CONFIG", "SET", "trace-enabled", "yes")
+    try:
+        admin.execute("TRACE", "RESET")
+        assert _window(server, list(zip(conns, cmds))) == [0] * 5 + [1] * 2
+        frames = _point_spans(admin)
+    finally:
+        admin.execute("CONFIG", "SET", "trace-enabled", "no")
+    assert sorted(v for v, _named in frames) == sorted(c[0] for c in cmds)
+    for verb, named in frames:
+        assert {"hop", "dispatch", "kernel", "point.wait", "readback"} <= set(named)
+        (hop,), (dispatch,), (kernel,), (wait,), (readback,) = (
+            named[n] for n in ("hop", "dispatch", "kernel", "point.wait", "readback"))
+        assert bytes(kernel["verb"]).decode() == verb == bytes(wait["verb"]).decode()
+        assert kernel["members"] == (5 if verb == "BF.EXISTS" else 2)
+        # submitted -> taken (the pause: not nothing), taken -> answered, and
+        # inside that the member's dispatch issued and the window's one fetch
+        assert hop["dur"] > 0 and bytes(hop["to"]) == b"dispatch"
+        assert hop["off"] + hop["dur"] <= dispatch["off"] + 2
+        for inner in (kernel, readback):
+            assert dispatch["off"] <= inner["off"] + 2
+            assert inner["off"] + inner["dur"] <= dispatch["off"] + dispatch["dur"] + 2
+        assert abs(wait["off"] - hop["off"]) <= 2
+        assert abs((wait["off"] + wait["dur"]) - (kernel["off"] + kernel["dur"])) <= 2
+        assert readback["grouped"] == 2 and readback["parts"] == 2  # both flags arrays, one fetch
 
 
 def _point_spans(c):
