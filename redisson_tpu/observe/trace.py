@@ -19,11 +19,17 @@ and each chokepoint it crosses appends a **stage span**:
   ``hop``       — ``run_in_executor`` submit -> the worker's first line
                   (``to`` = ``dispatch`` or ``force``): the queue for a
                   pool thread plus the thread switch;
+  ``wake``      — the mirror image of ``hop``: a worker's last line -> the
+                  first line of the frame's coroutine after its ``await``
+                  (``frm`` = ``dispatch`` or ``force``): ``call_soon_threadsafe``
+                  plus the turns the loop took to come round.  A ``hop`` says
+                  how long a worker was waited for, a ``wake`` how long the
+                  loop was;
   ``dispatch``  — handler execution window for the whole frame;
   ``stage``     — device-lane gate wait (queueing ahead of the chip);
-  ``kernel``    — ONE span per coalesced same-verb run, its member commands
-                  recorded as ``kernel.member`` child spans; one per
-                  single-item BF.ADD / BF.EXISTS too (``members`` 1);
+  ``kernel``    — ONE span per coalesced same-verb run (``members``, and
+                  ``keys``: its first 32 members' keys, comma-joined); one
+                  per single-item BF.ADD / BF.EXISTS too (``members`` 1);
   ``point.wait`` — such a point command's plan (its worker job's submit) ->
                   its device dispatch issued (``verb``): the queue for a
                   worker and the record's lock.  It lies over ``hop`` and
@@ -34,6 +40,8 @@ and each chokepoint it crosses appends a **stage span**:
                   trace total the true client-observable latency.  Its
                   children say what the tail was: ``reply.wait`` (for the
                   overlapped readback future, or in the writer's queue),
+                  ``reply.wake`` (inside that wait: the force job's last
+                  line -> the writer task has its result),
                   ``reply.encode`` and ``reply.write`` (``write`` + ``drain``;
                   ``nbytes``, ``batch`` = frames in that one write);
   ``host.gc`` / ``host.stall`` — on SLOW frames only (total at or over the
@@ -49,11 +57,23 @@ HISTORY``; per-stage duration timers feed the server's MetricsRegistry so
 
 **Host events** ride the same clock: while armed, a small bounded ring
 records every garbage collection of a millisecond or more (``gc.callbacks``,
-``gen`` annotated) and every time the server's event loop woke 5 ms or more
-late (``stall``: a heartbeat task on the loop — a GC, a worker holding the
-GIL, a long synchronous call).  ``TRACE EVENTS`` lists the ring, four
-monotone ``host_*`` totals ride METRICS, and a slow frame is annotated with
-the events it overlapped — what an operator asks of a slow log.
+``gen`` annotated) and every turn of the server's event loop that lasted
+5 ms or more (``stall``: for that long no socket was read and no hand-off
+taken — a GC, a worker holding the GIL, a long synchronous call, or simply
+more callbacks than a turn should carry; found where the loop selects,
+``LoopSelector``, so a server inside somebody else's loop records none; a
+pause that begins while the loop sleeps in ``select`` is sleep to that
+clock — a collection on a worker thread is in the ring as ``gc``).
+``TRACE EVENTS`` lists the ring, four monotone ``host_*`` totals ride
+METRICS, and a slow frame is annotated with the events it overlapped — what
+an operator asks of a slow log.
+
+**The event loop's own account** is not tracing and is always on
+(``LoopSelector``, below): the loop's turns and the seconds it spent outside
+``select`` — METRICS ``host_loop_turns_total``,
+``host_loop_busy_seconds_total`` — beside the CPU clocks of the loop's
+thread, the pools' threads and the process, read at the scrape
+(``thread_cpu_s``).
 
 Arming follows the chaos-hook discipline (net/client.py ``_fault_plane``):
 
@@ -76,6 +96,7 @@ from __future__ import annotations
 import gc
 import itertools
 import os
+import selectors
 import threading
 import time
 from collections import deque
@@ -110,7 +131,7 @@ class FrameTrace:
 
     __slots__ = ("trace_id", "ts", "t0", "verbs", "n_cmds", "client_id",
                  "qos_class", "tenant", "spans", "dispatched_at", "hop_at",
-                 "total_us", "finished", "base_attrs")
+                 "left_at", "total_us", "finished", "base_attrs")
 
     def __init__(self, trace_id: int, ts: float, t0: float, verbs: str,
                  n_cmds: int, client_id: int):
@@ -128,6 +149,10 @@ class FrameTrace:
         # hops follow one another; a sharded plan's buckets, submitted in
         # one loop turn, share the stamp)
         self.hop_at = t0
+        # the last line of the worker job that ended last (the same slot
+        # discipline: a bucket frame's jobs each stamp it, the last to end
+        # stamps it last)
+        self.left_at = t0
         self.total_us = 0
         self.finished = False
         # attrs merged into EVERY span of this frame (replica-served frames
@@ -157,16 +182,21 @@ class FrameTrace:
         (``hop_at``) — the wait for a pool thread plus the thread switch."""
         self.add_span("hop", self.hop_at, time.monotonic(), to=to)
 
+    def woke(self, frm: str) -> None:
+        """The loop's first line after awaiting a worker: close the ``wake``
+        the worker's last line opened (``left_at``) — the hand-off to the
+        loop plus the turns it took to come round to this frame."""
+        self.add_span("wake", self.left_at, time.monotonic(), frm=frm)
+
     def stage_totals(self) -> Dict[str, int]:
         """{stage: summed µs} — the SLOWLOG breakdown projection (child
-        spans excluded: ``kernel.member`` duplicates its kernel span's time,
-        the ``reply.*`` children their ``reply`` span's, ``wave.plan`` its
-        kernel span's, ``wave.answer`` its ``reply`` span's, and
-        ``point.wait`` lies over the ``hop`` and the head of ``dispatch``)."""
+        spans excluded: the ``reply.*`` children duplicate their ``reply``
+        span's time, ``wave.plan`` its kernel span's, ``wave.answer`` its
+        ``reply`` span's, and ``point.wait`` lies over the ``hop`` and the
+        head of ``dispatch``)."""
         out: Dict[str, int] = {}
         for s in self.spans:
-            if s.name.endswith(".member") or s.name.startswith(
-                    ("reply.", "wave.", "point.")):
+            if s.name.startswith(("reply.", "wave.", "point.")):
                 continue
             out[s.name] = out.get(s.name, 0) + s.dur_us
         return out
@@ -184,7 +214,7 @@ class Tracer:
     # host-event ring: a collection enters it at GC_MIN_S (the young
     # generation collects thousands of times a minute in tens of µs — the
     # totals count those, the ring keeps what can explain a slow frame), a
-    # late loop wake-up at STALL_MIN_S; LONG_S is the size PERF.md gives
+    # turn of the loop at STALL_MIN_S; LONG_S is the size PERF.md gives
     # the stalls that make p99 unboundable
     HOST_EVENTS = 1024
     GC_MIN_S = 0.001
@@ -225,8 +255,11 @@ class Tracer:
             verb = bytes(commands[0][0]).upper().decode()
         except Exception:  # noqa: BLE001 — malformed frame still traces
             verb = "?"
+        start = now if t0 is None else t0
+        # the wall stamp is the anchor's, not the parse's end: a span's
+        # wall position is ts + its offset
         tr = FrameTrace(
-            next(self._ids), time.time(), t0 if t0 is not None else now,
+            next(self._ids), time.time() - (now - start), start,
             verb, len(commands), getattr(ctx, "client_id", 0),
         )
         if t0 is not None:
@@ -293,17 +326,14 @@ class Tracer:
 
     # -- host events ----------------------------------------------------------
 
-    def note_wake(self, due: float, now: float) -> None:
-        """The loop's heartbeat was due at ``due`` and woke at ``now``
-        (server._heartbeat; one loop a server, so no two writers of these
-        totals): STALL_MIN_S or more late is a ``stall``."""
-        seconds = now - due
-        if seconds < self.STALL_MIN_S:
-            return
+    def note_stall(self, start: float, seconds: float) -> None:
+        """A turn of the loop that began at ``start`` lasted ``seconds``,
+        STALL_MIN_S or more (LoopSelector.select, armed only; one loop a
+        server, so no two writers of these totals): a ``stall``."""
         self.loop_stall_s += seconds
         if seconds >= self.LONG_S:
             self.loop_long_stalls += 1
-        self._host.append(("stall", time.time() - seconds, due, seconds,
+        self._host.append(("stall", time.time() - seconds, start, seconds,
                            None))
 
     def _on_gc(self, phase: str, info: dict) -> None:
@@ -426,6 +456,53 @@ class Tracer:
             "trace_ring_entries": float(len(self._ring)),
             "trace_inflight": float(self._inflight),
         }
+
+
+# -- the event loop's account (always on) --------------------------------------
+
+
+class LoopSelector(selectors.DefaultSelector):
+    """The selector a server hands its own event loop
+    (``asyncio.SelectorEventLoop(LoopSelector())``), counting where the loop
+    selects: a TURN is what the loop runs between two calls of ``select`` —
+    the callbacks of the events and hand-offs that were ready — and BUSY is
+    the time it spent there, so 1 - busy / wall is the share of its life
+    the loop slept waiting for work, and busy / turns what one more
+    hand-off waits for the loop, on average, when it never sleeps.  Two
+    clock reads, two adds and a comparison a turn; one loop, one writer, so
+    no lock.  Readers on other threads see totals that only grow.  While
+    tracing is armed a turn of ``Tracer.STALL_MIN_S`` or more is the
+    tracer's ``stall`` host event."""
+
+    def __init__(self):
+        super().__init__()
+        self.turns = 0
+        self.busy_s = 0.0
+        self._ran_at = time.monotonic()
+
+    def select(self, timeout=None):
+        ran = time.monotonic() - self._ran_at
+        self.busy_s += ran
+        self.turns += 1
+        if ran >= Tracer.STALL_MIN_S:
+            tracer = _tracer  # one read: another thread may disarm
+            if tracer is not None:
+                tracer.note_stall(self._ran_at, ran)
+        ready = super().select(timeout)
+        self._ran_at = time.monotonic()
+        return ready
+
+
+def thread_cpu_s(thread: Optional[threading.Thread]) -> float:
+    """CPU seconds `thread` has run (user + system), read from its own
+    clock: nothing is counted on the thread itself.  0.0 for no thread, or
+    one that has ended (its clock goes with it)."""
+    if thread is None or not thread.is_alive():
+        return 0.0
+    try:
+        return time.clock_gettime(time.pthread_getcpuclockid(thread.ident))
+    except OSError:  # it ended between the two lines
+        return 0.0
 
 
 # -- process-global arming (the chaos-hook discipline) -------------------------

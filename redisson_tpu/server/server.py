@@ -144,7 +144,8 @@ def _on_worker(trace, to: str, fn, *args):
     """The one entry of a frame's work on a worker thread.  `trace` (tracing
     armed only) closes the `hop` its submit site opened and is this
     thread's current trace while `fn` runs, so the lane, kernel and
-    readback spans recorded below land on the right frame."""
+    readback spans recorded below land on the right frame; its last line
+    opens the `wake` the loop closes after its await."""
     if trace is None:
         return fn(*args)
     trace.hopped(to)
@@ -153,6 +154,7 @@ def _on_worker(trace, to: str, fn, *args):
         return fn(*args)
     finally:
         _obs.clear_current()
+        trace.left_at = time.monotonic()
 
 
 # POINT commands — a single-item BF.ADD / BF.EXISTS — by the verb's bytes as
@@ -223,6 +225,13 @@ class _Laneless:
         if self._cur is not None:
             self._cur.add_span("dispatch", self._t0, time.monotonic())
         return False
+
+
+def _drain_index(search) -> None:
+    """A writing frame's last worker job: what it left dirty under a search
+    index is indexed (a `dispatch` span of its own on the frame's trace)."""
+    with _Laneless():
+        search.drain_all()
 
 
 def _force_lazies(results: list, server) -> None:
@@ -362,7 +371,8 @@ class TpuServer:
         self.mode = mode
         self.node_id = uuid.uuid4().hex
         self.started_at = time.time()
-        self.stats = {"connections": 0, "commands": 0, "errors": 0, "sheds": 0,
+        self.stats = {"connections": 0, "commands": 0, "frames": 0,
+                      "errors": 0, "sheds": 0,
                       # read-scaling plane (ISSUE 17): replica-served keyed
                       # reads, reads refused as too stale (REPLSTATE
                       # MAXSTALE), reads bounced to the master (missing
@@ -392,8 +402,8 @@ class TpuServer:
             lambda: self.tracer.census()["trace_inflight"],
         )
         # the host's pauses (armed only; monotone, so a scraper's
-        # after-minus-before is the window's): collections, and wake-ups of
-        # this server's event loop that came late (_heartbeat)
+        # after-minus-before is the window's): collections, and turns of
+        # this server's event loop of 5 ms or more (LoopSelector.select)
         self.metrics.gauge(
             "host_gc_pause_seconds_total", lambda: self.tracer.gc_pause_s
         )
@@ -406,6 +416,43 @@ class TpuServer:
         self.metrics.gauge(
             "host_loop_long_stalls_total",
             lambda: self.tracer.loop_long_stalls,
+        )
+        # the event loop's account (always on; monotone): its turns and the
+        # seconds it spent outside `select`, counted by the selector this
+        # server hands its own loop (new_event_loop; a server inside
+        # somebody else's loop reads 0), and the frames the loop served.
+        # Whose CPU: the loop thread's, this server's three pools' threads'
+        # and the whole process's (with the runtime's and XLA's threads)
+        # since start_async, read from the threads' own clocks AT THE
+        # SCRAPE — nothing on the hot path — beside the uptime a reader
+        # divides them by.  Busy less CPU of the loop is time it held a
+        # turn open without running: the interpreter lock, or a blocking
+        # call.
+        self._loop_selector: Optional[_obs.LoopSelector] = None
+        self._own_loop = None
+        self._loop_thread: Optional[threading.Thread] = None
+        self._up_at = time.monotonic()
+        self._loop_cpu0 = self._process_cpu0 = 0.0
+        self._cpu_seen = {"loop": 0.0, "worker": 0.0}
+        self._cpu_lock = threading.Lock()
+        for series, field in (("turns", "turns"), ("busy_seconds", "busy_s")):
+            self.metrics.gauge(
+                f"host_loop_{series}_total",
+                lambda field=field: getattr(self._loop_selector, field, 0),
+            )
+        self.metrics.gauge("frames_served_total", lambda: self.stats["frames"])
+        for who in self._cpu_seen:
+            self.metrics.gauge(
+                f"host_{who}_cpu_seconds_total",
+                lambda who=who: self._threads_cpu_s(who),
+            )
+        self.metrics.gauge(
+            "host_process_cpu_seconds_total",
+            lambda: time.process_time() - self._process_cpu0,
+        )
+        self.metrics.gauge(
+            "host_uptime_seconds_total",
+            lambda: time.monotonic() - self._up_at,
         )
         # rows the bank kernels were sent against rows the device walked for
         # them (core/kernels.py count_rows): what a bucket's padding costs
@@ -487,8 +534,6 @@ class TpuServer:
             "gather_bytes_fetched_total",
             lambda: ioplane.gather_bytes_counted()[1],
         )
-        self._heartbeat_task = None
-        self.heartbeat_wakes = 0
         # orphaned RESP3 pushes (ISSUE 12 satellite bugfix): the process-
         # global drop counter was census-only — a fleet scrape could never
         # see a desync-avoided push drop.  Now a first-class gauge.
@@ -1447,7 +1492,7 @@ class TpuServer:
         member's reply to the loop in ONE call, and — before returning —
         submit the record's next job if anybody joined meanwhile.  `hop`
         closes here, where the job takes the member; `dispatch` is taken ->
-        answered."""
+        answered, and `wake` opens where it ends."""
         self._await_resume()
         with self._point_lock:
             waiting = self._point_open[key]
@@ -1469,6 +1514,7 @@ class TpuServer:
             t_done = time.monotonic()
             for tr in traced:
                 tr.add_span("dispatch", t_taken, t_done)
+                tr.left_at = t_done
             loop.call_soon_threadsafe(_resolve_point_window, members, replies, error)
             with self._point_lock:
                 if not waiting:
@@ -1590,19 +1636,16 @@ class TpuServer:
     @staticmethod
     def _stacked_kernel_span(cur, k0: float, verb: str, cmds, key_at: int = 1,
                              stacked: int = STACK_PLANES) -> None:
-        """Coalescer fan-in: ONE kernel span for a stacked dispatch, its
-        member commands recorded as child spans sharing the kernel's
-        interval (bounded so a 1000-command blob run cannot bloat the
-        trace).  `stacked`: what the dispatch was padded to."""
-        k1 = time.monotonic()
+        """Coalescer fan-in: ONE kernel span for a stacked dispatch; `keys`
+        names its member commands' keys (the first 32, so a 1000-command
+        blob run cannot bloat the trace).  `stacked`: what the dispatch was
+        padded to."""
         cur.add_span(
-            "kernel", k0, k1, verb=verb, members=len(cmds), stacked=stacked,
+            "kernel", k0, time.monotonic(), verb=verb, members=len(cmds),
+            stacked=stacked,
+            keys=b",".join(bytes(c[key_at]) for c in cmds[:32]).decode(
+                errors="replace"),
         )
-        for c in cmds[:32]:
-            cur.add_span(
-                "kernel.member", k0, k1,
-                key=bytes(c[key_at]).decode(errors="replace"),
-            )
 
     def _dispatch_bitset_wave(self, ctx, cmds):
         """ONE stacked dispatch for a wave of same-form SETBITSB, BITOP OR /
@@ -2016,6 +2059,8 @@ class TpuServer:
                 results[0] = await self._join_point_window(
                     ctx, cmd, loop, pool, trace
                 )
+                if trace is not None:
+                    trace.woke("dispatch")
                 plan = ()
         if plan is None:
             plan = self._plan_frame(ctx, commands, shed_mask)
@@ -2041,6 +2086,8 @@ class TpuServer:
                         "dispatch", self._dispatch_serial, ctx,
                         [commands[i] for i in seg[at:end]], qos_class,
                     )
+                    if trace is not None:
+                        trace.woke("dispatch")
                     for i, r in zip(seg[at:end], replies):
                         results[i] = r
                     at = end
@@ -2055,6 +2102,8 @@ class TpuServer:
                     ctx, dev_index, [(i, commands[i]) for i in idxs], qos_class,
                 ))
             outs = await asyncio.gather(*jobs, return_exceptions=True)
+            if trace is not None:
+                trace.woke("dispatch")  # from the bucket that ended last
             err = next((o for o in outs if isinstance(o, BaseException)), None)
             if err is not None:
                 raise err
@@ -2066,7 +2115,12 @@ class TpuServer:
         # (services/search.py; with no index, one dict lookup a frame)
         search = self.engine._services.get("search")
         if search is not None and search.has_dirty():
-            await loop.run_in_executor(pool, search.drain_all)
+            if trace is not None:
+                trace.hop_at = time.monotonic()
+            await loop.run_in_executor(
+                pool, _on_worker, trace, "dispatch", _drain_index, search)
+            if trace is not None:
+                trace.woke("dispatch")
         return results
 
     async def _finish_frame(self, ctx, results, loop, write_q, readback_slots,
@@ -2101,6 +2155,8 @@ class TpuServer:
                 self._pool_for(adm), _on_worker, trace, "force",
                 _force_lazies, results, self,
             )
+            if trace is not None:
+                trace.woke("force")
         # one queue item per frame — the whole frame's replies
         # encode in one pass and write in one syscall batch
         if trace is not None:
@@ -2238,6 +2294,7 @@ class TpuServer:
         `qos` span, annotated tenant/class/items/shed."""
         if not commands:
             return True  # a read that completed no frame
+        self.stats["frames"] += 1
         sched = self.scheduler
         adm = None
         bulk_gate = None
@@ -2358,10 +2415,12 @@ class TpuServer:
             # its `reply` span HERE — the trace total is therefore the true
             # client-observable latency, and the span's children say what
             # the tail was: `reply.wait` (the readback future, or the time
-            # queued here), `reply.encode`, `reply.write` (write -> drain
-            # returned, shared by the batch).  A trace whose bytes never reach
-            # the wire (pool death, connection error) is abandoned so the
-            # inflight census row still drains.
+            # queued here; `reply.wake` is the end of it, the force job's
+            # last line -> this task has its result), `reply.encode`,
+            # `reply.write` (write -> drain returned, shared by the batch).
+            # A trace whose bytes never reach the wire (pool death,
+            # connection error) is abandoned so the inflight census row
+            # still drains.
             held = None  # a _PendingFrame popped while coalescing bytes
             try:
                 while True:
@@ -2404,6 +2463,8 @@ class TpuServer:
                                 parts.append(item.encoded())
                                 tr.add_span("reply.wait", tr.dispatched_at,
                                             t_got)
+                                tr.add_span("reply.wake", tr.left_at, t_got,
+                                            frm="force")
                                 tr.add_span("reply.encode", t_got,
                                             time.monotonic())
                                 if done_tr is None:
@@ -2596,34 +2657,47 @@ class TpuServer:
             )
         return NodeClient(address, **kw)
 
+    def new_event_loop(self) -> asyncio.AbstractEventLoop:
+        """The loop this server runs on where it makes its own (`asyncio.run
+        (..., loop_factory=server.new_event_loop)`: ServerThread, the CLI):
+        a selector loop whose selector keeps the loop's account."""
+        self._loop_selector = _obs.LoopSelector()
+        self._own_loop = asyncio.SelectorEventLoop(self._loop_selector)
+        return self._own_loop
+
+    def _threads_cpu_s(self, who: str) -> float:
+        """CPU seconds of the loop's thread since start_async (`loop`), or
+        of this server's three pools' threads (`worker`).  A stopping
+        server keeps its last reading: stop() closes under the same lock,
+        before any of those threads can end, so the clock of a thread that
+        is gone is never asked for and no total falls."""
+        with self._cpu_lock:
+            if not self._closing:
+                if who == "loop":
+                    now = _obs.thread_cpu_s(self._loop_thread) - self._loop_cpu0
+                else:
+                    now = sum(
+                        _obs.thread_cpu_s(t)
+                        for pool in (self._pool, self._qos_pool, self._slow_pool)
+                        for t in tuple(pool._threads)
+                    )
+                self._cpu_seen[who] = now
+            return self._cpu_seen[who]
+
     async def start_async(self):
         self._loop = asyncio.get_running_loop()
+        if self._loop is not self._own_loop:
+            self._loop_selector = None  # somebody else's loop: no account
+        self._loop_thread = threading.current_thread()
+        self._loop_cpu0 = _obs.thread_cpu_s(self._loop_thread)
+        self._process_cpu0 = time.process_time()
         self._server = await asyncio.start_server(
             self._handle, self.host, self.port, reuse_address=True,
             ssl=self._server_ssl_context(), limit=_FRAME_CAP,
         )
         if self.port == 0:
             self.port = self._server.sockets[0].getsockname()[1]
-        self._heartbeat_task = self._loop.create_task(self._heartbeat())
         return self
-
-    async def _heartbeat(self):
-        """The event loop's own pulse.  Tracing armed: sleep 10 ms at a
-        time and record, as a `stall` host event, every wake-up that came
-        5 ms or more late — whatever kept the loop from running (a GC, a
-        worker holding the GIL, a long synchronous call on the loop).
-        Disarmed: one wake a second to look at the guard, nothing recorded
-        — so arming from a worker thread (CONFIG SET trace-enabled yes)
-        needs no cross-thread plumbing and takes effect within a second."""
-        while True:
-            self.heartbeat_wakes += 1
-            if _obs._tracer is None:
-                await asyncio.sleep(1.0)
-                continue
-            due = time.monotonic() + 0.010
-            await asyncio.sleep(0.010)
-            if _obs._tracer is not None:
-                _obs._tracer.note_wake(due, time.monotonic())
 
     async def serve_forever(self):
         await self.start_async()
@@ -2699,14 +2773,13 @@ class TpuServer:
         # parked blocking verbs (_block_loop, WAIT) poll this to unpark:
         # a forever-blocked worker would otherwise survive pool shutdown
         # (wait=False) and hang interpreter exit via the futures atexit join
-        self._closing = True
+        with self._cpu_lock:  # no scrape is reading a thread's clock now
+            self._closing = True
         self._pause_gate.set()  # release chaos-paused workers
         loop, server = self._loop, self._server
         if loop is not None and server is not None:
             def shutdown():
                 server.close()
-                if self._heartbeat_task is not None:
-                    self._heartbeat_task.cancel()
                 # drop established connections too: clients must see a dead
                 # node, not a half-alive one (failover tests depend on this)
                 for w in list(self._writers):
@@ -2795,7 +2868,7 @@ class ServerThread:
                     except asyncio.CancelledError:
                         pass
 
-            asyncio.run(main())
+            asyncio.run(main(), loop_factory=self.server.new_event_loop)
 
         self._thread = threading.Thread(target=run, daemon=True, name="rtpu-server")
         self._thread.start()
@@ -3039,9 +3112,12 @@ def main(argv=None):
     try:
         # SIGTERM and SIGINT both land on the graceful path (the supervisor
         # stops nodes with SIGTERM; see serve_until_signal)
-        asyncio.run(srv.serve_until_signal(
-            ready_fd=args.ready_fd, journal_dir=args.journal_dir,
-        ))
+        asyncio.run(
+            srv.serve_until_signal(
+                ready_fd=args.ready_fd, journal_dir=args.journal_dir,
+            ),
+            loop_factory=srv.new_event_loop,
+        )
     finally:
         if checkpointer is not None:
             # flush-on-stop: writes since the last interval tick reach disk

@@ -1061,7 +1061,7 @@ def cmd_trace(server, ctx, args):
     `host.gc` / `host.stall` for each host pause it overlapped.  EVENTS
     returns the host-event ring, newest first: [kind, unix_ms, dur_us,
     attrs] with kind `gc` (a collection of 1 ms or more, `gen`) or `stall`
-    (the event loop woke 5 ms or more late).  RESET clears both rings.
+    (a turn of the event loop of 5 ms or more).  RESET clears both rings.
     Empty while tracing is disarmed (CONFIG SET trace-enabled yes arms)."""
     sub = bytes(args[0]).upper() if args else b"GET"
     tracer = server.tracer

@@ -672,7 +672,8 @@ def test_kernel_and_readback_spans_say_what_they_rode(served):
                     "BITOP XOR": 12, "BITCOUNT": 12}
     # at most one wave a form and lane: 12 tenants over 4 lanes
     assert len(kernels) <= 6 * 4
-    members = [bytes(a["key"]).decode() for n, a in spans if n == "kernel.member"]
+    assert not [n for n, _a in spans if n == "kernel.member"]
+    members = [k for a in kernels for k in bytes(a["keys"]).decode().split(",")]
     assert sorted(members) == sorted(
         [names(t)[0] for t in range(3)] + [names(t)[0] for t in range(12)]
         + [names(t)[1] for t in range(12)] * 3 + [names(t)[2] for t in range(12)])

@@ -9,7 +9,9 @@ answerable from a terminal:
     trace 184  BF.MEXISTS64 x1  total 63.1ms  class=interactive tenant=ta
       parse      0.0ms |#                                                 |
       qos        0.1ms |#                                                 |
+      hop        0.3ms |#                                                 |  to=dispatch
       dispatch  12.4ms |....#########                                     |
+      wake       0.4ms |.............#                                    |  frm=dispatch
       readback  48.9ms |.............###################################  |
       reply      1.2ms |..............................................### |
 
@@ -50,8 +52,6 @@ def render_trace(entry, width: int = WIDTH) -> str:
     lines = [head]
     for name, off_us, dur_us, attrs in spans:
         name = _b(name)
-        if name.endswith(".member"):
-            continue  # members duplicate their kernel span's interval
         # the bar shows the part inside [0, total]: `recv` lies before 0
         off_us, end_us = max(0, int(off_us)), int(off_us) + int(dur_us)
         lo = min(width, int(off_us * width / total_us))
