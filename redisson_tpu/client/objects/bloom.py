@@ -159,36 +159,34 @@ class BloomFilter(RExpirable):
             self._touch_version(rec)
         return newly, n
 
-    def add_in_order_async(self, items) -> list:
-        """Pipelined add of byte items answered as if they were added ONE AT
-        A TIME, in order: [(device newly-added array, n_valid), ...], a
-        dispatch each, nothing fetched.  add_each_async answers a batch from
-        ONE gather taken before its scatter, so an item whose every unset
-        cell an EARLIER item of the batch sets still reports newly added —
-        right for a caller's own batch, not for commands of different
-        clients answered together (server/verbs/sketch.py point_window).
-        The items' cells are computed here, on the host, and the batch is
-        cut before every item that shares a cell with an earlier one of its
-        run: inside a run no item's answer depends on another's, and the
-        runs are dispatched in order under the record's lock, so the device
-        applies them one after another.  Distinct items hardly ever share a
-        cell: one run, one dispatch."""
-        items = [o if isinstance(o, bytes) else self._codec.encode(o) for o in items]
+    def answer_window_async(self, items, adds):
+        """Probes and adds of byte items from different clients, answered
+        together as ONE one-at-a-time execution — every probe, then every
+        add in the order given — by ONE upload and ONE dispatch under the
+        record's lock, nothing fetched: (device uint8 flags, n_valid).
+        `adds[i]` says whether item i is added (BF.ADD) or probed
+        (BF.EXISTS).  add_each_async answers a batch from one gather taken
+        before its scatter, right for a caller's own batch; here an add
+        whose every clear cell an EARLIER add sets reports 0, and a probe
+        does not see the window's adds (kernels.bloom_window_bytes_masked;
+        server/verbs/sketch.py point_window)."""
+        words, nbytes = H.pack_keys(
+            [o if isinstance(o, bytes) else self._codec.encode(o) for o in items])
+        n = len(nbytes)
+        w = K.pow2_bucket(words.shape[0], minimum=4)
+        buf = np.zeros((w + 2, K.pow2_bucket(n)), np.uint32)
+        buf[:words.shape[0], :n] = words
+        buf[w, :n] = nbytes
+        buf[w + 1, :n] = adds
+        staged = K.stage(buf)
         with self._engine.locked(self._name):
-            cuts = [0]
-            if len(items) > 1:
-                rec = self._rec()
-                h1, h2 = H.hash_packed_bytes(*H.pack_keys(items), np)
-                rows = H.bloom_indexes(h1, h2, rec.meta["k"], rec.meta["m"], np)
-                seen: set = set()
-                for at, row in enumerate(rows.tolist()):
-                    if not seen.isdisjoint(row):
-                        cuts.append(at)
-                        seen = set()
-                    seen.update(row)
-            cuts.append(len(items))
-            return [self.add_each_async(items[lo:hi])
-                    for lo, hi in zip(cuts, cuts[1:]) if hi > lo]
+            rec = self._rec()
+            bits, flags = K.bloom_window_bytes_masked(
+                rec.arrays["bits"], staged, K.valid_n(n), rec.meta["k"], rec.meta["m"])
+            rec.arrays["bits"] = bits
+            if any(adds):
+                self._touch_version(rec)
+        return flags, n
 
     def contains(self, obj) -> bool:
         if isinstance(obj, np.ndarray):

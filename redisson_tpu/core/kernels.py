@@ -167,13 +167,15 @@ def rows_counted() -> tuple:
 # answered by verb, device dispatches issued for them, and count_rows's rule
 # at those dispatches — rows the bytes kernel was handed against rows
 # somebody asked for.  A window of commands formed across connections
-# (server/server.py _join_point_window) counts its members here and each of
-# its dispatches — one a verb — once; a lone command is one command, one
-# dispatch, one row of a bucket of MIN_BUCKET.  Always on; METRICS exports the sums
-# (point_cmds_total with point_cmds_bf_add_total / point_cmds_bf_exists_total,
-# point_dispatches_total, point_rows_valid_total, point_rows_issued_total).
+# (server/server.py _join_point_window) counts its members here, its one
+# dispatch once and itself once; a lone command is one command, one window,
+# one dispatch, one row of a bucket of MIN_BUCKET.  Always on; METRICS
+# exports the sums (point_cmds_total with point_cmds_bf_add_total /
+# point_cmds_bf_exists_total, point_windows_total, point_dispatches_total,
+# point_rows_valid_total, point_rows_issued_total).
 POINT_VERBS = ("BF.ADD", "BF.EXISTS")
 _point_cmds = dict.fromkeys(POINT_VERBS, 0)
+_point_windows = 0
 _point_dispatches = 0
 _point_rows_valid = 0
 _point_rows_issued = 0
@@ -195,11 +197,19 @@ def count_point_cmds(verb: str, n: int = 1) -> None:
         _point_cmds[verb] += n
 
 
+def count_point_window() -> None:
+    """One window of point commands served."""
+    global _point_windows
+    with _ROWS_LOCK:
+        _point_windows += 1
+
+
 def point_counted() -> dict:
-    """This process's point-command totals: {"cmds": {verb: n},
+    """This process's point-command totals: {"cmds": {verb: n}, "windows",
     "dispatches", "rows_valid", "rows_issued"}."""
-    return {"cmds": dict(_point_cmds), "dispatches": _point_dispatches,
-            "rows_valid": _point_rows_valid, "rows_issued": _point_rows_issued}
+    return {"cmds": dict(_point_cmds), "windows": _point_windows,
+            "dispatches": _point_dispatches, "rows_valid": _point_rows_valid,
+            "rows_issued": _point_rows_issued}
 
 
 def _map_valid_chunks(rows, n_valid, body):
@@ -299,6 +309,38 @@ def bloom_contains_bytes_masked(bits, words, nbytes, n_valid, k: int, m: int):
         h1, h2 = H.hash_packed_bytes(words, nbytes, jnp)
         idx = H.bloom_indexes(h1, h2, k, m, jnp)
         return bt.contains(bits, idx) & _valid_mask(h1.shape[0], n_valid)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4), donate_argnums=(0,))
+def bloom_window_bytes_masked(bits, buf, n_valid, k: int, m: int):
+    """A window of point commands in ONE program (server/verbs/sketch.py
+    point_window).  `buf` is one (W + 2, B) uint32 upload: W rows of item
+    words (hashing.pack_keys' layout), a row of byte lengths, a row that
+    is 1 for an add.  The answers are those of one one-at-a-time execution,
+    the probes then the adds in row order: a probe reads the plane before
+    the window; an add is newly added where one of its cells is clear in
+    that plane AND is no cell of an earlier add of the window.  The adds'
+    cells are then set.  Returns (plane, uint8[B] flags, 0 past n_valid)."""
+    with jax.named_scope("bloom_window_bytes_masked"):
+        w = buf.shape[0] - 2
+        h1, h2 = H.hash_packed_bytes(buf[:w], buf[w], jnp)
+        idx = H.bloom_indexes(h1, h2, k, m, jnp)
+        rows = jnp.arange(idx.shape[0], dtype=jnp.int32)
+        valid = rows < n_valid
+        add = (buf[w + 1] != 0) & valid
+        old = bits.at[idx].get(mode="fill", fill_value=1)
+        present = jnp.all(old != 0, axis=-1)
+        # cell (i, j) is set before add i by an earlier add i' < i of the
+        # window: an equality test of every cell against every other, (B*k)
+        # squared.  On a v5e, windows dispatched back to back are paced by
+        # the host (about 0.3 ms each) with this test, a flat one or a sort
+        earlier = add[None, :] & (rows[None, :] < rows[:, None])
+        meets = jnp.any(idx[:, :, None, None] == idx[None, None, :, :], axis=3)
+        covered = jnp.any(meets & earlier[:, None, :], axis=2)
+        newly = jnp.any((old == 0) & ~covered, axis=-1)
+        flags = jnp.where(add, newly, present & valid)
+        new_bits = bt.set_bits(bits, jnp.where(add[:, None], idx, bits.shape[0]), 1)
+        return new_bits, flags.astype(jnp.uint8)
 
 
 # --- multi-tenant bloom bank: (T, m) bit plane, ops carry a tenant row ------

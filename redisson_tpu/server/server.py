@@ -166,8 +166,8 @@ _POINT_VERBS = {
     for name in ("BF.ADD", "BF.EXISTS")
     for spelling in (name.encode(), name.lower().encode())
 }
-# the most members one window takes: the smallest bucket pack_keys gives
-# byte items, so a window of any size runs the programs a lone command runs
+# the most members one window takes: the smallest bucket of a batch, so a
+# window of any size runs the one program a lone command runs
 _POINT_WINDOW_MAX = MIN_BUCKET
 
 
@@ -512,8 +512,8 @@ class TpuServer:
             "knn_wave_shared_cmds_total", lambda: _coalesce.knn_wave_counted()[1]
         )
         # point commands (single-item BF.ADD / BF.EXISTS; always on): answered
-        # by verb, device dispatches issued for them, rows those were handed
-        # against rows asked (core/kernels.py count_point_*)
+        # by verb, windows served, device dispatches issued for them, rows
+        # those were handed against rows asked (core/kernels.py count_point_*)
         self.metrics.gauge(
             "point_cmds_total", lambda: sum(_K.point_counted()["cmds"].values())
         )
@@ -522,7 +522,7 @@ class TpuServer:
                 f"point_cmds_{verb.lower()}_total",
                 lambda verb=verb: _K.point_counted()["cmds"][verb],
             )
-        for series in ("dispatches", "rows_valid", "rows_issued"):
+        for series in ("windows", "dispatches", "rows_valid", "rows_issued"):
             self.metrics.gauge(
                 f"point_{series}_total",
                 lambda series=series: _K.point_counted()[series],
@@ -1437,7 +1437,7 @@ class TpuServer:
     # -- windows of point commands (ISSUE 36) ----------------------------------
     # A frame that is ONE point command does not go to a worker alone: on
     # the loop it joins the open window of its record, and ONE worker job a
-    # window answers every member with one dispatch a verb and one fetch.
+    # window answers every member with one dispatch and one fetch.
     # A record has one job at a time, so no timer and no size: a window is
     # what arrived while the one before it was served (group commit) — a
     # lone command on an idle server is taken at once, alone, and 200

@@ -275,69 +275,47 @@ def point_window(server, name: str, verbs, items, traces=None) -> List[int]:
     frame, MULTI/EXEC, cluster mode): the same code, a window of one.
 
     The answers are those of ONE one-at-a-time execution of the members:
-    the probes, then the adds in the order given.  One BloomFilter a
-    window; ONE pack and ONE dispatch of the probes' items
-    (contains_each_async: bloom_contains_bytes_masked under the record's
-    lock), ONE of the adds' (add_in_order_async: bloom_add_bytes_masked,
-    cut only where an add shares a cell with an earlier one, so that
-    `newly` is what the serial order gives); every flags array fetched by
-    ONE grouped fetch (registry.gather_lazy_device_results).  Nothing is
-    answered before the adds are applied under the record's lock and the
-    flags are on the host, and a dispatch is never issued twice: a window
-    that raises has answered nobody.
+    the probes, then the adds in the order given.  ONE upload, ONE program
+    (BloomFilter.answer_window_async: bloom_window_bytes_masked under the
+    record's lock) and ONE fetch of its uint8 flags
+    (registry.gather_lazy_device_results), whatever the window's size and
+    mix: every window of items up to 16 bytes runs the program a lone
+    command runs.  Nothing is answered before the adds are applied under
+    the record's lock and the flags are on the host, and the dispatch is
+    never issued twice: a window that raises has answered nobody.
 
-    Counted by what is done (core/kernels.py count_point_*): a dispatch
-    and the rows it was handed when it is issued, the commands by verb once
-    they are answered.  Tracing armed (`traces`: a FrameTrace or None a
-    member; None = the thread's current one for each): a `kernel` span a
-    member (`verb`, `members`: the commands its verb's dispatch answered),
-    `point.wait` from the member's submit (`hop_at`) to that dispatch
-    issued, and the fetch's `readback`."""
-    from redisson_tpu.core import ioplane
+    Counted by what is done (core/kernels.py count_point_*): the dispatch
+    and the rows it was handed when it is issued, the commands by verb and
+    the window once they are answered.  Tracing armed (`traces`: a
+    FrameTrace or None a member; None = the thread's current one for each):
+    a `kernel` span a member (`verb`: the window's verbs, "BF.EXISTS+BF.ADD"
+    where it holds both; `members`: the window), `point.wait` (`verb`: the
+    member's) from the member's submit (`hop_at`) to the dispatch issued,
+    and the fetch's `readback`."""
     from redisson_tpu.core import kernels as K
     from redisson_tpu.server.registry import gather_lazy_device_results
 
     if traces is None and _obs._tracer is not None:
         traces = [_obs.current_trace()] * len(items)
     traced = [t for t in traces if t is not None] if traces else ()
-    bf = _bloom(server, name)
-    issued = []  # (device flags, the members they answer, row for row)
-    asked = []   # (verb, how many members)
-    for verb, dispatch in (
-        ("BF.EXISTS", lambda its: [bf.contains_each_async(its)]),
-        ("BF.ADD", bf.add_in_order_async),
-    ):
-        members = [i for i, v in enumerate(verbs) if v == verb]
-        if not members:
-            continue
-        asked.append((verb, len(members)))
-        k0 = time.monotonic() if traced else 0.0
-        lo = 0
-        for flags, n in dispatch([items[i] for i in members]):
-            K.count_point_dispatch(n, flags.shape[0])
-            issued.append((flags, members[lo:lo + n]))
-            lo += n
-        if traced:
-            k1 = time.monotonic()
-            for i in members:
-                tr = traces[i]
-                if tr is not None:
-                    tr.add_span("kernel", k0, k1, verb=verb, members=len(members))
-                    tr.add_span("point.wait", tr.hop_at, k1, verb=verb)
-    # which windows are mixed is the traffic's composition: the programs
-    # that stack two flags arrays for the fetch are compiled with the first
-    # window served, not with the first mixed one
-    ioplane.warm_stack_class(issued[0][0])
-    fetched = gather_lazy_device_results(
-        [LazyReply(device=(flags,), owed=len(members)) for flags, members in issued],
-        traced,
-    )
-    answers = [0] * len(items)
-    for (_flags, members), (host,) in zip(issued, fetched):
-        for i, flag in zip(members, host[:len(members)].tolist()):
-            answers[i] = 1 if flag else 0
-    for verb, n in asked:
-        K.count_point_cmds(verb, n)
+    adds = [v == "BF.ADD" for v in verbs]
+    n_adds = sum(adds)
+    k0 = time.monotonic() if traced else 0.0
+    flags, n = _bloom(server, name).answer_window_async(items, adds)
+    K.count_point_dispatch(n, flags.shape[0])
+    if traced:
+        k1 = time.monotonic()
+        window = "+".join(v for v, has in (("BF.EXISTS", n_adds < n), ("BF.ADD", n_adds)) if has)
+        for tr, verb in zip(traces, verbs):
+            if tr is not None:
+                tr.add_span("kernel", k0, k1, verb=window, members=n)
+                tr.add_span("point.wait", tr.hop_at, k1, verb=verb)
+    ((host,),) = gather_lazy_device_results([LazyReply(device=(flags,), owed=n)], traced)
+    answers = host[:n].tolist()
+    for verb, count in (("BF.EXISTS", n - n_adds), ("BF.ADD", n_adds)):
+        if count:
+            K.count_point_cmds(verb, count)
+    K.count_point_window()
     return answers
 
 

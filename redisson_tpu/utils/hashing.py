@@ -128,15 +128,11 @@ def pack_keys(keys):
     n = len(keys)
     if n == 0:
         return np.zeros((0, 0), np.uint32), np.zeros((0,), np.uint32)
-    maxlen = max(len(k) for k in keys)
-    w = max(1, (maxlen + 3) // 4)
-    buf = np.zeros((n, w * 4), np.uint8)
-    nbytes = np.empty((n,), np.uint32)
-    for i, k in enumerate(keys):
-        buf[i, : len(k)] = np.frombuffer(k, np.uint8)
-        nbytes[i] = len(k)
-    words = buf.view("<u4").T.copy()  # (W, N)
-    return words, nbytes
+    nbytes = np.fromiter(map(len, keys), np.uint32, n)
+    w = max(1, (int(nbytes.max()) + 3) // 4)
+    # one join of the keys zero-padded to whole rows, viewed as words
+    rows = np.frombuffer(b"".join(k.ljust(4 * w, b"\0") for k in keys), "<u4")
+    return rows.reshape(n, w).T.copy(), nbytes  # (W, N)
 
 
 def int_keys_to_u32_pair(keys):

@@ -22,7 +22,7 @@ Contracts pinned here:
 
 ISSUE 36: point commands of different connections are answered as one
 WINDOW — what was waiting for its record when a worker took the job — with
-one dispatch a verb and one fetch.  Pinned here (``_window`` makes a window
+one upload, one dispatch and one fetch.  Pinned here (``_window`` makes a window
 on purpose: the server paused, the commands sent and joined, then resumed):
   * a window's answers are those of one one-at-a-time execution, the probes
     then the adds: a probe of an item the same window adds sees the plane
@@ -35,8 +35,8 @@ on purpose: the server paused, the commands sent and joined, then resumed):
     a tracking client is invalidated by a windowed ``BF.ADD``;
   * a window that raises answers every member an error and dispatches
     nothing twice; armed, every member's frame carries ``hop``,
-    ``dispatch``, ``kernel`` (``members`` = its verb's share of the window),
-    ``point.wait`` and ``readback``.
+    ``dispatch``, ``kernel`` (``verb`` = the window's verbs, ``members`` =
+    the window), ``point.wait`` (``verb`` = its own) and ``readback``.
 """
 import threading
 import time
@@ -175,9 +175,9 @@ def test_counters_count_what_they_say(fleet):
     adds = PARAMS["connections"] * (PER_CONN // 11 + 1)
     assert c["cmds"] == sent and c["cmds_bf_add"] == adds
     assert c["cmds_bf_exists"] == sent - adds
-    # 32 connections against four workers: windows form (a window of both
-    # verbs is two dispatches), and each hands its kernel one bucket
-    assert c["dispatches"] < c["cmds"]
+    # 32 connections against four workers: windows form, a window is one
+    # dispatch whatever its mix, and each hands its kernel one bucket
+    assert c["dispatches"] < c["cmds"] and c["windows"] == c["dispatches"]
     assert c["rows_valid"] == sent and c["rows_issued"] == c["dispatches"] * K.MIN_BUCKET
 
 
@@ -189,7 +189,7 @@ def test_one_command_counts_once(conn, verb):
     d = _delta(_metrics(conn), before)
     other = "cmds_bf_exists" if verb == "BF.ADD" else "cmds_bf_add"
     assert d == {"cmds": 1, "cmds_" + verb.lower().replace(".", "_"): 1, other: 0,
-                 "dispatches": 1, "rows_valid": 1, "rows_issued": K.MIN_BUCKET}
+                 "windows": 1, "dispatches": 1, "rows_valid": 1, "rows_issued": K.MIN_BUCKET}
 
 
 def test_the_batch_forms_count_nothing(conn):
@@ -261,7 +261,7 @@ def test_a_window_is_the_probes_then_the_adds(server, conns):
         sent += len(cmds)
     d = _delta(_metrics(conns[0]), before)
     assert d["cmds"] == sent == d["rows_valid"]
-    assert d["dispatches"] == 2 * 12  # a dispatch a verb a window, whatever its size
+    assert d["dispatches"] == d["windows"] == 12  # a dispatch a window, whatever its size and mix
 
 
 def _meeting_pair(m: int, k: int):
@@ -351,7 +351,7 @@ def test_a_lone_command_is_a_window_of_one_and_waits_for_nobody(server, conn):
             conn.execute("bf.exists", "win:lone", b"b"), conn.execute("Bf.Add", "win:lone", b"a")] \
         == [1, 1, 0, 0]
     d = _delta(_metrics(conn), before)
-    assert (d["cmds"], d["dispatches"], d["rows_valid"]) == (4, 4, 4)
+    assert (d["cmds"], d["windows"], d["dispatches"], d["rows_valid"]) == (4, 4, 4, 4)
     assert d["rows_issued"] == 4 * K.MIN_BUCKET
     deadline = time.monotonic() + 30.0  # the job lets go of the record after it answered
     while server.server._point_open and time.monotonic() < deadline:
@@ -469,17 +469,18 @@ def test_a_window_that_raises_answers_every_member_and_dispatches_once(server, c
     conns[0].execute("BF.RESERVE", "win:fail", "0.01", 1000)
     issued = []
 
-    def failing(self, items):
-        issued.append(list(items))
-        raise RuntimeError("the adds' dispatch failed")
+    def failing(self, items, adds):
+        issued.append(list(zip(items, adds)))
+        raise RuntimeError("the window's dispatch failed")
 
-    monkeypatch.setattr(BloomFilter, "add_in_order_async", failing)
+    monkeypatch.setattr(BloomFilter, "answer_window_async", failing)
     errors = srv.stats["errors"]
     cmds = [("BF.EXISTS", "win:fail", b"f-0"), ("BF.ADD", "win:fail", b"f-1"),
             ("BF.ADD", "win:fail", b"f-2"), ("BF.EXISTS", "win:fail", b"f-3")]
     got = _window(server, list(zip(conns, cmds)))
-    assert all(isinstance(r, RespError) and "the adds' dispatch failed" in str(r) for r in got)
-    assert len(issued) == 1 and sorted(issued[0]) == [b"f-1", b"f-2"]  # never issued again
+    assert all(isinstance(r, RespError) and "the window's dispatch failed" in str(r) for r in got)
+    assert len(issued) == 1 and sorted(issued[0]) == [  # one dispatch, never issued again
+        (b"f-0", False), (b"f-1", True), (b"f-2", True), (b"f-3", False)]
     assert srv.stats["errors"] - errors == 4
     monkeypatch.undo()
     assert conns[0].execute("BF.MEXISTS", "win:fail", b"f-1", b"f-2") == [0, 0]
@@ -503,8 +504,8 @@ def test_every_member_of_a_window_has_its_spans(server, conns):
         assert {"hop", "dispatch", "kernel", "point.wait", "readback"} <= set(named)
         (hop,), (dispatch,), (kernel,), (wait,), (readback,) = (
             named[n] for n in ("hop", "dispatch", "kernel", "point.wait", "readback"))
-        assert bytes(kernel["verb"]).decode() == verb == bytes(wait["verb"]).decode()
-        assert kernel["members"] == (5 if verb == "BF.EXISTS" else 2)
+        assert bytes(wait["verb"]).decode() == verb  # the member's; the window's both
+        assert bytes(kernel["verb"]).decode() == "BF.EXISTS+BF.ADD" and kernel["members"] == 7
         # submitted -> taken (the pause: not nothing), taken -> answered, and
         # inside that the member's dispatch issued and the window's one fetch
         assert hop["dur"] > 0 and bytes(hop["to"]) == b"dispatch"
@@ -514,7 +515,7 @@ def test_every_member_of_a_window_has_its_spans(server, conns):
             assert inner["off"] + inner["dur"] <= dispatch["off"] + dispatch["dur"] + 2
         assert abs(wait["off"] - hop["off"]) <= 2
         assert abs((wait["off"] + wait["dur"]) - (kernel["off"] + kernel["dur"])) <= 2
-        assert readback["grouped"] == 2 and readback["parts"] == 2  # both flags arrays, one fetch
+        assert readback["grouped"] == 1 and readback["parts"] == 1  # one flags array, one fetch
 
 
 def _point_spans(c):
