@@ -806,10 +806,11 @@ def bitset_stack_popcount(planes):
 # multimap entry per emit (mapreduce/Collector.java:56-73, MapperTask.java:
 # 50-78).  The TPU path hashes, shuffles and reduces the WHOLE text in
 # compiled programs (services/mapreduce.py drives them):
-#   1. wc_extract_words, once a map task (a chunk of entries): per-byte
-#      polynomial hashing via cumsum scans, word ends compacted by a sort of
-#      the byte positions, per-word (hash_a, hash_b, start) read out by
-#      GATHERS at the ends, and the task's word count.
+#   1. wc_extract_words, once a map task (a chunk of entries): running
+#      sums of the per-byte hash terms, word ends compacted by a sort of the
+#      byte positions, each end's (sum_a, sum_b, last whitespace) read by
+#      ONE gather of a (3, N) stack — a word's hash is its end's sum less
+#      the previous word's —, and the task's word count.
 #   2. wc_count_runs, once a job over every task's words: lexicographic
 #      sort of the 64-bit word hashes (TPU sorts are fast) + run-boundary
 #      compaction via a second sort — counts come out as diffs of run-start
@@ -826,10 +827,17 @@ def bitset_stack_popcount(planes):
 #     TPU scatter costs ~21ms per 1M updates; scatters CANNOT carry this.
 #   * Dual-table count sketch (IBLT peeling, 4 scatters over 10.8M words):
 #     ~1.9s — better, still scatter-bound.
-#   * This sort-based pipeline: sorts + scans + gathers only.  A 64-task
-#     job of 76.7 MB spends ~1 s of v5e time in extraction, 0.9 s of it the
-#     five gathers a word at ~9.7 ns an index: one row gather of a per-byte
-#     running hash would take most of that out.
+#   * This sort-based pipeline: sorts + scans + one gather only.  One
+#     task of the 64-task, 76.7 MB job (1,310,720 bytes, 196,608 rows;
+#     tools/wc_extract_probe.py, v5e, 2026-10): 13.7 ms when the position
+#     weights were a table lookup (a gather of every byte, 2.3 ms), the
+#     sums two jnp.cumsum (1.4 ms) and a word was read by five gathers at
+#     its end and its last whitespace (~1.9 ms each); 6.8 ms with sums
+#     restarted at whitespace by a 21-step shifted scan over the 1-D text;
+#     4.9 ms with the sums carried through a stable sort as payload (4.2
+#     unstable); 3.1 ms now — weights multiplied up from cap's bits, sums
+#     scanned in 128-lane rows (0.9 ms for both, timed alone), an unstable
+#     sort of the end positions alone and ONE stacked gather.
 # Hash identity: words are keyed by a 64-bit (2x u32) polynomial hash of
 # byte+1 values with position weights p^min(pos,63) plus a length term —
 # words longer than 63 bytes that share a 63-byte prefix, length, AND the
@@ -854,31 +862,52 @@ _WC_POW_A = _wc_pow_table(0x01000193)  # FNV-32 prime
 _WC_POW_B = _wc_pow_table(40503)
 
 
+def _wc_pow(cap, table):
+    """table[cap] for a (N,) cap in [0, 64): p^cap mod 2^32, multiplied up
+    from the table's p^(2^k) by cap's bits.  Elementwise: a lookup of the
+    64-entry table lowers to a gather of every byte."""
+    out = jnp.ones(cap.shape, jnp.uint32)
+    for k in range(6):
+        out = jnp.where((cap >> k) & 1 == 1, out * jnp.uint32(int(table[1 << k])), out)
+    return out
+
+
+_WC_LANES = 128
+
+
+def _wc_cumsum(x):
+    """Inclusive running sum of a (N,) uint32, mod 2^32: rows of 128 lanes
+    summed by a log-step scan of shifted adds, then each row offset by the
+    rows before it (a running sum of N / 128 totals)."""
+    n = x.shape[0]
+    pad = -n % _WC_LANES
+    r = jnp.pad(x, (0, pad)).reshape(-1, _WC_LANES)
+    d = 1
+    while d < _WC_LANES:
+        r = r + jnp.pad(r, ((0, 0), (d, 0)))[:, :_WC_LANES]
+        d *= 2
+    tot = r[:, -1]
+    return (r + (jnp.cumsum(tot) - tot)[:, None]).reshape(-1)[:n]
+
+
 def _wc_hash_prelude(buf):
+    """Per byte: whether it is whitespace, the last whitespace at or before
+    it, and the running sums of the hash terms (0 at whitespace) — mod
+    2^32, so a word's hash is the sum at its last byte less the sum at the
+    last byte of the word before it."""
     n = buf.shape[0]
     idx = jnp.arange(n, dtype=jnp.int32)
     ws = buf == 32
     last_ws = jax.lax.cummax(jnp.where(ws, idx, jnp.int32(-1)))
-    pos = idx - last_ws - 1
-    cap = jnp.minimum(pos, _WC_POW - 1)
-    b1 = buf.astype(jnp.uint32) + 1
-    ca = jnp.where(ws, jnp.uint32(0), b1 * jnp.asarray(_WC_POW_A)[cap])
-    cb = jnp.where(ws, jnp.uint32(0), b1 * jnp.asarray(_WC_POW_B)[cap])
-    return ws, idx, last_ws, jnp.cumsum(ca), jnp.cumsum(cb)
+    cap = jnp.minimum(idx - last_ws - 1, _WC_POW - 1)
+    b1 = jnp.where(ws, jnp.uint32(0), buf.astype(jnp.uint32) + 1)
+    return (ws, idx, last_ws, _wc_cumsum(b1 * _wc_pow(cap, _WC_POW_A)),
+            _wc_cumsum(b1 * _wc_pow(cap, _WC_POW_B)))
 
 
-def _wc_gather_words(cum_a, cum_b, last_ws, e, valid, base):
-    lw = last_ws[e]
-    ha = cum_a[e] - jnp.where(lw >= 0, cum_a[jnp.maximum(lw, 0)], 0)
-    hb = cum_b[e] - jnp.where(lw >= 0, cum_b[jnp.maximum(lw, 0)], 0)
-    ln = (e - lw).astype(jnp.uint32)
-    ha = ha ^ (ln * jnp.uint32(2654435761))
-    hb = hb + (ln * jnp.uint32(0x9E3779B9))
-    sentinel = jnp.uint32(_WC_SENTINEL)
-    ha = jnp.where(valid, ha, sentinel)
-    hb = jnp.where(valid, hb, sentinel)
-    start = jnp.where(valid, (lw + 1).astype(jnp.uint32) + base, sentinel)
-    return jnp.stack([ha, hb, start])
+def _wc_after(x):
+    """x[k] - x[k - 1] along the words, x[-1] taken as 0."""
+    return x - jnp.concatenate([jnp.zeros((1,), x.dtype), x[:-1]])
 
 
 @functools.partial(jax.jit, static_argnums=(1,))
@@ -891,16 +920,23 @@ def wc_extract_words(buf, eb: int, base):
     word —, the chunk's word count as int32).  A count above eb means the
     rows were cut: the caller extracts again at a larger eb."""
     with jax.named_scope("wc_extract_words"):
-        n = buf.shape[0]
         ws, idx, last_ws, cum_a, cum_b = _wc_hash_prelude(buf)
         end_mask = (~ws) & jnp.concatenate([ws[1:], jnp.ones((1,), bool)])
         n_words = jnp.sum(end_mask, dtype=jnp.int32)
-        ends = jnp.sort(jnp.where(end_mask, idx, jnp.int32(0x7FFFFFFF)))[:eb]
+        # word ends in order (ties are the non-ends, cut or masked: no
+        # stable sort), then each end's running sums and last whitespace
+        # in ONE gather; in the words' order, the sum at the whitespace
+        # before a word is the sum at the previous word's end
+        key = jnp.where(end_mask, idx, jnp.int32(0x7FFFFFFF))
+        e = jnp.minimum(jax.lax.sort(key, is_stable=False)[:eb], buf.shape[0] - 1)
+        ca, cb, lw = jnp.take(jnp.stack([cum_a, cum_b, last_ws.astype(jnp.uint32)]), e, axis=1)
+        lw = lw.astype(jnp.int32)
+        ln = (e - lw).astype(jnp.uint32)
+        ha = _wc_after(ca) ^ (ln * jnp.uint32(2654435761))
+        hb = _wc_after(cb) + (ln * jnp.uint32(0x9E3779B9))
+        rows = jnp.stack([ha, hb, (lw + 1).astype(jnp.uint32) + base.astype(jnp.uint32)])
         valid = jnp.arange(eb, dtype=jnp.int32) < n_words
-        e = jnp.where(valid, jnp.minimum(ends, n - 1), 0)
-        rows = _wc_gather_words(cum_a, cum_b, last_ws, e, valid,
-                                base.astype(jnp.uint32))
-        return rows, n_words
+        return jnp.where(valid, rows, jnp.uint32(_WC_SENTINEL)), n_words
 
 
 @jax.jit

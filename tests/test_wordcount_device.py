@@ -35,6 +35,73 @@ def test_device_word_count_matches_host(vals):
     assert device_word_count(vals) == _ref(vals)
 
 
+
+def _identity_rows(buf: np.ndarray, eb: int, base: int):
+    """NumPy reference of wc_extract_words: each word's (hash_a, hash_b,
+    start + base) — the sum of (byte + 1) * p^min(pos, 63) mod 2^32 for
+    each prime, mixed with the length —, sentinel rows after the words, and
+    the word count."""
+    from redisson_tpu.core import kernels as K
+
+    mask = (1 << 32) - 1
+    rows = []
+    at = 0
+    for word in bytes(buf).split(b" "):
+        if word:
+            pos = np.minimum(np.arange(len(word)), K._WC_POW - 1)
+            terms = np.frombuffer(word, np.uint8).astype(np.uint64) + 1
+            ha = int((terms * K._WC_POW_A[pos].astype(np.uint64)).sum()) & mask
+            hb = int((terms * K._WC_POW_B[pos].astype(np.uint64)).sum()) & mask
+            n = len(word)
+            rows.append([ha ^ ((n * 2654435761) & mask), (hb + n * 0x9E3779B9) & mask,
+                         (at + base) & mask])
+        at += len(word) + 1
+    want = np.full((3, eb), K._WC_SENTINEL, np.uint32)
+    kept = np.array(rows[:eb], np.uint32).reshape(-1, 3)
+    want[:, : len(kept)] = kept.T
+    return want, len(rows)
+
+
+def _text(*parts, size=1024):
+    """`parts` (bytes) laid into a space-filled buffer of `size` bytes,
+    each at the offset given before it."""
+    buf = np.full(size, 32, np.uint8)
+    for at, word in parts:
+        buf[at: at + len(word)] = np.frombuffer(word, np.uint8)
+    return buf
+
+
+def _word(n, seed=0):
+    g = np.random.default_rng(seed + n)
+    return bytes(g.choice(np.r_[33:256], n).astype(np.uint8).tolist())
+
+
+@pytest.mark.parametrize(
+    "buf,eb,base",
+    [
+        (_text(*[(at, _word(n)) for at, n in zip(
+            [0, 2, 6, 70, 134, 200, 300], [1, 2, 62, 63, 64, 65, 200])]), 16, 0),
+        (_text((5, b"ab"), (12, b"c"), (13 + 40, b"de fg"), (200, b"h"), size=333), 8, 0),
+        (_text((0, b"x" * 7), (1024 - 8, b"end word"), size=1024), 8, 0),
+        (_text((3, _word(64)), (100, b"base"), size=256), 4, 1 << 20),
+        (_text(*[(3 * i, b"ab") for i in range(40)], size=128), 16, 7),
+        (np.full(64, 32, np.uint8), 4, 0),
+    ],
+    ids=["lengths-1-to-200", "runs-of-spaces", "word-on-last-byte", "base",
+         "count-above-eb", "no-words"],
+)
+def test_extract_words_keeps_the_word_identity(buf, eb, base):
+    """wc_extract_words' rows are the identity bit for bit: the words'
+    hashes across the 63-byte cap, their starts with the task's base, the
+    sentinel rows and the count (which may pass eb: the rows are then the
+    first eb words)."""
+    from redisson_tpu.core import kernels as K
+
+    rows, count = K.wc_extract_words(K.stage(buf), eb, K.valid_n(base))
+    want, n = _identity_rows(buf, eb, base)
+    assert int(count) == n
+    np.testing.assert_array_equal(np.asarray(rows), want)
+
 def test_device_word_count_long_words_and_chunking():
     long_word = "x" * 200
     vals = [f"{long_word} short {long_word}", "short " + "y" * 80]
