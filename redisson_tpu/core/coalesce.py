@@ -398,6 +398,8 @@ _planes_stacked = 0
 _cmds_offered = 0
 _knn_fused = 0
 _knn_shared = 0
+_knn_hits = 0
+_knn_reordered = 0
 
 
 def planes_counted() -> tuple:
@@ -442,6 +444,24 @@ def knn_wave_counted() -> tuple:
     METRICS exports both (knn_wave_cmds_total, knn_wave_shared_cmds_total),
     always on."""
     return _knn_fused, _knn_shared
+
+
+def count_knn_hits(hits: int, reordered: int) -> None:
+    """A KNN's fetched rows became `hits` reply hits, `reordered` of which
+    changed place when each query's hits were put in (canonical score,
+    rowid) order (services/search.py SearchService.knn's finish: once a
+    wave, once a lone search)."""
+    global _knn_hits, _knn_reordered
+    with _PLANES_LOCK:
+        _knn_hits += hits
+        _knn_reordered += reordered
+
+
+def knn_hits_counted() -> tuple:
+    """(hits returned, hits reordered) of this process's KNN replies.
+    METRICS exports both (knn_hits_total, knn_hits_reordered_total), always
+    on."""
+    return _knn_hits, _knn_reordered
 
 
 def cmds_counted() -> tuple:

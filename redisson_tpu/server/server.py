@@ -511,6 +511,14 @@ class TpuServer:
         self.metrics.gauge(
             "knn_wave_shared_cmds_total", lambda: _coalesce.knn_wave_counted()[1]
         )
+        # KNN hits returned, and those a reply's (canonical score, rowid)
+        # order moved from where the scoring path put them (services/search.py)
+        self.metrics.gauge(
+            "knn_hits_total", lambda: _coalesce.knn_hits_counted()[0]
+        )
+        self.metrics.gauge(
+            "knn_hits_reordered_total", lambda: _coalesce.knn_hits_counted()[1]
+        )
         # point commands (single-item BF.ADD / BF.EXISTS; always on): answered
         # by verb, windows served, device dispatches issued for them, rows
         # those were handed against rows asked (core/kernels.py count_point_*)

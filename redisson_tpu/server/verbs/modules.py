@@ -486,10 +486,12 @@ def _ft_field_blob(v) -> bytes:
 
 
 def _ft_score_bytes(d: float) -> bytes:
-    """Distance formatting for KNN replies: fixed 4-decimal text, so the
-    armed (device f32) and disarmed (NumPy f32) paths — which may differ in
-    the last ulp from reduction order — encode identically on the wire."""
-    return (b"%.4f" % d)
+    """Distance text for KNN replies: the canonical float32 score
+    (vector.pair_scores, the same bits on the armed and the disarmed path)
+    to nine significant digits, which a float32 always reads back from
+    whole: a client can check a hit's distance and tell apart neighbours
+    an ulp apart."""
+    return b"%.9g" % d
 
 
 def _ft_parse_search_opts(args, i):
@@ -682,7 +684,7 @@ def cmd_ft_search(server, ctx, args):
     readback (LazyReply), so M concurrent KNN frames cost <= M+1 blocking
     syncs; a run of such commands in one pipelined frame is ONE stacked
     dispatch (coalesce_knn_run).  Results carry ``__<field>_score``
-    (distance, 4 decimals, ascending).  WITHCURSOR pages k > COUNT hits
+    (the float32 distance, ascending).  WITHCURSOR pages k > COUNT hits
     through FT.CURSOR READ (nested-row shape, see _ft_knn_reply)."""
     plan = _ft_knn_plan(server, ctx, args, multi=False)
     if plan["knn"] is not None:

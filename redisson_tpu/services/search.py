@@ -850,10 +850,12 @@ class SearchService:
         the fetched host arrays; disarmed (RTPU_NO_VECTOR), `device` is
         None and ``finish(None)`` scores on the NumPy path.  Either way
         ``finish`` maps rows back to doc ids and returns one
-        ``[(doc_id, distance), ...]`` list per query (distance ascending,
-        ties toward the lower rowid) — or, with ``columns``, the same hits
-        as ``(doc ids (H,), distances (H,), ends (nq,))``, query after query,
-        ``ends`` where each query's hits end (hit_lists makes the lists)."""
+        ``[(doc_id, distance), ...]`` list per query (canonical distance
+        ascending, ties toward the lower rowid) — or, with ``columns``, the
+        same hits as ``(doc ids (H,), distances (H,), ends (nq,))``, query
+        after query, ``ends`` where each query's hits end (hit_lists makes
+        the lists)."""
+        from redisson_tpu.core import coalesce as _coalesce
         from redisson_tpu.services import vector as V
 
         idx = self._idx(index)
@@ -907,14 +909,22 @@ class SearchService:
             # deleted between dispatch and fetch
             docs = idx._rowdoc.take(idx_h)
             ok = np.isfinite(dist_h) & (docs != None)  # noqa: E711 — elementwise
-            qis, _j = np.nonzero(ok)  # row-major: reply order
+            qis, _j = np.nonzero(ok)  # row-major: query after query
             if not len(qis):
                 return shape(*none)
             # the kernel/NumPy paths choose WHICH rows win; the scores on
             # the wire come from ONE canonical per-pair routine so armed
-            # and disarmed replies are byte-identical (vector.pair_scores)
-            scores = bank.pair_scores(q, qis, idx_h[ok])
-            return shape(docs[ok], scores, np.cumsum(ok.sum(axis=1)))
+            # and disarmed replies are byte-identical (vector.pair_scores),
+            # and a query's hits are put in the order of those scores (ties
+            # toward the lower rowid): the order the scoring path found
+            # them in may differ where two distances are an ulp apart
+            rows = idx_h[ok]
+            scores = bank.pair_scores(q, qis, rows)
+            order = np.lexsort((rows, scores, qis))
+            _coalesce.count_knn_hits(
+                len(order), int(np.count_nonzero(order != np.arange(len(order)))))
+            return shape(docs[ok][order], scores[order],
+                         np.cumsum(ok.sum(axis=1)))
 
         if not armed:
             return None, finish

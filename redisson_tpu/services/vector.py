@@ -426,12 +426,14 @@ def _pair_score_math(rows: np.ndarray, qs: np.ndarray,
     """The per-pair score reduction shared by EVERY reply path (plain and
     sharded banks): (M, d) rows against (M, d) queries -> (M,) f32 scores.
     One routine on purpose — the armed/disarmed byte-identity contract
-    hangs off these exact reductions."""
-    dots = np.einsum("md,md->m", rows, qs, dtype=np.float32)
+    hangs off these exact reductions.  L2 sums the squared differences:
+    its float32 rounding is then relative to the distance itself, where
+    |q|^2 - 2 q.x + |x|^2 loses to cancellation as much as the norms
+    exceed the distance."""
     if metric == "L2":
-        q_sq = np.einsum("md,md->m", qs, qs, dtype=np.float32)
-        r_sq = np.einsum("md,md->m", rows, rows, dtype=np.float32)
-        return (q_sq - 2.0 * dots + r_sq).astype(np.float32)
+        diff = rows - qs
+        return np.einsum("md,md->m", diff, diff, dtype=np.float32)
+    dots = np.einsum("md,md->m", rows, qs, dtype=np.float32)
     if metric == "COSINE":
         qn = np.sqrt(np.einsum("md,md->m", qs, qs, dtype=np.float32))
         rn = np.sqrt(np.einsum("md,md->m", rows, rows, dtype=np.float32))
@@ -1470,9 +1472,13 @@ class EmbeddingBank(RecordRowBank):
                             k_eff: int) -> None:
         """Build knn_flat_topk at every KNN_QUERY_BUCKETS size for this
         capacity, k and kind of query, on the planes themselves (it writes
-        nothing).  jit keeps the programs; the set only spares the calls."""
+        nothing), and the grouped fetch's stack programs for each bucket's
+        outputs: a frame that reaches the server in two pieces runs two
+        smaller buckets.  jit keeps the programs; the set only spares the
+        calls."""
         import jax
 
+        from redisson_tpu.core import ioplane
         from redisson_tpu.core import kernels as K
 
         key = (self._cap, k_eff, mask is not None)
@@ -1480,10 +1486,12 @@ class EmbeddingBank(RecordRowBank):
             return
         for qb in KNN_QUERY_BUCKETS:
             zeros = K.stage(np.zeros((qb, self.pwidth), np.float32))
-            jax.block_until_ready(K.knn_flat_topk(
+            out = jax.block_until_ready(K.knn_flat_topk(
                 bank, scale, bias, norms, mask, zeros, nv, k_eff,
                 self.spec.metric,
             ))
+            for value in out:
+                ioplane.warm_stack_class(value)
         self._warm.add(key)
 
     def _host_flat_dists(self, q: np.ndarray, host: np.ndarray) -> np.ndarray:
@@ -1540,8 +1548,8 @@ class EmbeddingBank(RecordRowBank):
         the wire score of every (query, row) hit is recomputed here — one
         deterministic per-pair NumPy reduction over the dequantized mirror,
         identical bits whichever path chose the ids.  (Device-vs-host GEMMs
-        disagree in the last ulp; at large score magnitudes that ulp
-        crosses the reply's 4-decimal rounding boundary.)"""
+        disagree in the last ulp, and the reply carries every digit of a
+        float32.)"""
         with self._lock:
             rows = self._host[np.asarray(rowids, np.int64)]       # (M, d)
         qs = np.ascontiguousarray(q, np.float32)[np.asarray(qis, np.int64)]

@@ -1,7 +1,6 @@
 """The FLAT KNN program compiled for a described v5e chip at the published
-size (1,048,576 x 128), with no chip attached (the on-chip-measurement
-guide's third rehearsal): what the TPU's compiler refuses, it refuses here,
-and the blocked walk must need no buffer anywhere near the (Q, capacity)
+sizes (1,048,576 x 128, and x 960), with no chip attached: what the TPU's
+compiler refuses, it refuses here, and the blocked walk must need no buffer anywhere near the (Q, capacity)
 distance matrix a whole-bank top-k holds (268 MB at Q = 64).  Beside it the
 two bytes kernels of the single 10 M-item filter (the benchmark's cell
 bf-200c), at the plane's real size: an add must write into the donated plane
@@ -30,19 +29,21 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("queries,dtype,masked", [
-    (1, jnp.float32, False), (64, jnp.float32, False), (4, jnp.float32, True),
-    (16, jnp.int8, False),
-], ids=["q1", "q64", "q4-masked", "q16-int8"])
+@pytest.mark.parametrize("queries,dtype,masked,dim", [
+    (1, jnp.float32, False, DIM), (64, jnp.float32, False, DIM),
+    (4, jnp.float32, True, DIM), (16, jnp.int8, False, DIM),
+    # the ann-gist-1m bank (4 GB): a KNN_BLOCK tile is 252 MB, read where it lies
+    (64, jnp.float32, False, 960),
+], ids=["q1", "q64", "q4-masked", "q16-int8", "q64-d960"])
 def test_flat_topk_compiles_for_the_chip_without_the_whole_matrix(
-        one_chip, queries, dtype, masked):
+        one_chip, queries, dtype, masked, dim):
     def shape(dims, dt):
         return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
 
     plane = shape((CAP,), jnp.float32)
     compiled = jax.jit(K.knn_flat_topk.__wrapped__, static_argnums=(7, 8)).lower(
-        shape((CAP, DIM), dtype), plane if dtype == jnp.int8 else None, plane, plane,
-        plane if masked else None, shape((queries, DIM), jnp.float32),
+        shape((CAP, dim), dtype), plane if dtype == jnp.int8 else None, plane, plane,
+        plane if masked else None, shape((queries, dim), jnp.float32),
         shape((), jnp.int32), K_TOP, "L2",
     ).compile()
     stats = compiled.memory_analysis()

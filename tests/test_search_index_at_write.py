@@ -161,7 +161,7 @@ def test_write_is_in_what_the_next_search_sees(server, change):
     hits = c.execute(*_search_cmd(vecs[0] + 1, "ix", 3))
     assert (key in _ids(hits)) is present, hits
     if present:
-        assert _ids(hits)[0] == key and bytes(hits[2][1]) == b"0.0000"
+        assert _ids(hits)[0] == key and bytes(hits[2][1]) == b"0"
     assert S.search_counted()[1] == scanned  # no search walked the keyspace
     c.close()
 
